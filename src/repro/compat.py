@@ -1,83 +1,32 @@
-"""Version-portability shim for the JAX mesh/sharding API surface.
+"""The JAX mesh/sharding API surface, in one place.
 
-The reproduction targets the post-0.5 explicit-sharding API
-(``get_abstract_mesh``, ``AxisType``, ``make_mesh(..., axis_types=...)``,
-``set_mesh``/``use_mesh``) but must run unchanged on jax 0.4.x, which
-predates all of them.  Every symbol here is resolved by *feature
-detection* — probing the running JAX once at import — never by parsing
-version strings, so point-release backports and renames keep working.
+The repository runs on one JAX release (0.9.0).  Mesh construction, the
+ambient mesh, sharding constraints and ``shard_map`` go through this module
+so that the conventions the rest of the code relies on live here:
+
+* ``get_abstract_mesh()`` returns ``None`` for "no mesh" (JAX returns an
+  empty abstract mesh);
+* ``use_mesh(None)`` is a no-op (the single-device path);
+* ``with_sharding_constraint`` resolves bare axis names against an explicit
+  or ambient mesh and is a no-op without one (CPU unit tests);
+* ``shard_map`` manualizes every mesh axis except ``auto``.
 
 This module is the ONLY place in the repo allowed to touch those jax
 symbols directly (enforced by a grep test in tests/test_compat.py).
 
-Fallback semantics on older JAX:
-
-* ``use_mesh(mesh)``      -> enters the concrete ``Mesh`` context manager
-  (which makes bare-``PartitionSpec`` sharding constraints resolvable)
-  and tracks the mesh on a thread-local stack.
-* ``get_abstract_mesh()`` -> the stack top, else the thread-resources
-  physical mesh (set by a raw ``with mesh:``), else ``None``.
-* ``make_mesh``           -> drops ``axis_types`` (the older API has a
-  single implicit behaviour equivalent to auto axes under GSPMD).
-* ``with_sharding_constraint`` -> resolves bare specs against an explicit
-  or ambient mesh via ``NamedSharding`` and degrades to a no-op when no
-  mesh is available (CPU unit tests).
-
 The same module owns kernel-backend selection (``pallas`` / ``interpret``
 / pure-``jnp``) so per-platform dispatch and the ``REPRO_KERNEL_IMPL``
-override live next to the rest of the runtime-portability decisions.
+override live next to the rest of the runtime decisions.
 """
 
 from __future__ import annotations
 
 import contextlib
-import enum
-import inspect
 import os
-import threading
 from typing import Optional, Sequence
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec
-
-
-def _probe(obj, name: str):
-    """``getattr`` that treats jax's accelerated-deprecation
-    ``AttributeError``s (raised from module ``__getattr__``) as absent."""
-    try:
-        return getattr(obj, name, None)
-    except Exception:
-        return None
-
-
-# Feature flags — module-level so tests can monkeypatch each branch.
-_NATIVE_AXIS_TYPE = _probe(jax.sharding, "AxisType")
-_NATIVE_GET_ABSTRACT_MESH = _probe(jax.sharding, "get_abstract_mesh")
-_NATIVE_USE_MESH = _probe(jax.sharding, "use_mesh") or _probe(jax, "set_mesh")
-_NATIVE_MAKE_MESH = _probe(jax, "make_mesh")
-
-
-def _accepts_axis_types(fn) -> bool:
-    if fn is None:
-        return False
-    try:
-        return "axis_types" in inspect.signature(fn).parameters
-    except (TypeError, ValueError):
-        return False
-
-
-_MAKE_MESH_AXIS_TYPES = _accepts_axis_types(_NATIVE_MAKE_MESH)
-
-
-class _AxisTypeStub(enum.Enum):
-    """Stand-in for the post-0.5 axis-type enum: call sites can name axis
-    types symbolically even where the running JAX has no such concept."""
-    Auto = "auto"
-    Explicit = "explicit"
-    Manual = "manual"
-
-
-AxisType = _NATIVE_AXIS_TYPE if _NATIVE_AXIS_TYPE is not None else _AxisTypeStub
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec
 
 
 def auto_axis_types(n: int):
@@ -86,66 +35,22 @@ def auto_axis_types(n: int):
 
 
 # ---------------------------------------------------------------------------
-# Mesh construction
+# Mesh construction and the ambient mesh
 # ---------------------------------------------------------------------------
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str], *,
-              axis_types="auto", devices=None) -> Mesh:
-    """Portable ``make_mesh``: passes ``axis_types`` only where the running
-    JAX accepts it.  ``axis_types='auto'`` means all-auto (this repo's only
-    use); ``None`` skips the argument entirely."""
-    axis_shapes = tuple(axis_shapes)
+              devices=None) -> Mesh:
+    """All-auto mesh over ``devices`` (default: every device)."""
     axis_names = tuple(axis_names)
-    if axis_types == "auto":
-        axis_types = auto_axis_types(len(axis_names))
-    kw = {}
-    if devices is not None:
-        kw["devices"] = devices
-    if _NATIVE_MAKE_MESH is not None:
-        if _MAKE_MESH_AXIS_TYPES and axis_types is not None:
-            return _NATIVE_MAKE_MESH(axis_shapes, axis_names,
-                                     axis_types=axis_types, **kw)
-        return _NATIVE_MAKE_MESH(axis_shapes, axis_names, **kw)
-    from jax.experimental import mesh_utils
-    devs = mesh_utils.create_device_mesh(axis_shapes, devices=devices)
-    return Mesh(devs, axis_names)
-
-
-# ---------------------------------------------------------------------------
-# Ambient mesh: native abstract-mesh tracking where available, otherwise a
-# thread-local stack maintained by use_mesh().
-# ---------------------------------------------------------------------------
-
-_ambient = threading.local()
-
-
-def _stack():
-    if not hasattr(_ambient, "meshes"):
-        _ambient.meshes = []
-    return _ambient.meshes
+    return jax.make_mesh(tuple(axis_shapes), axis_names,
+                         axis_types=auto_axis_types(len(axis_names)),
+                         devices=devices)
 
 
 def get_abstract_mesh():
-    """The ambient mesh, or ``None`` when no mesh context is active.
-
-    Normalizes across versions: the native API returns an *empty* abstract
-    mesh when unset — callers here always get ``None`` for "no mesh"."""
-    if _NATIVE_GET_ABSTRACT_MESH is not None:
-        m = _NATIVE_GET_ABSTRACT_MESH()
-        if m is not None and tuple(getattr(m, "axis_names", ()) or ()):
-            return m
-        return None
-    st = _stack()
-    if st:
-        return st[-1]
-    try:  # a raw `with mesh:` (0.4.x resource env) also counts as ambient
-        from jax._src import mesh as _mesh_src
-        pm = _mesh_src.thread_resources.env.physical_mesh
-        if pm is not None and not pm.empty:
-            return pm
-    except Exception:
-        pass
-    return None
+    """The ambient mesh, or ``None`` when no mesh context is active."""
+    m = jax.sharding.get_abstract_mesh()
+    return m if tuple(m.axis_names) else None
 
 
 @contextlib.contextmanager
@@ -155,20 +60,8 @@ def use_mesh(mesh: Optional[Mesh]):
     if mesh is None:
         yield None
         return
-    if _NATIVE_USE_MESH is not None:
-        with _NATIVE_USE_MESH(mesh):
-            yield mesh
-        return
-    st = _stack()
-    st.append(mesh)
-    try:
-        if hasattr(mesh, "__enter__"):  # 0.4.x: resolves bare PartitionSpecs
-            with mesh:
-                yield mesh
-        else:
-            yield mesh
-    finally:
-        st.pop()
+    with jax.set_mesh(mesh):
+        yield mesh
 
 
 def unwrap_mesh(mesh_or_ctx):
@@ -183,9 +76,8 @@ def with_sharding_constraint(x, *spec, mesh=None):
 
     Bare axis names (or a ready ``PartitionSpec``) are resolved against the
     explicit ``mesh`` when given, else the ambient mesh.  A concrete mesh
-    resolves through ``NamedSharding`` (works on every version without any
-    ambient context); otherwise the bare spec is handed to jax, which the
-    post-0.5 abstract-mesh machinery resolves itself."""
+    resolves through ``NamedSharding``; otherwise the bare spec is handed
+    to jax, which resolves it against the ambient abstract mesh."""
     if len(spec) == 1 and isinstance(spec[0], PartitionSpec):
         sp = spec[0]
     else:
@@ -199,61 +91,19 @@ def with_sharding_constraint(x, *spec, mesh=None):
         return x
 
 
-# ---------------------------------------------------------------------------
-# shard_map: top-level jax.shard_map (0.6+, manual axes named via
-# ``axis_names``) vs jax.experimental.shard_map.shard_map (0.4.x/0.5.x,
-# manual-by-default with an ``auto`` complement set).
-# ---------------------------------------------------------------------------
-
-_NATIVE_SHARD_MAP = _probe(jax, "shard_map")
-
-
-def _experimental_shard_map():
-    from jax.experimental.shard_map import shard_map as sm
-    return sm
-
-
 def shard_map(f, mesh, in_specs, out_specs, auto=frozenset()):
-    """Portable ``shard_map``: manualize every mesh axis except ``auto``
-    (left to GSPMD — e.g. the tensor-parallel 'model' axis while the DP
-    gradient reduction runs manually over 'data').
+    """``jax.shard_map`` manual over every mesh axis except ``auto`` (left
+    to GSPMD — e.g. the tensor-parallel 'model' axis while the DP gradient
+    reduction runs manually over 'data').
 
-    Replication checking is disabled on every version: the call sites here
-    produce post-``psum`` (replicated-by-construction) outputs that the
-    checker cannot always prove through dtype casts, and 0.4.x rejects
-    ``check_rep=True`` combined with non-empty ``auto``."""
+    Varying-manual-axes checking is off: the call sites here produce
+    post-``psum`` (replicated-by-construction) outputs that the checker
+    cannot always prove through dtype casts."""
     mesh = unwrap_mesh(mesh)
-    auto = frozenset(auto)
-    if _NATIVE_SHARD_MAP is not None:
-        params = inspect.signature(_NATIVE_SHARD_MAP).parameters
-        if "axis_names" in params:
-            manual = frozenset(mesh.axis_names) - auto
-            kw = {"axis_names": manual}
-            if "check_vma" in params:
-                kw["check_vma"] = False
-            elif "check_rep" in params:
-                kw["check_rep"] = False
-            return _NATIVE_SHARD_MAP(f, mesh=mesh, in_specs=in_specs,
-                                     out_specs=out_specs, **kw)
-        return _NATIVE_SHARD_MAP(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=False,
-                                 auto=auto)
-    return _experimental_shard_map()(f, mesh=mesh, in_specs=in_specs,
-                                     out_specs=out_specs, check_rep=False,
-                                     auto=auto)
-
-
-# ---------------------------------------------------------------------------
-# Compiled-artifact introspection
-# ---------------------------------------------------------------------------
-
-def cost_analysis(compiled) -> dict:
-    """Normalized ``compiled.cost_analysis()``: newer jax returns a flat
-    dict, 0.4.x a one-element list of per-program dicts."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        return dict(cost[0]) if cost else {}
-    return dict(cost or {})
+    manual = frozenset(mesh.axis_names) - frozenset(auto)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, axis_names=manual,
+                         check_vma=False)
 
 
 # ---------------------------------------------------------------------------

@@ -27,5 +27,5 @@ def smoke(cfg: ModelConfig) -> ModelConfig:
 LLAMA_60M = _llama("llama-60m", 8, 512, 8, 1376)
 LLAMA_130M = _llama("llama-130m", 12, 768, 12, 2048)
 LLAMA_350M = _llama("llama-350m", 24, 1024, 16, 2736)
-LLAMA_1B = _llama("llama-1b", 32, 2048, 24, 5461)
+LLAMA_1B = _llama("llama-1b", 24, 2048, 32, 5461)
 LLAMA_3B = _llama("llama-3b", 32, 2560, 32, 6848)

@@ -85,7 +85,7 @@ def gwt(lr: Schedule | float,
     (``repro.optim.codec``): int8 composes multiplicatively with the
     wavelet subspace — host moments live on the ``A_l`` band AND are
     stored blocked-quantized.  On the fused kernel path the requantize
-    epilogue runs inside the kernel (``ops.fused_update_q8``).
+    epilogue runs inside the kernel (``ops.fused_write_update_q8``).
     ``fused_write=False`` keeps the DWT+Adam core kernel but stages the
     limiter/step/param-write outside it (the pre-megakernel dataflow,
     materializing g̃) — a benchmarking baseline, not a production knob."""

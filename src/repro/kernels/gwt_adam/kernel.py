@@ -1,59 +1,101 @@
 """Pallas TPU kernel: FUSED GWT-Adam update (the paper's Algorithm 1 inner
 loop, beyond-paper fusion).
 
-Per ``(bm, bn)`` gradient tile, in a single VMEM residency:
+Per ``(bm, n)`` row stripe of the gradient, in a single VMEM residency:
 
     forward Haar butterfly (all ``l`` levels)      [bands stay in registers]
-    M ← β₁M + (1−β₁)A ;  V ← β₂V + (1−β₂)A²        [moment tiles bn/2^l wide]
+    M ← β₁M + (1−β₁)A ;  V ← β₂V + (1−β₂)A²        [moment tiles n/2^l wide]
     Ã = M/(√V+ε) ;  D̃_k = D_k · repeat(1/(√V+ε))
     inverse butterfly → G̃ tile
     partial ‖G̃‖² per tile                          [for the norm-growth limiter]
 
-HBM traffic: read G (bm·bn) + read/write M,V (2·bm·bn/2^l each) + write G̃
-(bm·bn) ≈ ``2 + 4/2^l`` elements per gradient element — vs ``≥ 6`` for the
-unfused op-by-op schedule (read G, write A/D, read A/D + M/V, write M/V/Ã/D̃,
-read Ã/D̃, write G̃).  The op does O(1) FLOPs/element, so on TPU v5e it is
-purely HBM-bandwidth-bound and the fusion is a ~2.5× win at l=2 (measured
-as bytes, see EXPERIMENTS.md §Perf).
+HBM traffic: read G + read/write M,V (at ``1/2^l`` of the width) + write
+G̃ — about ``2 + 4/2^l`` elements per gradient element, against ``≥ 6``
+for the op-by-op schedule.  The detail bands are *never* materialized in
+HBM — the paper's "temporary information generated during the wavelet
+transform" observation (§V), taken to its architectural conclusion.  What
+that buys on a chip is not measured yet.
 
-The detail bands are *never* materialized in HBM — exactly the paper's
-"temporary information generated during the wavelet transform" observation
-(§V), taken to its architectural conclusion.
+The butterfly's even/odd lane pairing is a product with a 0/1 selection
+matrix on the MXU (``repro.kernels.lanes``): exact in f32, so every tile
+is bitwise the jnp butterfly's.  Stripes span the full row (a block whose
+last dimension is the array's is always tile-legal, whatever the width);
+the row tile is bounded by the VMEM budget and the grid is
+``pl.cdiv(m, bm)``, so a partial last tile masks its out-of-range rows
+out of every norm.  Scalars (per-leaf limiter state, step size, weight
+decay, rounding salts) and the per-tile norm partials live in SMEM.
 
-Bias correction (``lr_mult``) and the norm-growth limiter ratio are scalars
-applied by the caller (ops.py) — the limiter needs the global norm, which is
-reduced from the per-tile partials this kernel emits.
+Bias correction (``lr_mult``) and the norm-growth limiter ratio are applied
+by the caller (ops.py) on the staged path — the limiter needs the global
+norm, which is reduced from the per-tile partials this kernel emits.
 
 **Fused-write megakernel** (``gwt_adam_tile_fused{,_q8}``): the full
 DWT→Adam→inverse→limit→param-write chain in ONE launch per ``(L, m, n)``
-bucket.  The leaf axis is folded into the grid (no vmap), the per-leaf
+bucket.  The leaf axis is folded into the grid (no vmap); the per-leaf
 ``‖G̃‖`` reduction runs as a two-phase pass over the row tiles with the
-``new_norm`` output block as the on-chip accumulator (all ``phases·gm``
-grid steps of leaf ``l`` map it to the same block — consecutive revisits
-keep it resident in VMEM on TPU), and the epilogue applies the norm-growth
-limiter, the bias-corrected step size, and weight decay before writing the
-parameter tile.  ``G̃`` never round-trips HBM and the gradient never lives
-alongside its transform.
+SMEM ``new_norm`` output as the accumulator, and the epilogue applies the
+norm-growth limiter, the bias-corrected step size, and weight decay before
+writing the parameter tile.  ``G̃`` never round-trips HBM and the gradient
+never lives alongside its transform.
+
+The q8 variant keeps the moments in the int8 codec's layout
+(``repro.optim.codec``): per-row blocks of ``block`` A-band elements, one
+f32 scale each, so a row tile always holds whole blocks.
 """
 
 from __future__ import annotations
 
 import functools
-import math
-from typing import Optional, Tuple
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import lanes
 from repro.optim import codec as codec_lib
 
 INV_SQRT2 = 0.7071067811865476
+_SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
+_TEMPS = 16  # f32 copies of a stripe the butterfly keeps live (VMEM estimate)
 
 
-def _body(level: int, b1: float, b2: float, eps: float,
-          g_ref, m_ref, v_ref,
-          gt_ref, m_out_ref, v_out_ref, ssq_ref):
+def _dht_adam_core(x, m_st, v_st, level, b1, b2, eps):
+    """Forward butterfly → Adam on A → scaled-detail inverse butterfly.
+    Shared by the f32 and the q8 (blocked-int8 moments) bodies."""
+    a = x
+    details = []
+    for _ in range(level):
+        even, odd = lanes.deinterleave(a)
+        a = (even + odd) * INV_SQRT2
+        details.append((even - odd) * INV_SQRT2)
+
+    m = b1 * m_st + (1.0 - b1) * a
+    v = b2 * v_st + (1.0 - b2) * a * a
+    inv_denom = 1.0 / (jnp.sqrt(v) + eps)
+
+    x = m * inv_denom
+    for k in range(level, 0, -1):
+        d_t = details[k - 1] * lanes.repeat_lanes(inv_denom, 1 << (level - k))
+        x = lanes.interleave((x + d_t) * INV_SQRT2, (x - d_t) * INV_SQRT2)
+    return x, m, v
+
+
+def _row_bytes(n: int, level: int, stream_cols: float) -> int:
+    """VMEM bytes per stripe row: the streamed blocks (``stream_cols``
+    bytes per gradient column, double-buffered) plus f32 temporaries."""
+    return int(2 * stream_cols * n + _TEMPS * 4 * n)
+
+
+def _check_width(n: int, level: int) -> None:
+    if n % (1 << level) != 0:
+        raise ValueError(f"n={n} not divisible by 2^{level}")
+
+
+def _body(level: int, b1: float, b2: float, eps: float, rows: int,
+          g_ref, m_ref, v_ref, gt_ref, m_out_ref, v_out_ref, ssq_ref):
+    i = pl.program_id(0)
     x = g_ref[...].astype(jnp.float32)
     out, m, v = _dht_adam_core(x, m_ref[...].astype(jnp.float32),
                                v_ref[...].astype(jnp.float32),
@@ -64,183 +106,8 @@ def _body(level: int, b1: float, b2: float, eps: float,
     v_out_ref[...] = v.astype(v_out_ref.dtype)
     # limiter norm partials over the ROUNDED output tile (matches ref.py):
     # the limiter should see the norm of the g̃ actually written to HBM
-    xr = out.astype(jnp.float32)
-    ssq_ref[0, 0] = jnp.sum(xr * xr)
-
-
-def _pick_blocks(m: int, n: int, level: int) -> Tuple[int, int]:
-    unit = max(1 << level, 128)
-    bn = unit
-    while bn * 2 <= min(n, 2048) and n % (bn * 2) == 0:
-        bn *= 2
-    if n % bn != 0:
-        bn = n
-    bm = 8
-    # working set ≈ (G + bands + G̃ + M,V) ≈ 3.5·bm·bn·4B; cap ~4MB
-    while bm * 2 <= min(m, 1024) and m % (bm * 2) == 0 \
-            and 4 * (bm * 2) * bn * 4 <= 4 * 1024 * 1024:
-        bm *= 2
-    if m % bm != 0:
-        bm = m
-    return bm, bn
-
-
-def _dht_adam_core(x, m_st, v_st, level, b1, b2, eps):
-    """Forward butterfly → Adam on A → scaled-detail inverse butterfly.
-    Shared by the f32 body and the q8 (blocked-int8 moments) body."""
-    bm = x.shape[0]
-    a = x
-    details = []
-    for _ in range(level):
-        pairs = a.reshape(bm, a.shape[-1] // 2, 2)
-        even, odd = pairs[..., 0], pairs[..., 1]
-        a = (even + odd) * INV_SQRT2
-        details.append((even - odd) * INV_SQRT2)
-
-    m = b1 * m_st + (1.0 - b1) * a
-    v = b2 * v_st + (1.0 - b2) * a * a
-    inv_denom = 1.0 / (jnp.sqrt(v) + eps)
-
-    x = m * inv_denom
-    for k in range(level, 0, -1):
-        d = details[k - 1]
-        reps = 1 << (level - k)
-        scale = inv_denom if reps == 1 else jnp.repeat(inv_denom, reps, axis=-1)
-        d_t = d * scale
-        even = (x + d_t) * INV_SQRT2
-        odd = (x - d_t) * INV_SQRT2
-        x = jnp.stack([even, odd], axis=-1).reshape(bm, x.shape[-1] * 2)
-    return x, m, v
-
-
-def _body_q8(level: int, b1: float, b2: float, eps: float, block: int,
-             g_ref, qm_ref, sm_ref, qv_ref, sv_ref, saltm_ref, saltv_ref,
-             gt_ref, qm_out_ref, sm_out_ref, qv_out_ref, sv_out_ref,
-             ssq_ref):
-    """q8 body: dequantize blocked-int8 moment tiles, run the fused DHT-Adam
-    core, stochastically requantize in the epilogue.  The grid tiles ROWS
-    only (full-width blocks), so each tile's row-major flat range is
-    block-aligned and scale blocks never straddle tiles."""
-    x = g_ref[...].astype(jnp.float32)
-    bm, bn = x.shape
-    bna = bn >> level
-    sb = (bm * bna) // block
-
-    def dequant(q_ref, s_ref):
-        q = q_ref[...].astype(jnp.float32).reshape(sb, block)
-        return (q * s_ref[...][:, 0][:, None]).reshape(bm, bna)
-
-    out, m, v = _dht_adam_core(x, dequant(qm_ref, sm_ref),
-                               dequant(qv_ref, sv_ref), level, b1, b2, eps)
-
-    gt = out.astype(gt_ref.dtype)
-    gt_ref[...] = gt
-    xr = gt.astype(jnp.float32)
-    ssq_ref[0, 0] = jnp.sum(xr * xr)
-
-    # ---- requant epilogue: global flat element index -> rounding bits ----
-    base = pl.program_id(0) * (bm * bna)
-    idx = (base
-           + jax.lax.broadcasted_iota(jnp.int32, (sb, block), 0) * block
-           + jax.lax.broadcasted_iota(jnp.int32, (sb, block), 1))
-
-    def requant(arr, salt, q_out, s_out):
-        blocks = arr.reshape(sb, block)
-        absmax = jnp.max(jnp.abs(blocks), axis=1)
-        scale = absmax * jnp.float32(1.0 / 127.0)
-        inv = jnp.where(scale > 0, 1.0 / scale, 0.0).astype(jnp.float32)
-        y = blocks * inv[:, None]
-        lo = jnp.floor(y)
-        q = lo + (codec_lib.uniform01(salt, idx) < (y - lo)).astype(
-            jnp.float32)
-        q_out[...] = jnp.clip(q, -127.0, 127.0).astype(jnp.int8).reshape(
-            bm, bna)
-        s_out[...] = scale[:, None]
-
-    requant(m, saltm_ref[0, 0], qm_out_ref, sm_out_ref)
-    requant(v, saltv_ref[0, 0], qv_out_ref, sv_out_ref)
-
-
-def q8_row_block(m: int, n: int, level: int,
-                 block: int) -> Optional[int]:
-    """Row-tile height for the q8 kernel, or None when the shape cannot be
-    tiled block-aligned (caller falls back to the jnp oracle).  ``bm`` must
-    divide ``m`` and keep ``bm·na`` a multiple of ``block`` so per-tile
-    scale slices are whole blocks."""
-    na = n >> level
-    if na == 0 or (m * na) % block != 0:
-        return None
-    step = block // math.gcd(na, block)
-    best = None
-    for bm in range(step, m + 1, step):
-        if m % bm:
-            continue
-        if 4 * bm * n * 4 <= 4 * 1024 * 1024 or best is None:
-            best = bm
-        else:
-            break
-    return best
-
-
-def gwt_adam_tile_q8(g: jax.Array, qm: jax.Array, sm: jax.Array,
-                     qv: jax.Array, sv: jax.Array,
-                     salt_m: jax.Array, salt_v: jax.Array, *,
-                     level: int, block: int, b1: float = 0.9,
-                     b2: float = 0.999, eps: float = 1e-6,
-                     interpret: bool = False):
-    """Fused q8 update for one 2-D leaf: blocked-int8 moments in/out.
-
-    ``qm/qv``: int8 ``(m, n>>level)``; ``sm/sv``: f32 ``(nb,)`` flat-block
-    scales; ``salt_m/salt_v``: uint32 rounding salts (slot-specific, from
-    ``codec.slot_salt``).  Returns ``(gt, qm', sm', qv', sv', ssq)`` with
-    ``ssq`` shaped ``(grid_m, 1)``.
-    """
-    mm, nn = g.shape
-    if nn % (1 << level) != 0:
-        raise ValueError(f"n={nn} not divisible by 2^{level}")
-    bm = q8_row_block(mm, nn, level, block)
-    if bm is None:
-        raise ValueError(f"q8 kernel: ({mm},{nn}) level={level} not "
-                         f"block-{block} alignable — use the jnp oracle")
-    na = nn >> level
-    nb = (mm * na) // block
-    sb = (bm * na) // block
-    gm = mm // bm
-    sm2, sv2 = sm.reshape(nb, 1), sv.reshape(nb, 1)
-    u32 = jnp.uint32
-    saltm2 = jnp.asarray(salt_m, u32).reshape(1, 1)
-    saltv2 = jnp.asarray(salt_v, u32).reshape(1, 1)
-    scalar = pl.BlockSpec((1, 1), lambda i: (0, 0))
-    gt, qm2, smo, qv2, svo, ssq = pl.pallas_call(
-        functools.partial(_body_q8, level, b1, b2, eps, block),
-        grid=(gm,),
-        in_specs=[
-            pl.BlockSpec((bm, nn), lambda i: (i, 0)),
-            pl.BlockSpec((bm, na), lambda i: (i, 0)),
-            pl.BlockSpec((sb, 1), lambda i: (i, 0)),
-            pl.BlockSpec((bm, na), lambda i: (i, 0)),
-            pl.BlockSpec((sb, 1), lambda i: (i, 0)),
-            scalar, scalar,
-        ],
-        out_specs=[
-            pl.BlockSpec((bm, nn), lambda i: (i, 0)),
-            pl.BlockSpec((bm, na), lambda i: (i, 0)),
-            pl.BlockSpec((sb, 1), lambda i: (i, 0)),
-            pl.BlockSpec((bm, na), lambda i: (i, 0)),
-            pl.BlockSpec((sb, 1), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((mm, nn), g.dtype),
-            jax.ShapeDtypeStruct((mm, na), jnp.int8),
-            jax.ShapeDtypeStruct((nb, 1), jnp.float32),
-            jax.ShapeDtypeStruct((mm, na), jnp.int8),
-            jax.ShapeDtypeStruct((nb, 1), jnp.float32),
-            jax.ShapeDtypeStruct((gm, 1), jnp.float32),
-        ],
-        interpret=interpret,
-    )(g, qm, sm2, qv, sv2, saltm2, saltv2)
-    return gt, qm2, smo.reshape(nb), qv2, svo.reshape(nb), ssq
+    ssq_ref[i] = lanes.masked_ssq(out.astype(jnp.float32), i * x.shape[0],
+                                  rows)
 
 
 def gwt_adam_tile(g: jax.Array, m_st: jax.Array, v_st: jax.Array, *,
@@ -250,33 +117,25 @@ def gwt_adam_tile(g: jax.Array, m_st: jax.Array, v_st: jax.Array, *,
     """Fused update for one 2-D leaf.
 
     Returns ``(g_tilde, new_m, new_v, sumsq_partials)`` where
-    ``sumsq_partials`` has shape ``(grid_m, grid_n)`` (caller sums → ‖G̃‖²).
+    ``sumsq_partials`` has one entry per row tile (caller sums → ‖G̃‖²).
     """
     mm, nn = g.shape
-    if nn % (1 << level) != 0:
-        raise ValueError(f"n={nn} not divisible by 2^{level}")
-    bm, bn = _pick_blocks(mm, nn, level)
-    gm, gn = mm // bm, nn // bn
-    bna = bn >> level
+    _check_width(nn, level)
+    na = nn >> level
+    stream = 2 * g.dtype.itemsize + 4 * m_st.dtype.itemsize / (1 << level)
+    bm = lanes.row_block(mm, _row_bytes(nn, level, stream))
+    gm = pl.cdiv(mm, bm)
+    tile = lambda w: pl.BlockSpec((bm, w), lambda i: (i, 0))
     return pl.pallas_call(
-        functools.partial(_body, level, b1, b2, eps),
-        grid=(gm, gn),
-        in_specs=[
-            pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
-            pl.BlockSpec((bm, bna), lambda i, j: (i, j)),
-            pl.BlockSpec((bm, bna), lambda i, j: (i, j)),
-        ],
-        out_specs=[
-            pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
-            pl.BlockSpec((bm, bna), lambda i, j: (i, j)),
-            pl.BlockSpec((bm, bna), lambda i, j: (i, j)),
-            pl.BlockSpec((1, 1), lambda i, j: (i, j)),
-        ],
+        functools.partial(_body, level, b1, b2, eps, mm),
+        grid=(gm,),
+        in_specs=[tile(nn), tile(na), tile(na)],
+        out_specs=[tile(nn), tile(na), tile(na), _SMEM],
         out_shape=[
             jax.ShapeDtypeStruct((mm, nn), g.dtype),
-            jax.ShapeDtypeStruct((mm, nn >> level), m_st.dtype),
-            jax.ShapeDtypeStruct((mm, nn >> level), v_st.dtype),
-            jax.ShapeDtypeStruct((gm, gn), jnp.float32),
+            jax.ShapeDtypeStruct((mm, na), m_st.dtype),
+            jax.ShapeDtypeStruct((mm, na), v_st.dtype),
+            jax.ShapeDtypeStruct((gm,), jnp.float32),
         ],
         interpret=interpret,
     )(g, m_st, v_st)
@@ -288,16 +147,23 @@ def gwt_adam_tile(g: jax.Array, m_st: jax.Array, v_st: jax.Array, *,
 # ---------------------------------------------------------------------------
 
 def fused_row_block(m: int, n: int, level: int) -> int:
-    """Row-tile height for the fused-write kernels: full-width stripes so
-    the per-leaf ssq accumulation sees one tile per grid step.  Working set
-    ≈ (G + P + G̃ + P' at width n, M,V in/out at width n>>level)
-    ≈ (4 + 4/2^level)·bm·n·4B; cap ~4MB."""
-    row_bytes = (4 + 4 / (1 << level)) * n * 4
-    bm = 8 if m % 8 == 0 else m
-    while bm * 2 <= min(m, 1024) and m % (bm * 2) == 0 \
-            and (bm * 2) * row_bytes <= 4 * 1024 * 1024:
-        bm *= 2
-    return bm
+    """Row-tile height of the f32-moment fused-write kernel: full-width
+    stripes (G, P in, P out; M, V in and out at ``n >> level``), sized for
+    f32 parameters under the VMEM budget.  ``ref.gwt_adam_fused`` takes
+    the same value to reproduce the kernel's norm reduction order."""
+    return lanes.row_block(m, _row_bytes(n, level, 12 + 16 / (1 << level)))
+
+
+def q8_row_block(m: int, n: int, level: int, block: int) -> int:
+    """Row-tile height of the q8 fused-write kernel: int8 moments and one
+    f32 scale per ``block`` moments.  Codec blocks never straddle rows, so
+    every row tile holds whole blocks and every shape tiles; the scales
+    of a tile are an ``(nbr, bm)`` block, so ``bm`` is a multiple of 128
+    (their lane axis) unless it spans all rows."""
+    scales = 4 * 4 * 8 * -(-(n >> level) // (block * 8))
+    return lanes.row_block(
+        m, _row_bytes(n, level, 12 + 4 / (1 << level) + scales / n),
+        align=128)
 
 
 def _limiter_scale(norm, prev, gamma: float):
@@ -310,16 +176,17 @@ def _limiter_scale(norm, prev, gamma: float):
 
 
 def _body_fused(level: int, b1: float, b2: float, eps: float, gamma: float,
-                use_limiter: bool, wd: bool,
-                g_ref, p_ref, m_ref, v_ref, pn_ref, ss_ref, wd_ref,
+                use_limiter: bool, wd: bool, rows: int,
+                pn_ref, sc_ref, g_ref, p_ref, m_ref, v_ref,
                 p_out_ref, m_out_ref, v_out_ref, norm_ref):
     """Grid ``(L, phases, gm)`` — leaf outermost, row tiles innermost; the
-    ``norm_ref`` output block (one per leaf, revisited every step of that
-    leaf) doubles as the cross-tile ssq accumulator.  Phase 0 accumulates
-    ``‖G̃_l‖²``; phase 1 recomputes the tile (the op is bandwidth-bound —
-    recompute is cheaper than an HBM round trip of G̃) and applies
-    limiter + step + weight decay + write.  ``use_limiter=False`` runs the
-    single write phase only."""
+    SMEM ``norm_ref[l]`` doubles as the cross-tile ssq accumulator.  Phase
+    0 accumulates ``‖G̃_l‖²``; phase 1 recomputes the tile (the op is
+    bandwidth-bound — recompute is cheaper than an HBM round trip of G̃)
+    and applies limiter + step + weight decay + write.
+    ``use_limiter=False`` runs the single write phase only.  ``sc_ref``
+    holds ``(step_size, wd_coef)``."""
+    leaf = pl.program_id(0)
     phase = pl.program_id(1)
     i = pl.program_id(2)
     gm = pl.num_programs(2)
@@ -328,50 +195,55 @@ def _body_fused(level: int, b1: float, b2: float, eps: float, gamma: float,
                                v_ref[0].astype(jnp.float32),
                                level, b1, b2, eps)
     gt = out.astype(g_ref.dtype)
-    prev = pn_ref[0, 0]
+    prev = pn_ref[leaf]
 
     def write(scale):
         limited = gt * scale.astype(gt.dtype)
         p32 = p_ref[0].astype(jnp.float32)
-        new_p = p32 - ss_ref[0, 0] * limited.astype(jnp.float32)
+        new_p = p32 - sc_ref[0] * limited.astype(jnp.float32)
         if wd:
-            new_p = new_p - wd_ref[0, 0] * p32
+            new_p = new_p - sc_ref[1] * p32
         p_out_ref[0] = new_p.astype(p_out_ref.dtype)
         m_out_ref[0] = m.astype(m_out_ref.dtype)
         v_out_ref[0] = v.astype(v_out_ref.dtype)
 
     if not use_limiter:
         write(jnp.float32(1.0))
-        norm_ref[0, 0] = prev  # limiter off: prev_norm passes through
+        norm_ref[leaf] = prev  # limiter off: prev_norm passes through
         return
 
-    xr = gt.astype(jnp.float32)
-    part = jnp.sum(xr * xr)
+    part = lanes.masked_ssq(gt.astype(jnp.float32), i * x.shape[0], rows)
 
     @pl.when(phase == 0)
     def _():
-        acc = jnp.where(i == 0, jnp.float32(0.0), norm_ref[0, 0])
-        norm_ref[0, 0] = acc + part
+        acc = jnp.where(i == 0, jnp.float32(0.0), norm_ref[leaf])
+        norm_ref[leaf] = acc + part
         # On hardware, every output window a grid step maps is copied back
         # to HBM when the step ends, written or not — and p/m/v alias
         # their inputs, so leaving them unwritten here would clobber the
         # state phase 1 re-reads with undefined VMEM.  Pass the inputs
-        # through unmodified (interpret mode masks this; the TPU parity
-        # test below pins it).
+        # through unmodified (interpret mode masks this; the chip parity
+        # phase of chip_smoke.py pins it).
         p_out_ref[0] = p_ref[0]
         m_out_ref[0] = m_ref[0]
         v_out_ref[0] = v_ref[0]
 
     @pl.when(phase == 1)
     def _():
-        norm = jnp.sqrt(norm_ref[0, 0])
+        norm = jnp.sqrt(norm_ref[leaf])
         scale = _limiter_scale(norm, prev, gamma)
         write(scale)
 
         @pl.when(i == gm - 1)
         def _():
             # zero-norm step preserves limiter history (core.limiter)
-            norm_ref[0, 0] = jnp.where(norm > 0, norm * scale, prev)
+            norm_ref[leaf] = jnp.where(norm > 0, norm * scale, prev)
+
+
+def _fused_specs(bm: int, widths):
+    """Per-leaf row-stripe BlockSpecs over the ``(L, phases, gm)`` grid."""
+    return [pl.BlockSpec((1, bm, w), lambda l, ph, i: (l, i, 0))
+            for w in widths]
 
 
 def gwt_adam_tile_fused(g: jax.Array, p: jax.Array, m_st: jax.Array,
@@ -389,110 +261,125 @@ def gwt_adam_tile_fused(g: jax.Array, p: jax.Array, m_st: jax.Array,
     ``new_norm`` f32 ``(L,)``.
     """
     L, mm, nn = g.shape
-    if nn % (1 << level) != 0:
-        raise ValueError(f"n={nn} not divisible by 2^{level}")
+    _check_width(nn, level)
     bm = fused_row_block(mm, nn, level)
-    gm = mm // bm
     na = nn >> level
     phases = 2 if use_limiter else 1
-    pn2 = prev_norm.astype(jnp.float32).reshape(L, 1)
-    ss2 = jnp.asarray(step_size, jnp.float32).reshape(1, 1)
-    wd2 = jnp.asarray(wd_coef, jnp.float32).reshape(1, 1)
-    tile = lambda w: pl.BlockSpec((1, bm, w), lambda l, ph, i: (l, i, 0))
-    leaf_scalar = pl.BlockSpec((1, 1), lambda l, ph, i: (l, 0))
-    scalar = pl.BlockSpec((1, 1), lambda l, ph, i: (0, 0))
-    new_p, new_m, new_v, new_norm = pl.pallas_call(
+    scalars = jnp.stack([jnp.asarray(step_size, jnp.float32),
+                         jnp.asarray(wd_coef, jnp.float32)])
+    return pl.pallas_call(
         functools.partial(_body_fused, level, b1, b2, eps, gamma,
-                          use_limiter, weight_decay),
-        grid=(L, phases, gm),
-        in_specs=[tile(nn), tile(nn), tile(na), tile(na),
-                  leaf_scalar, scalar, scalar],
-        out_specs=[tile(nn), tile(na), tile(na), leaf_scalar],
+                          use_limiter, weight_decay, mm),
+        grid=(L, phases, pl.cdiv(mm, bm)),
+        in_specs=[_SMEM, _SMEM] + _fused_specs(bm, [nn, nn, na, na]),
+        out_specs=_fused_specs(bm, [nn, na, na]) + [_SMEM],
         out_shape=[
             jax.ShapeDtypeStruct((L, mm, nn), p.dtype),
             jax.ShapeDtypeStruct((L, mm, na), m_st.dtype),
             jax.ShapeDtypeStruct((L, mm, na), v_st.dtype),
-            jax.ShapeDtypeStruct((L, 1), jnp.float32),
+            jax.ShapeDtypeStruct((L,), jnp.float32),
         ],
         # in-place write semantics: p/m/v are updated in their own
         # buffers (each tile reads its block before writing it; phase 0
         # writes the inputs through unchanged).  NOT prev_norm→new_norm:
-        # phase 0
-        # accumulates ssq into the norm output while phase 1 still reads
-        # the history from pn_ref — aliasing them would clobber it.
-        input_output_aliases={1: 0, 2: 1, 3: 2},
+        # phase 0 accumulates ssq into the norm output while phase 1 still
+        # reads the history from pn_ref — aliasing them would clobber it.
+        input_output_aliases={3: 0, 4: 1, 5: 2},
         interpret=interpret,
-    )(g, p, m_st, v_st, pn2, ss2, wd2)
-    return new_p, new_m, new_v, new_norm.reshape(L)
+    )(prev_norm.astype(jnp.float32), scalars, g, p, m_st, v_st)
+
+
+def _expand_scales(s: jax.Array, width: int, block: int) -> jax.Array:
+    """Per-element scale ``(bm, width)`` from per-block scales
+    ``(bm, ceil(width/block))``: lane selects, no reshape."""
+    bid = jax.lax.broadcasted_iota(jnp.int32, (s.shape[0], width), 1) // block
+    col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    out = jnp.zeros((s.shape[0], width), jnp.float32)
+    for j in range(s.shape[1]):
+        sj = jnp.sum(jnp.where(col == j, s, 0.0), axis=1, keepdims=True)
+        out = jnp.where(bid == j, sj, out)
+    return out
+
+
+def _requant(arr, salt, row0, block: int):
+    """``codec.blocked_quant`` of a row tile: returns ``(q int8, scales)``
+    with scales ``(bm, ceil(width/block))``.  The rounding bits hash the
+    element's row-major index in the whole leaf, so any tiling (and the
+    phase-1 recompute) requantizes identically."""
+    bm, width = arr.shape
+    nbr = -(-width // block)
+    bid = jax.lax.broadcasted_iota(jnp.int32, arr.shape, 1) // block
+    col = jax.lax.broadcasted_iota(jnp.int32, (bm, nbr), 1)
+    mag = jnp.abs(arr)
+    scales = jnp.zeros((bm, nbr), jnp.float32)
+    inv = jnp.zeros(arr.shape, jnp.float32)
+    for j in range(nbr):
+        amax = jnp.max(jnp.where(bid == j, mag, 0.0), axis=1, keepdims=True)
+        sj = amax * jnp.float32(1.0 / 127.0)
+        scales = jnp.where(col == j, sj, scales)
+        inv = jnp.where(bid == j, jnp.where(sj > 0, 1.0 / sj, 0.0), inv)
+    y = arr * inv
+    idx = (lanes.global_rows(arr.shape, row0) * width
+           + jax.lax.broadcasted_iota(jnp.int32, arr.shape, 1))
+    lo = jnp.floor(y)
+    q = lo + (codec_lib.uniform01(salt, idx) < (y - lo)).astype(jnp.float32)
+    return jnp.clip(q, -127.0, 127.0).astype(jnp.int8), scales
 
 
 def _body_fused_q8(level: int, b1: float, b2: float, eps: float,
                    gamma: float, use_limiter: bool, wd: bool, block: int,
+                   rows: int, pn_ref, sc_ref, salt_ref,
                    g_ref, p_ref, qm_ref, sm_ref, qv_ref, sv_ref,
-                   saltm_ref, saltv_ref, pn_ref, ss_ref, wd_ref,
                    p_out_ref, qm_out_ref, sm_out_ref, qv_out_ref,
                    sv_out_ref, norm_ref):
     """q8 sibling of ``_body_fused``: blocked-int8 moments are dequantized
     in the prologue and stochastically requantized in the write phase (the
     rounding bits are a pure function of (salt, flat index), so the
-    phase-1 recompute requantizes identically)."""
+    phase-1 recompute requantizes identically).  ``salt_ref`` holds the
+    per-leaf m salts then the v salts, as int32 bit patterns."""
+    leaf = pl.program_id(0)
     phase = pl.program_id(1)
     i = pl.program_id(2)
     gm = pl.num_programs(2)
+    nleaves = pl.num_programs(0)
     x = g_ref[0].astype(jnp.float32)
-    bm, bn = x.shape
-    bna = bn >> level
-    sb = (bm * bna) // block
+    bm = x.shape[0]
+    na = x.shape[1] >> level
 
     def dequant(q_ref, s_ref):
-        q = q_ref[0].astype(jnp.float32).reshape(sb, block)
-        return (q * s_ref[0][:, 0][:, None]).reshape(bm, bna)
+        return q_ref[0].astype(jnp.float32) * _expand_scales(
+            jnp.transpose(s_ref[0]), na, block)
 
     out, m, v = _dht_adam_core(x, dequant(qm_ref, sm_ref),
                                dequant(qv_ref, sv_ref), level, b1, b2, eps)
     gt = out.astype(g_ref.dtype)
-    prev = pn_ref[0, 0]
-
-    base = i * (bm * bna)
-    idx = (base
-           + jax.lax.broadcasted_iota(jnp.int32, (sb, block), 0) * block
-           + jax.lax.broadcasted_iota(jnp.int32, (sb, block), 1))
-
-    def requant(arr, salt, q_out, s_out):
-        blocks = arr.reshape(sb, block)
-        absmax = jnp.max(jnp.abs(blocks), axis=1)
-        scale = absmax * jnp.float32(1.0 / 127.0)
-        inv = jnp.where(scale > 0, 1.0 / scale, 0.0).astype(jnp.float32)
-        y = blocks * inv[:, None]
-        lo = jnp.floor(y)
-        q = lo + (codec_lib.uniform01(salt, idx) < (y - lo)).astype(
-            jnp.float32)
-        q_out[0] = jnp.clip(q, -127.0, 127.0).astype(jnp.int8).reshape(
-            bm, bna)
-        s_out[0] = scale[:, None]
+    prev = pn_ref[leaf]
 
     def write(scale):
         limited = gt * scale.astype(gt.dtype)
         p32 = p_ref[0].astype(jnp.float32)
-        new_p = p32 - ss_ref[0, 0] * limited.astype(jnp.float32)
+        new_p = p32 - sc_ref[0] * limited.astype(jnp.float32)
         if wd:
-            new_p = new_p - wd_ref[0, 0] * p32
+            new_p = new_p - sc_ref[1] * p32
         p_out_ref[0] = new_p.astype(p_out_ref.dtype)
-        requant(m, saltm_ref[0, 0], qm_out_ref, sm_out_ref)
-        requant(v, saltv_ref[0, 0], qv_out_ref, sv_out_ref)
+        for arr, salt, q_out, s_out in (
+                (m, salt_ref[leaf], qm_out_ref, sm_out_ref),
+                (v, salt_ref[nleaves + leaf], qv_out_ref, sv_out_ref)):
+            q, sc = _requant(arr, salt, i * bm, block)
+            q_out[0] = q
+            s_out[0] = jnp.transpose(sc)
 
     if not use_limiter:
         write(jnp.float32(1.0))
-        norm_ref[0, 0] = prev
+        norm_ref[leaf] = prev
         return
 
-    xr = gt.astype(jnp.float32)
-    part = jnp.sum(xr * xr)
+    part = lanes.masked_ssq(gt.astype(jnp.float32), i * bm, rows)
 
     @pl.when(phase == 0)
     def _():
-        acc = jnp.where(i == 0, jnp.float32(0.0), norm_ref[0, 0])
-        norm_ref[0, 0] = acc + part
+        acc = jnp.where(i == 0, jnp.float32(0.0), norm_ref[leaf])
+        norm_ref[leaf] = acc + part
         # hardware copy-out of unwritten aliased windows would clobber
         # the state phase 1 re-reads — pass inputs through unmodified
         # (see _body_fused)
@@ -504,13 +391,13 @@ def _body_fused_q8(level: int, b1: float, b2: float, eps: float,
 
     @pl.when(phase == 1)
     def _():
-        norm = jnp.sqrt(norm_ref[0, 0])
+        norm = jnp.sqrt(norm_ref[leaf])
         scale = _limiter_scale(norm, prev, gamma)
         write(scale)
 
         @pl.when(i == gm - 1)
         def _():
-            norm_ref[0, 0] = jnp.where(norm > 0, norm * scale, prev)
+            norm_ref[leaf] = jnp.where(norm > 0, norm * scale, prev)
 
 
 def gwt_adam_tile_fused_q8(g: jax.Array, p: jax.Array, qm: jax.Array,
@@ -524,54 +411,44 @@ def gwt_adam_tile_fused_q8(g: jax.Array, p: jax.Array, qm: jax.Array,
                            interpret: bool = False):
     """Fused-write q8 update for a whole ``(L, m, n)`` bucket in one launch.
 
-    ``qm/qv``: int8 ``(L, m, n>>level)``; ``sm/sv``: f32 ``(L, nb)``
-    flat-block scales; ``salt_m/salt_v``: uint32 ``(L,)`` per-leaf slot
-    salts.  Returns ``(new_p, qm', sm', qv', sv', new_norm)``.
+    ``qm/qv``: int8 ``(L, m, n>>level)``; ``sm/sv``: f32 ``(L, nbr, m)``
+    per-row-block scales (``codec.blocked_quant`` layout); ``salt_m/
+    salt_v``: uint32 ``(L,)`` per-leaf slot salts.  Returns ``(new_p, qm',
+    sm', qv', sv', new_norm)``.
     """
     L, mm, nn = g.shape
-    if nn % (1 << level) != 0:
-        raise ValueError(f"n={nn} not divisible by 2^{level}")
+    _check_width(nn, level)
     bm = q8_row_block(mm, nn, level, block)
-    if bm is None:
-        raise ValueError(f"q8 fused kernel: ({mm},{nn}) level={level} not "
-                         f"block-{block} alignable — use the jnp oracle")
     na = nn >> level
-    nb = (mm * na) // block
-    sb = (bm * na) // block
-    gm = mm // bm
+    nbr = -(-na // block)
     phases = 2 if use_limiter else 1
-    u32 = jnp.uint32
-    sm3, sv3 = sm.reshape(L, nb, 1), sv.reshape(L, nb, 1)
-    saltm2 = jnp.asarray(salt_m, u32).reshape(L, 1)
-    saltv2 = jnp.asarray(salt_v, u32).reshape(L, 1)
-    pn2 = prev_norm.astype(jnp.float32).reshape(L, 1)
-    ss2 = jnp.asarray(step_size, jnp.float32).reshape(1, 1)
-    wd2 = jnp.asarray(wd_coef, jnp.float32).reshape(1, 1)
-    tile = lambda w: pl.BlockSpec((1, bm, w), lambda l, ph, i: (l, i, 0))
-    stile = pl.BlockSpec((1, sb, 1), lambda l, ph, i: (l, i, 0))
-    leaf_scalar = pl.BlockSpec((1, 1), lambda l, ph, i: (l, 0))
-    scalar = pl.BlockSpec((1, 1), lambda l, ph, i: (0, 0))
-    new_p, qm2, smo, qv2, svo, new_norm = pl.pallas_call(
+    scalars = jnp.stack([jnp.asarray(step_size, jnp.float32),
+                         jnp.asarray(wd_coef, jnp.float32)])
+    salts = jax.lax.bitcast_convert_type(
+        jnp.concatenate([jnp.asarray(salt_m, jnp.uint32).reshape(L),
+                         jnp.asarray(salt_v, jnp.uint32).reshape(L)]),
+        jnp.int32)
+    tiles = _fused_specs(bm, [nn, nn, na])
+    stile = pl.BlockSpec((1, nbr, bm), lambda l, ph, i: (l, 0, i))
+    tiles = tiles + [stile, tiles[2], stile]
+    return pl.pallas_call(
         functools.partial(_body_fused_q8, level, b1, b2, eps, gamma,
-                          use_limiter, weight_decay, block),
-        grid=(L, phases, gm),
-        in_specs=[tile(nn), tile(nn), tile(na), stile, tile(na), stile,
-                  leaf_scalar, leaf_scalar, leaf_scalar, scalar, scalar],
-        out_specs=[tile(nn), tile(na), stile, tile(na), stile, leaf_scalar],
+                          use_limiter, weight_decay, block, mm),
+        grid=(L, phases, pl.cdiv(mm, bm)),
+        in_specs=[_SMEM, _SMEM, _SMEM] + tiles,
+        out_specs=tiles[1:] + [_SMEM],
         out_shape=[
             jax.ShapeDtypeStruct((L, mm, nn), p.dtype),
             jax.ShapeDtypeStruct((L, mm, na), jnp.int8),
-            jax.ShapeDtypeStruct((L, nb, 1), jnp.float32),
+            jax.ShapeDtypeStruct((L, nbr, mm), jnp.float32),
             jax.ShapeDtypeStruct((L, mm, na), jnp.int8),
-            jax.ShapeDtypeStruct((L, nb, 1), jnp.float32),
-            jax.ShapeDtypeStruct((L, 1), jnp.float32),
+            jax.ShapeDtypeStruct((L, nbr, mm), jnp.float32),
+            jax.ShapeDtypeStruct((L,), jnp.float32),
         ],
         # in-place p and int8 payload/scale updates (reads precede writes
         # within each tile; phase 0 writes the inputs through unchanged).
         # prev_norm is deliberately NOT aliased to new_norm — see
         # gwt_adam_tile_fused.
-        input_output_aliases={1: 0, 2: 1, 3: 2, 4: 3, 5: 4},
+        input_output_aliases={4: 0, 5: 1, 6: 2, 7: 3, 8: 4},
         interpret=interpret,
-    )(g, p, qm, sm3, qv, sv3, saltm2, saltv2, pn2, ss2, wd2)
-    return (new_p, qm2, smo.reshape(L, nb), qv2, svo.reshape(L, nb),
-            new_norm.reshape(L))
+    )(prev_norm.astype(jnp.float32), scalars, salts, g, p, qm, sm, qv, sv)

@@ -18,6 +18,7 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro import compat
 from repro.kernels.gwt_adam import kernel, ref
@@ -70,73 +71,6 @@ def _fused_update(g, state, step, *, level, b1, b2, eps, impl):
 
 
 # ---------------------------------------------------------------------------
-# q8 path: blocked-int8 moments (state codec 'int8'), requant fused in.
-# ---------------------------------------------------------------------------
-
-def _tile_fn_q8(impl: str, shape, level: int, block: int,
-                b1: float, b2: float, eps: float):
-    """Per-(impl, leaf-shape) q8 tile function.  The Pallas path needs
-    block-aligned row tiles (``kernel.q8_row_block``); shapes it cannot
-    tile fall back to the jnp oracle — a static, per-bucket decision."""
-    if impl in ("pallas", "interpret") and \
-            kernel.q8_row_block(shape[-2], shape[-1], level, block) is not None:
-        return functools.partial(kernel.gwt_adam_tile_q8, level=level,
-                                 block=block, b1=b1, b2=b2, eps=eps,
-                                 interpret=impl == "interpret")
-    return functools.partial(ref.gwt_adam_tile_q8, level=level, block=block,
-                             b1=b1, b2=b2, eps=eps)
-
-
-def fused_update_q8(g: jax.Array, state: dict, step: jax.Array,
-                    key: jax.Array, leaf_ids: jax.Array, *,
-                    level: int, block: int = 64, b1: float = 0.9,
-                    b2: float = 0.999, eps: float = 1e-6,
-                    impl: str = "auto") -> Tuple[jax.Array, jax.Array, dict]:
-    """``fused_update`` over blocked-int8 moments: ``state`` is the encoded
-    layout ``{"m": {"q", "scale"}, "v": {"q", "scale"}}``; dequant → update
-    → stochastic requant happens inside the tile (Pallas epilogue or jnp
-    oracle).  ``key`` is ``opt_state["codec_key"]``; ``leaf_ids`` the
-    bucket's flatten-order leaf indices (scalar for a single leaf) — the
-    per-slot salts (m=0, v=1) match ``codec.map_slots`` order, so this
-    path rounds identically to the engine's generic scan wrap."""
-    impl = compat.resolve_kernel_impl(impl)
-    return _fused_update_q8(g, state["m"]["q"], state["m"]["scale"],
-                            state["v"]["q"], state["v"]["scale"],
-                            step, key, leaf_ids, level=level, block=block,
-                            b1=b1, b2=b2, eps=eps, impl=impl)
-
-
-@functools.partial(jax.jit, static_argnames=("level", "block", "b1", "b2",
-                                             "eps", "impl"))
-def _fused_update_q8(g, qm, sm, qv, sv, step, key, leaf_ids, *,
-                     level, block, b1, b2, eps, impl):
-    from repro.optim import codec as codec_lib
-    salt_m = codec_lib.slot_salt(key, step, 0, leaf_ids)
-    salt_v = codec_lib.slot_salt(key, step, 1, leaf_ids)
-    if g.ndim > 2:  # stacked scan leaves (L, *extra, m, n)
-        # The codec blocks/salts over each leaf's row-major FLAT order, so
-        # a 3-D+ leaf's extra dims can't become vmap axes (scales and
-        # rounding indices span them).  Merging them into the row axis
-        # keeps the flat order bit-identical and the DHT is per-row, so
-        # the tile math is unchanged; vmap only over the leaf axis L.
-        row = lambda a: a.reshape(a.shape[0], -1, a.shape[-1])
-        g2 = row(g)
-        fn = _tile_fn_q8(impl, g2.shape, level, block, b1, b2, eps)
-        gt, qm2, sm2, qv2, sv2, _ = jax.vmap(fn)(
-            g2, row(qm), sm, row(qv), sv,
-            salt_m.reshape(-1), salt_v.reshape(-1))
-        gt = gt.reshape(g.shape)
-        qm2, qv2 = qm2.reshape(qm.shape), qv2.reshape(qv.shape)
-    else:
-        fn = _tile_fn_q8(impl, g.shape, level, block, b1, b2, eps)
-        gt, qm2, sm2, qv2, sv2, _ = fn(g, qm, sm, qv, sv, salt_m, salt_v)
-    t = step.astype(jnp.float32) + 1.0
-    lr_mult = jnp.sqrt(1 - b2 ** t) / (1 - b1 ** t)
-    return gt, lr_mult, {"m": {"q": qm2, "scale": sm2},
-                         "v": {"q": qv2, "scale": sv2}}
-
-
-# ---------------------------------------------------------------------------
 # Fused-write (megakernel) path: limiter + bias-corrected apply + weight
 # decay + parameter write move INTO the launch — one kernel call per bucket
 # consumes (g, p, m, v, prev_norm) and emits (new_p, new_m, new_v,
@@ -152,6 +86,26 @@ def _step_scalars(step, lr_t, alpha, weight_decay, b1, b2):
     step_size = (lr_t * lr_mult * alpha).astype(jnp.float32)
     wd_coef = jnp.asarray(lr_t * weight_decay, jnp.float32)
     return step_size, wd_coef
+
+
+def _on_each_device(call):
+    """Under an ambient mesh, run the kernel whole on every device.
+
+    GSPMD cannot partition a Mosaic kernel, so a bucket whose state is
+    sharded (FSDP) would not compile.  A ``shard_map`` with replicated
+    specs gathers the bucket's operands, runs the same launch on each
+    device and hands back replicated results, which the caller's sharding
+    constraints slice again.  The update stays exactly the single-device
+    one; what it costs is the gathers and the repeated work."""
+    mesh = compat.get_abstract_mesh()
+    if mesh is None:
+        return call
+
+    def run(*args):
+        specs = tuple(P() for _ in args)
+        return compat.shard_map(call, mesh, in_specs=specs,
+                                out_specs=P())(*args)
+    return run
 
 
 def _norm_shapes(g):
@@ -205,9 +159,9 @@ def _fused_write_update(g, p, m_st, v_st, prev_norm, step, lr_t, *,
     kw = dict(level=level, gamma=gamma, use_limiter=use_limiter,
               weight_decay=weight_decay != 0, b1=b1, b2=b2, eps=eps)
     if impl in ("pallas", "interpret"):
-        new_p, m, v, new_norm = kernel.gwt_adam_tile_fused(
-            g3, p3, m3, v3, pn, step_size, wd_coef,
-            interpret=impl == "interpret", **kw)
+        new_p, m, v, new_norm = _on_each_device(functools.partial(
+            kernel.gwt_adam_tile_fused, interpret=impl == "interpret",
+            **kw))(g3, p3, m3, v3, pn, step_size, wd_coef)
     else:
         new_p, m, v, new_norm = ref.gwt_adam_fused(
             g3, p3, m3, v3, pn, step_size, wd_coef,
@@ -230,10 +184,10 @@ def fused_write_update_q8(g: jax.Array, p: jax.Array, state: dict,
                           b2: float = 0.999, eps: float = 1e-6,
                           impl: str = "auto"):
     """``fused_write_update`` over blocked-int8 moments: dequant → update →
-    stochastic requant AND limit+apply+write all inside the launch.  Shapes
-    the q8 kernel cannot tile block-aligned fall back to the jnp oracle —
-    a static, per-bucket decision.  Returns ``(new_p, new_norm,
-    new_state)`` in the encoded layout."""
+    stochastic requant AND limit+apply+write all inside the launch.  The
+    codec's per-row blocks tile every shape, so ``pallas``/``interpret``
+    always run the kernel.  Returns ``(new_p, new_norm, new_state)`` in
+    the encoded layout."""
     impl = compat.resolve_kernel_impl(impl)
     return _fused_write_update_q8(
         g, p, state["m"]["q"], state["m"]["scale"],
@@ -258,22 +212,23 @@ def _fused_write_update_q8(g, p, qm, sm, qv, sv, prev_norm, step, key,
     qm3, _, _ = _norm_shapes(qm)
     qv3, _, _ = _norm_shapes(qv)
     L, mm, nn = g3.shape
-    sm2, sv2 = sm.reshape(L, -1), sv.reshape(L, -1)
+    sm2, sv2 = sm.reshape(L, -1, mm), sv.reshape(L, -1, mm)
     salt_m = codec_lib.slot_salt(key, step, 0, leaf_ids).reshape(L)
     salt_v = codec_lib.slot_salt(key, step, 1, leaf_ids).reshape(L)
     pn = prev_norm.reshape(L)
-    bm = kernel.q8_row_block(mm, nn, level, block)
     kw = dict(level=level, block=block, gamma=gamma,
               use_limiter=use_limiter, weight_decay=weight_decay != 0,
               b1=b1, b2=b2, eps=eps)
-    if impl in ("pallas", "interpret") and bm is not None:
-        new_p, qm2, smo, qv2, svo, new_norm = kernel.gwt_adam_tile_fused_q8(
+    if impl in ("pallas", "interpret"):
+        new_p, qm2, smo, qv2, svo, new_norm = _on_each_device(
+            functools.partial(kernel.gwt_adam_tile_fused_q8,
+                              interpret=impl == "interpret", **kw))(
             g3, p3, qm3, sm2, qv3, sv2, salt_m, salt_v, pn, step_size,
-            wd_coef, interpret=impl == "interpret", **kw)
+            wd_coef)
     else:
         new_p, qm2, smo, qv2, svo, new_norm = ref.gwt_adam_fused_q8(
             g3, p3, qm3, sm2, qv3, sv2, salt_m, salt_v, pn, step_size,
-            wd_coef, bm=bm if bm is not None else mm, **kw)
+            wd_coef, bm=kernel.q8_row_block(mm, nn, level, block), **kw)
     new_p = new_p.reshape(gshape)
     qshape = gshape[:-1] + (nn >> level,)
     qm2, qv2 = qm2.reshape(qshape), qv2.reshape(qshape)

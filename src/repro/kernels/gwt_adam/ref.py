@@ -6,6 +6,17 @@ op rounds separately and near-cancelling approximation coefficients can
 land one f32 ulp away from the kernel's — which the ``1/(√V+ε)`` detail
 scaling then amplifies across a bf16 rounding boundary (a single-element
 8192-magnitude mismatch at ~2^20 magnitudes).
+
+The kernel's lane shuffles are matmuls (``repro.kernels.lanes``), so each
+butterfly level starts from a materialized array, and XLA:CPU may contract
+the jnp butterfly's multiply of one level into the next level's add (an
+FMA) where the kernel cannot.  The oracle therefore runs the kernel's own
+per-tile math (``kernel._dht_adam_core``) on whole leaves; what it checks
+independently is the tiling, the two-phase limiter norm, the write chain,
+the int8 requantize and the SMEM/aliasing plumbing.  The shuffles
+themselves are pinned bitwise against jnp reshapes (tests/test_kernels.py)
+and the whole update against the ``core.haar`` butterfly within a stated
+tolerance.
 """
 
 from __future__ import annotations
@@ -16,22 +27,18 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.core import haar
+from repro.kernels.gwt_adam import kernel
 
 
 @functools.partial(jax.jit, static_argnames=("level", "b1", "b2", "eps"))
 def gwt_adam_tile(g: jax.Array, m_st: jax.Array, v_st: jax.Array, *,
                   level: int, b1: float = 0.9, b2: float = 0.999,
                   eps: float = 1e-6) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
-    g32 = g.astype(jnp.float32)
-    a, details = haar.haar_forward(g32, level)
-    m = b1 * m_st.astype(jnp.float32) + (1 - b1) * a
-    v = b2 * v_st.astype(jnp.float32) + (1 - b2) * a * a
-    inv_denom = 1.0 / (jnp.sqrt(v) + eps)
-    a_t = m * inv_denom
-    tilde_d = [d * haar.detail_scale_upsample(inv_denom, level, level - i)
-               for i, d in enumerate(details)]
-    gt = haar.haar_inverse(a_t, tilde_d).astype(g.dtype)
+    out, m, v = kernel._dht_adam_core(g.astype(jnp.float32),
+                                      m_st.astype(jnp.float32),
+                                      v_st.astype(jnp.float32), level,
+                                      b1, b2, eps)
+    gt = out.astype(g.dtype)
     # limiter norm partials over the ROUNDED output — the norm of the g̃
     # actually emitted, matching the kernel's ssq_ref
     gr = gt.astype(jnp.float32)
@@ -51,20 +58,22 @@ def gwt_adam_tile_q8(g: jax.Array, qm: jax.Array, sm: jax.Array,
     Dequantize → ``gwt_adam_tile`` math → stochastic requantize with the
     caller-supplied per-slot salts (``repro.optim.codec`` hash — the same
     bits the Pallas epilogue and the engine's generic scan wrap produce).
+    The dequantize multiplies by the kernel's per-element scale expansion
+    (values equal to ``codec.blocked_dequant``'s broadcast, but XLA may
+    fold ``β·(q·s)`` differently around a broadcast and round an ulp off).
     Returns ``(gt, qm', sm', qv', sv', ssq)``.
     """
     from repro.optim import codec as codec_lib
-    m_st = codec_lib.blocked_dequant(qm, sm, block)
-    v_st = codec_lib.blocked_dequant(qv, sv, block)
-    g32 = g.astype(jnp.float32)
-    a, details = haar.haar_forward(g32, level)
-    m = b1 * m_st + (1 - b1) * a
-    v = b2 * v_st + (1 - b2) * a * a
-    inv_denom = 1.0 / (jnp.sqrt(v) + eps)
-    a_t = m * inv_denom
-    tilde_d = [d * haar.detail_scale_upsample(inv_denom, level, level - i)
-               for i, d in enumerate(details)]
-    gt = haar.haar_inverse(a_t, tilde_d).astype(g.dtype)
+    rows, na = qm.shape
+
+    def dequant(q, s):
+        return q.astype(jnp.float32) * kernel._expand_scales(
+            s.reshape(-1, rows).T, na, block)
+
+    m_st, v_st = dequant(qm, sm), dequant(qv, sv)
+    out, m, v = kernel._dht_adam_core(g.astype(jnp.float32), m_st, v_st,
+                                      level, b1, b2, eps)
+    gt = out.astype(g.dtype)
     gr = gt.astype(jnp.float32)
     ssq = jnp.sum(gr * gr)[None, None]
     qm2, sm2 = codec_lib.blocked_quant(m, salt_m, block)
@@ -84,10 +93,14 @@ def gwt_adam_tile_q8(g: jax.Array, qm: jax.Array, sm: jax.Array,
 
 def _tiled_norm(gt: jax.Array, bm: int) -> jax.Array:
     """‖gt‖ via the kernel's reduction order: one ``jnp.sum`` per (bm, n)
-    row stripe, partials added sequentially."""
+    row stripe, partials added sequentially; a partial last stripe is
+    zero-padded to ``bm`` rows, as the kernel masks its missing rows."""
     xr = gt.astype(jnp.float32)
+    pad = -xr.shape[0] % bm
+    if pad:
+        xr = jnp.pad(xr, ((0, pad), (0, 0)))
     acc = None
-    for k in range(gt.shape[0] // bm):
+    for k in range(xr.shape[0] // bm):
         t = xr[k * bm:(k + 1) * bm]
         part = jnp.sum(t * t)
         acc = part if acc is None else acc + part
@@ -96,7 +109,6 @@ def _tiled_norm(gt: jax.Array, bm: int) -> jax.Array:
 
 def _limit_write(gt, p, prev, step_size, wd_coef, *, gamma, use_limiter,
                  weight_decay, bm):
-    from repro.kernels.gwt_adam import kernel
     if use_limiter:
         norm = _tiled_norm(gt, bm)
         scale = kernel._limiter_scale(norm, prev, gamma)

@@ -40,7 +40,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat, configs
+from repro import configs
 from repro.core.gwt import gwt as gwt_optimizer
 from repro.distributed import sharding as shr
 from repro.launch.mesh import make_production_mesh
@@ -131,7 +131,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
             compiled = lowered.compile()
             t_compile = time.time() - t0 - t_lower
             mem = compiled.memory_analysis()
-            cost = compat.cost_analysis(compiled)
+            cost = compiled.cost_analysis() or {}
             hlo = compiled.as_text()
     except Exception as e:
         return {"arch": arch, "shape": shape_name, "multi_pod": multi_pod,

@@ -2,7 +2,7 @@
 
 FUNCTIONS, not module constants — importing this module never touches
 jax device state.  All mesh construction routes through
-:mod:`repro.compat` so the same code runs on jax 0.4.x–0.6.x.
+:mod:`repro.compat`.
 """
 
 from __future__ import annotations

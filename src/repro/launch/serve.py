@@ -23,6 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import configs
+from repro.launch.cache import enable_compile_cache
 from repro.models import lm
 from repro.runtime.context import MeshContext
 
@@ -164,6 +165,7 @@ def main(argv=None):
                          "depth, slot occupancy, page-arena utilization) "
                          "-> <dir>/trace.json")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     from repro import obs
     tel = obs.configure(args.metrics_dir or None,
                         run={"cmd": "serve", "arch": args.arch,

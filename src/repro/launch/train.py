@@ -32,6 +32,7 @@ from repro import configs, obs, optim
 from repro.checkpoint.manager import CheckpointManager
 from repro.data.pipeline import make_source
 from repro.distributed.compression import DPReduceSpec
+from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import make_mesh_context
 from repro.models import encdec, lm
 from repro.optim.schedules import warmup_cosine
@@ -157,6 +158,7 @@ def main(argv=None):
                          "compiles away — training numerics stay "
                          "bitwise-identical")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     tel = obs.configure(args.metrics_dir or None,
                         run={"cmd": "train", "arch": args.arch,

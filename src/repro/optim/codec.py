@@ -7,12 +7,15 @@ codec:
 
 * ``f32`` — passthrough (default).  The engine skips the codec entirely,
   so updates are bitwise-identical to the pre-codec engine.
-* ``int8`` — blocked 8-bit: each slot array is flattened (row-major) and
+* ``int8`` — blocked 8-bit: each row of a slot array (its last axis) is
   quantized in blocks of ``block`` elements against a per-block absmax
   scale (``scale = absmax/127``), with **stochastic rounding** so repeated
   requantization stays unbiased (FOAM / bitsandbytes-style).  The encoded
-  slot is ``{"q": int8 (original shape), "scale": f32 (nb,)}`` with
-  ``nb = ceil(size/block)`` — ~``1/4 + 1/(4·block)`` of the f32 bytes.
+  slot is ``{"q": int8 (original shape), "scale": f32 (nbr, rows)}``
+  with ``nbr = ceil(width/block)`` blocks per row — ~``1/4 +
+  1/(4·block)`` of the f32 bytes.  Blocks never straddle rows, so a
+  kernel that tiles rows holds whole blocks whatever the width, and the
+  scales of a row tile are a lane-dense ``(nbr, bm)`` block.
 
 Rounding randomness is **counter-based**, not ``jax.random``: a
 murmur-style uint32 mixing hash of ``(codec_key, step, slot_idx, leaf_id,
@@ -76,25 +79,44 @@ def uniform01(salt, idx: jax.Array) -> jax.Array:
     24 mantissa-exact bits."""
     bits = _fmix(jnp.asarray(salt, jnp.uint32)
                  ^ (idx.astype(jnp.uint32) * jnp.uint32(_GOLD)))
-    return (bits >> 8).astype(jnp.float32) * jnp.float32(1.0 / (1 << 24))
+    # via int32: the 24-bit value is exact there, and Mosaic has no
+    # uint32 -> f32 conversion
+    return (bits >> 8).astype(jnp.int32).astype(jnp.float32) \
+        * jnp.float32(1.0 / (1 << 24))
 
 
 # ---------------------------------------------------------------------------
 # Blocked int8 quantization with stochastic rounding
 # ---------------------------------------------------------------------------
 
-def num_blocks(size: int, block: int = DEFAULT_BLOCK) -> int:
-    return max(1, -(-size // block))
+def _rows_width(shape) -> tuple:
+    """``(rows, width)`` of the row view the codec blocks: the last axis is
+    the row; a scalar is one row of one element."""
+    width = int(shape[-1]) if shape else 1
+    rows = 1
+    for d in shape[:-1]:
+        rows *= int(d)
+    return rows, width
+
+
+def scale_shape(shape, block: int = DEFAULT_BLOCK) -> tuple:
+    """``(blocks per row, rows)`` — the encoded scale array's shape."""
+    rows, width = _rows_width(tuple(shape))
+    return (max(1, -(-width // block)), rows)
 
 
 def blocked_quant(x: jax.Array, salt, block: int = DEFAULT_BLOCK,
                   rounding: str = "stochastic"):
-    """``x -> (q int8 (x.shape), scale f32 (nb,))``; row-major flat blocks.
+    """``x -> (q int8 (x.shape), scale f32 (nbr, rows))``; per-row blocks.
 
-    ``scale = absmax/127`` per block; elements are divided by their block's
-    scale and stochastically rounded (``floor(y) + (u < frac(y))`` with
-    ``u = uniform01(salt, flat_idx)``) — unbiased, error ≤ one quantum
-    (= scale).  All-zero blocks encode as ``scale = 0`` exactly.
+    Each row (last axis) is cut into ``nbr = ceil(width/block)`` blocks,
+    the last one short when ``block`` does not divide the width;
+    ``scale[j, r]`` belongs to block ``j`` of row ``r``.  ``scale = absmax/127``
+    per block; elements are divided by their block's scale and
+    stochastically rounded (``floor(y) + (u < frac(y))`` with ``u =
+    uniform01(salt, flat_idx)``, ``flat_idx`` the element's row-major
+    index) — unbiased, error ≤ one quantum (= scale).  All-zero blocks
+    encode as ``scale = 0`` exactly.
 
     ``rounding="nearest"`` rounds to the nearest level instead (``salt``
     is ignored): half the worst-case error, but biased under repeated
@@ -102,39 +124,43 @@ def blocked_quant(x: jax.Array, salt, block: int = DEFAULT_BLOCK,
     which encodes each entry exactly once), wrong for optimizer moments.
     """
     shape = tuple(x.shape)
-    n = int(x.size)
-    nb = num_blocks(n, block)
-    xf = x.astype(jnp.float32).reshape(-1)
-    if nb * block != n:
-        xf = jnp.pad(xf, (0, nb * block - n))
-    blocks = xf.reshape(nb, block)
-    absmax = jnp.max(jnp.abs(blocks), axis=1)
+    rows, width = _rows_width(shape)
+    nbr = max(1, -(-width // block))
+    xf = x.astype(jnp.float32).reshape(rows, width)
+    if nbr * block != width:
+        xf = jnp.pad(xf, ((0, 0), (0, nbr * block - width)))
+    blocks = xf.reshape(rows, nbr, block)
+    absmax = jnp.max(jnp.abs(blocks), axis=2)
     scale = absmax * jnp.float32(1.0 / 127.0)
     inv = jnp.where(scale > 0, 1.0 / scale, 0.0).astype(jnp.float32)
-    y = blocks * inv[:, None]
+    y = blocks * inv[..., None]
     if rounding == "nearest":
         q = jnp.round(y)
     elif rounding == "stochastic":
-        idx = jax.lax.iota(jnp.uint32, nb * block).reshape(nb, block)
+        col = jax.lax.broadcasted_iota(jnp.int32, (rows, nbr * block), 1)
+        row = jax.lax.broadcasted_iota(jnp.int32, (rows, nbr * block), 0)
+        idx = (row * width + col).reshape(rows, nbr, block)
         lo = jnp.floor(y)
         q = lo + (uniform01(salt, idx) < (y - lo)).astype(jnp.float32)
     else:
         raise ValueError(f"rounding {rounding!r}: expected 'stochastic' "
                          "or 'nearest'")
     q = jnp.clip(q, -127.0, 127.0).astype(jnp.int8)
-    return q.reshape(-1)[:n].reshape(shape), scale
+    q = q.reshape(rows, nbr * block)[:, :width].reshape(shape)
+    return q, scale.T
 
 
 def blocked_dequant(q: jax.Array, scale: jax.Array,
                     block: int = DEFAULT_BLOCK) -> jax.Array:
     shape = tuple(q.shape)
-    n = int(q.size)
-    nb = int(scale.shape[-1])
-    qf = q.astype(jnp.float32).reshape(-1)
-    if nb * block != n:
-        qf = jnp.pad(qf, (0, nb * block - n))
-    out = (qf.reshape(nb, block) * scale.astype(jnp.float32)[:, None])
-    return out.reshape(-1)[:n].reshape(shape)
+    rows, width = _rows_width(shape)
+    nbr = max(1, -(-width // block))
+    qf = q.astype(jnp.float32).reshape(rows, width)
+    if nbr * block != width:
+        qf = jnp.pad(qf, ((0, 0), (0, nbr * block - width)))
+    out = (qf.reshape(rows, nbr, block)
+           * scale.astype(jnp.float32).reshape(nbr, rows).T[..., None])
+    return out.reshape(rows, nbr * block)[:, :width].reshape(shape)
 
 
 # ---------------------------------------------------------------------------
@@ -170,9 +196,9 @@ class BlockedInt8Codec:
     def init(self, x):
         # zeros encode exactly (scale 0) — built structurally, no hashing,
         # so rule init stays traceable under eval_shape without a key.
-        nb = num_blocks(int(x.size), self.block)
         return {"q": jnp.zeros(tuple(x.shape), jnp.int8),
-                "scale": jnp.zeros((nb,), jnp.float32)}
+                "scale": jnp.zeros(scale_shape(x.shape, self.block),
+                                   jnp.float32)}
 
     def encode(self, x, salt):
         q, scale = blocked_quant(x, salt, self.block)

@@ -1,10 +1,8 @@
-"""The runtime portability layer: both shim branches (native API present
-vs. fallback) via monkeypatching, kernel-backend resolution, MeshContext,
-plus regressions that (a) every src/repro module imports under the pinned
-JAX and (b) no module outside repro.compat touches the drifting jax
-symbols directly."""
+"""The runtime layer over JAX's mesh API: mesh construction, the ambient
+mesh, kernel-backend resolution, MeshContext, plus regressions that (a)
+every src/repro module imports under the installed JAX and (b) no module
+outside repro.compat touches the mesh/sharding jax symbols directly."""
 
-import contextlib
 import importlib
 import os
 import pathlib
@@ -37,32 +35,8 @@ def test_axis_type_symbols_exist():
     assert len(compat.auto_axis_types(3)) == 3
 
 
-def test_make_mesh_axis_types_feature_detection(monkeypatch):
-    rec = {}
-
-    def fake(shapes, names, **kw):
-        rec.clear()
-        rec.update(kw, args=(shapes, names))
-        return "MESH"
-
-    monkeypatch.setattr(compat, "_NATIVE_MAKE_MESH", fake)
-    monkeypatch.setattr(compat, "_MAKE_MESH_AXIS_TYPES", True)
-    assert compat.make_mesh((2,), ("data",)) == "MESH"
-    assert rec["axis_types"] == compat.auto_axis_types(1)
-
-    monkeypatch.setattr(compat, "_MAKE_MESH_AXIS_TYPES", False)
-    compat.make_mesh((2,), ("data",))
-    assert "axis_types" not in rec  # older signature: kwarg dropped
-
-
-def test_make_mesh_without_native_make_mesh(monkeypatch):
-    monkeypatch.setattr(compat, "_NATIVE_MAKE_MESH", None)
-    mesh = compat.make_mesh((1,), ("data",))
-    assert tuple(mesh.axis_names) == ("data",)
-
-
 # ---------------------------------------------------------------------------
-# ambient mesh: use_mesh / get_abstract_mesh, both branches
+# ambient mesh: use_mesh / get_abstract_mesh
 # ---------------------------------------------------------------------------
 
 def test_ambient_mesh_none_by_default():
@@ -80,47 +54,6 @@ def test_use_mesh_sets_ambient_and_restores():
 def test_use_mesh_none_is_noop():
     with compat.use_mesh(None) as m:
         assert m is None
-    assert compat.get_abstract_mesh() is None
-
-
-def test_fallback_branch_forced(monkeypatch):
-    """Force the pre-0.5 path: thread-local stack + Mesh context manager."""
-    monkeypatch.setattr(compat, "_NATIVE_GET_ABSTRACT_MESH", None)
-    monkeypatch.setattr(compat, "_NATIVE_USE_MESH", None)
-    mesh = compat.make_mesh((1,), ("data",))
-    assert compat.get_abstract_mesh() is None
-    with compat.use_mesh(mesh):
-        assert compat.get_abstract_mesh() is mesh
-        with compat.use_mesh(mesh):  # nesting
-            assert compat.get_abstract_mesh() is mesh
-        assert compat.get_abstract_mesh() is mesh
-    assert compat.get_abstract_mesh() is None
-
-
-def test_native_branch_forced(monkeypatch):
-    """Force the post-0.5 path with stand-ins for the native API."""
-    mesh = compat.make_mesh((1,), ("data",))
-    monkeypatch.setattr(compat, "_NATIVE_GET_ABSTRACT_MESH", lambda: mesh)
-    assert compat.get_abstract_mesh() is mesh
-
-    calls = []
-
-    @contextlib.contextmanager
-    def fake_use(m):
-        calls.append(m)
-        yield
-
-    monkeypatch.setattr(compat, "_NATIVE_USE_MESH", fake_use)
-    with compat.use_mesh(mesh):
-        pass
-    assert calls == [mesh]
-
-
-def test_native_empty_abstract_mesh_normalized(monkeypatch):
-    class _Empty:
-        axis_names = ()
-
-    monkeypatch.setattr(compat, "_NATIVE_GET_ABSTRACT_MESH", _Empty)
     assert compat.get_abstract_mesh() is None
 
 

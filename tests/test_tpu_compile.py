@@ -1,0 +1,121 @@
+"""The main path's Pallas kernels compile for a TPU v5e at llama-1b widths.
+
+Nothing runs: each test lowers one kernel for a described (not attached)
+v5e chip and compiles it with the chip's own compiler, which refuses what
+interpret mode accepts — block shapes off the (8, 128) tiling, lane
+reshapes Mosaic cannot lower, tiles past the scoped VMEM limit.  The
+shapes are llama-1b's GWT buckets as the optimizer hands them over: each
+``(24, m, n)`` layer stack merged into ``24·m`` rows, so the MLP buckets
+are 5461-row stripes stacked 24 deep.
+
+The topology is described inside a module fixture, never at import: only
+one process may hold the TPU library, and the test workers all import
+this file.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from repro.kernels.gwt_adam import kernel as kg
+from repro.kernels.haar_dwt import kernel as kf
+
+F32, BF16, I8, U32 = jnp.float32, jnp.bfloat16, jnp.int8, jnp.uint32
+LEVEL = 2
+BLOCK = 64
+# (L, rows, n): wq/wk/wv/wo, and the w_gate/w_up pair (transposed: DHT
+# over d_model) / w_down
+ATTN = (4, 24 * 2048, 2048)
+MLP = (2, 24 * 5461, 2048)
+MLP_DOWN = (1, 24 * 5461, 2048)
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this environment
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip cannot be read back from the
+    # persistent cache without one, so keep the cache out of these tests
+    from jax.experimental.compilation_cache import compilation_cache
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", prev)
+
+
+def _compile(fn, one_chip, *shapes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    hlo = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in hlo
+    return hlo
+
+
+def _fused_args(L, m, n):
+    na = n >> LEVEL
+    return [((L, m, n), BF16), ((L, m, n), BF16), ((L, m, na), F32),
+            ((L, m, na), F32), ((L,), F32), ((), F32), ((), F32)]
+
+
+def _fused_q8_args(L, m, n):
+    na = n >> LEVEL
+    scales = (L, -(-na // BLOCK), m)  # codec.scale_shape per leaf
+    return [((L, m, n), BF16), ((L, m, n), BF16), ((L, m, na), I8),
+            (scales, F32), ((L, m, na), I8), (scales, F32),
+            ((L,), U32), ((L,), U32), ((L,), F32), ((), F32), ((), F32)]
+
+
+@pytest.mark.parametrize("shape", [ATTN, MLP], ids=["attn", "mlp"])
+def test_fused_f32_compiles(one_chip, shape):
+    fn = functools.partial(kg.gwt_adam_tile_fused, level=LEVEL, gamma=1.01,
+                           use_limiter=True, weight_decay=False)
+    _compile(fn, one_chip, *_fused_args(*shape))
+
+
+@pytest.mark.parametrize("shape,use_limiter", [
+    (ATTN, True), (MLP, True), (MLP_DOWN, False), (ATTN, False)],
+    ids=["attn-limiter", "mlp-limiter", "mlp_down-nolimiter",
+         "attn-nolimiter"])
+def test_fused_q8_compiles(one_chip, shape, use_limiter):
+    fn = functools.partial(kg.gwt_adam_tile_fused_q8, level=LEVEL,
+                           block=BLOCK, gamma=1.01, use_limiter=use_limiter,
+                           weight_decay=True)
+    _compile(fn, one_chip, *_fused_q8_args(*shape))
+
+
+def test_core_tile_compiles(one_chip):
+    m, n = 5461, 2048
+    fn = functools.partial(kg.gwt_adam_tile, level=LEVEL)
+    _compile(fn, one_chip, ((m, n), BF16), ((m, n >> LEVEL), F32),
+             ((m, n >> LEVEL), F32))
+
+
+@pytest.mark.parametrize("m,n", [(24 * 5461, 2048), (2048, 32000)],
+                         ids=["mlp_rows", "lm_head"])
+def test_dwt_fwd_compiles(one_chip, m, n):
+    _compile(functools.partial(kf.haar_dwt_fwd, level=LEVEL), one_chip,
+             ((m, n), F32))
+
+
+@pytest.mark.parametrize("detail", [BF16, jnp.float8_e4m3fn],
+                         ids=["bf16", "f8"])
+def test_dwt_wire_compiles(one_chip, detail):
+    """The compressed DP wire: f32 approximation band, narrow details, on
+    a layer stack flattened to rows."""
+    _compile(functools.partial(kf.haar_dwt_fwd_q, level=LEVEL,
+                               detail_dtype=detail),
+             one_chip, ((24 * 2048, 2048), F32))
+
+
+def test_dwt_inv_compiles(one_chip):
+    m, n = 5461, 2048
+    bands = [((m, n >> LEVEL), F32)] + [((m, n >> k), F32)
+                                       for k in range(LEVEL, 0, -1)]
+    _compile(lambda a, *d: kf.haar_dwt_inv(a, d), one_chip, *bands)
