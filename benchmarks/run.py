@@ -1448,7 +1448,8 @@ def obs_bench(quick: bool):
     evs = doc.get("traceEvents", [])
     cats = {e.get("cat") for e in evs}
     names = {e.get("name") for e in evs}
-    if not {"prefetch", "dispatch", "block"} <= names:
+    if not ({"train.input_wait", "train.place", "train.block"} <= names
+            and names & {"train.dispatch", "train.dispatch_first"}):
         probs.append(f"train spans missing from trace (names={names})")
     if "serve" not in cats:
         probs.append("no serve-category events in trace")

@@ -177,15 +177,17 @@ def gwt(lr: Schedule | float,
             # limiter, bias-corrected step, and weight decay all run in
             # the kernel epilogue, so g̃ never round-trips HBM.
             from repro.kernels.gwt_adam import ops as gwt_ops  # lazy
-            gt = jnp.swapaxes(g_stk, -1, -2) if swap else g_stk
-            pt = jnp.swapaxes(p_stk, -1, -2) if swap else p_stk
+            with jax.named_scope("optim.pack"):
+                gt = jnp.swapaxes(g_stk, -1, -2) if swap else g_stk
+                pt = jnp.swapaxes(p_stk, -1, -2) if swap else p_stk
             new_p, new_norm, hstate = gwt_ops.fused_write_update(
                 gt, pt, state["host"], step, state["prev_norm"],
                 lr_t=lr(step), alpha=alpha, weight_decay=weight_decay,
                 gamma=gamma, use_limiter=use_limiter, level=level,
                 impl=impl, **adam_kw)
             if swap:
-                new_p = jnp.swapaxes(new_p, -1, -2)
+                with jax.named_scope("optim.pack"):
+                    new_p = jnp.swapaxes(new_p, -1, -2)
             return new_p, {"host": hstate, "prev_norm": new_norm}
 
         def vector_update_q8(g_stk, p_stk, state, step, leaf_ids,
@@ -197,8 +199,9 @@ def gwt(lr: Schedule | float,
             # codec.map_slots' sorted-key order, so this path and the
             # generic scan wrap produce the same rounding bits.
             from repro.kernels.gwt_adam import ops as gwt_ops  # lazy
-            gt = jnp.swapaxes(g_stk, -1, -2) if swap else g_stk
-            pt = jnp.swapaxes(p_stk, -1, -2) if swap else p_stk
+            with jax.named_scope("optim.pack"):
+                gt = jnp.swapaxes(g_stk, -1, -2) if swap else g_stk
+                pt = jnp.swapaxes(p_stk, -1, -2) if swap else p_stk
             new_p, new_norm, hstate = gwt_ops.fused_write_update_q8(
                 gt, pt, state["host"], step, codec_key, leaf_ids,
                 state["prev_norm"], lr_t=lr(step), alpha=alpha,
@@ -206,7 +209,8 @@ def gwt(lr: Schedule | float,
                 use_limiter=use_limiter, level=level, block=cdc.block,
                 impl=impl, **adam_kw)
             if swap:
-                new_p = jnp.swapaxes(new_p, -1, -2)
+                with jax.named_scope("optim.pack"):
+                    new_p = jnp.swapaxes(new_p, -1, -2)
             return new_p, {"host": hstate, "prev_norm": new_norm}
 
         def taps(g_stk, p_stk, new_p_stk, old_st, new_st, step):

@@ -132,13 +132,18 @@ def fused_write_update(g: jax.Array, p: jax.Array, state: dict,
 
     Returns ``(new_p, new_norm, new_state)``.  ``impl='jnp'`` routes to the
     tiled ``ref.gwt_adam_fused`` oracle with the SAME row-block choice as
-    the kernel, so interpret/pallas bitwise-match it."""
+    the kernel, so interpret/pallas bitwise-match it.
+
+    The launch runs under the named scope ``gwt.kernel`` (DESIGN.md §12),
+    opened outside the jit: the Mosaic custom call takes its HLO name from
+    the innermost name-stack entry, which stays ``_fused_write_update``."""
     impl = compat.resolve_kernel_impl(impl)
-    return _fused_write_update(
-        g, p, state["m"], state["v"], prev_norm, step, lr_t,
-        alpha=alpha, weight_decay=weight_decay, gamma=gamma,
-        use_limiter=use_limiter, level=level, b1=b1, b2=b2, eps=eps,
-        impl=impl)
+    with jax.named_scope("gwt.kernel"):
+        return _fused_write_update(
+            g, p, state["m"], state["v"], prev_norm, step, lr_t,
+            alpha=alpha, weight_decay=weight_decay, gamma=gamma,
+            use_limiter=use_limiter, level=level, b1=b1, b2=b2, eps=eps,
+            impl=impl)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -150,11 +155,12 @@ def _fused_write_update(g, p, m_st, v_st, prev_norm, step, lr_t, *,
     from repro.kernels.gwt_adam import kernel, ref  # noqa: F811 — local
     step_size, wd_coef = _step_scalars(step, lr_t, alpha, weight_decay,
                                        b1, b2)
-    g3, gshape, lead2 = _norm_shapes(g)
-    p3, _, _ = _norm_shapes(p)
-    m3, _, _ = _norm_shapes(m_st)
-    v3, _, _ = _norm_shapes(v_st)
-    pn = prev_norm.reshape(g3.shape[0])
+    with jax.named_scope("optim.pack"):
+        g3, gshape, lead2 = _norm_shapes(g)
+        p3, _, _ = _norm_shapes(p)
+        m3, _, _ = _norm_shapes(m_st)
+        v3, _, _ = _norm_shapes(v_st)
+        pn = prev_norm.reshape(g3.shape[0])
     L, mm, nn = g3.shape
     kw = dict(level=level, gamma=gamma, use_limiter=use_limiter,
               weight_decay=weight_decay != 0, b1=b1, b2=b2, eps=eps)
@@ -166,12 +172,13 @@ def _fused_write_update(g, p, m_st, v_st, prev_norm, step, lr_t, *,
         new_p, m, v, new_norm = ref.gwt_adam_fused(
             g3, p3, m3, v3, pn, step_size, wd_coef,
             bm=kernel.fused_row_block(mm, nn, level), **kw)
-    new_p = new_p.reshape(gshape)
-    mshape = gshape[:-1] + (nn >> level,)
-    m, v = m.reshape(mshape), v.reshape(mshape)
-    if lead2:
-        new_p, m, v = new_p[0], m[0], v[0]
-        new_norm = new_norm.reshape(())
+    with jax.named_scope("optim.pack"):
+        new_p = new_p.reshape(gshape)
+        mshape = gshape[:-1] + (nn >> level,)
+        m, v = m.reshape(mshape), v.reshape(mshape)
+        if lead2:
+            new_p, m, v = new_p[0], m[0], v[0]
+            new_norm = new_norm.reshape(())
     return new_p, new_norm, {"m": m, "v": v}
 
 
@@ -187,14 +194,15 @@ def fused_write_update_q8(g: jax.Array, p: jax.Array, state: dict,
     stochastic requant AND limit+apply+write all inside the launch.  The
     codec's per-row blocks tile every shape, so ``pallas``/``interpret``
     always run the kernel.  Returns ``(new_p, new_norm, new_state)`` in
-    the encoded layout."""
+    the encoded layout; the launch runs under ``gwt.kernel`` as there."""
     impl = compat.resolve_kernel_impl(impl)
-    return _fused_write_update_q8(
-        g, p, state["m"]["q"], state["m"]["scale"],
-        state["v"]["q"], state["v"]["scale"], prev_norm, step, key,
-        leaf_ids, lr_t, alpha=alpha, weight_decay=weight_decay,
-        gamma=gamma, use_limiter=use_limiter, level=level, block=block,
-        b1=b1, b2=b2, eps=eps, impl=impl)
+    with jax.named_scope("gwt.kernel"):
+        return _fused_write_update_q8(
+            g, p, state["m"]["q"], state["m"]["scale"],
+            state["v"]["q"], state["v"]["scale"], prev_norm, step, key,
+            leaf_ids, lr_t, alpha=alpha, weight_decay=weight_decay,
+            gamma=gamma, use_limiter=use_limiter, level=level, block=block,
+            b1=b1, b2=b2, eps=eps, impl=impl)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -207,12 +215,13 @@ def _fused_write_update_q8(g, p, qm, sm, qv, sv, prev_norm, step, key,
     from repro.optim import codec as codec_lib
     step_size, wd_coef = _step_scalars(step, lr_t, alpha, weight_decay,
                                        b1, b2)
-    g3, gshape, lead2 = _norm_shapes(g)
-    p3, _, _ = _norm_shapes(p)
-    qm3, _, _ = _norm_shapes(qm)
-    qv3, _, _ = _norm_shapes(qv)
-    L, mm, nn = g3.shape
-    sm2, sv2 = sm.reshape(L, -1, mm), sv.reshape(L, -1, mm)
+    with jax.named_scope("optim.pack"):
+        g3, gshape, lead2 = _norm_shapes(g)
+        p3, _, _ = _norm_shapes(p)
+        qm3, _, _ = _norm_shapes(qm)
+        qv3, _, _ = _norm_shapes(qv)
+        L, mm, nn = g3.shape
+        sm2, sv2 = sm.reshape(L, -1, mm), sv.reshape(L, -1, mm)
     salt_m = codec_lib.slot_salt(key, step, 0, leaf_ids).reshape(L)
     salt_v = codec_lib.slot_salt(key, step, 1, leaf_ids).reshape(L)
     pn = prev_norm.reshape(L)
@@ -229,12 +238,13 @@ def _fused_write_update_q8(g, p, qm, sm, qv, sv, prev_norm, step, key,
         new_p, qm2, smo, qv2, svo, new_norm = ref.gwt_adam_fused_q8(
             g3, p3, qm3, sm2, qv3, sv2, salt_m, salt_v, pn, step_size,
             wd_coef, bm=kernel.q8_row_block(mm, nn, level, block), **kw)
-    new_p = new_p.reshape(gshape)
-    qshape = gshape[:-1] + (nn >> level,)
-    qm2, qv2 = qm2.reshape(qshape), qv2.reshape(qshape)
-    smo, svo = smo.reshape(sm.shape), svo.reshape(sv.shape)
-    if lead2:
-        new_p, qm2, qv2 = new_p[0], qm2[0], qv2[0]
-        new_norm = new_norm.reshape(())
+    with jax.named_scope("optim.pack"):
+        new_p = new_p.reshape(gshape)
+        qshape = gshape[:-1] + (nn >> level,)
+        qm2, qv2 = qm2.reshape(qshape), qv2.reshape(qshape)
+        smo, svo = smo.reshape(sm.shape), svo.reshape(sv.shape)
+        if lead2:
+            new_p, qm2, qv2 = new_p[0], qm2[0], qv2[0]
+            new_norm = new_norm.reshape(())
     return new_p, new_norm, {"m": {"q": qm2, "scale": smo},
                              "v": {"q": qv2, "scale": svo}}

@@ -524,28 +524,32 @@ def make_sharded_train_step(cfg, optimizer, loss, *, ctx: MeshContext,
                                 gsum, g)
             return (gsum, lsum + l), None
 
-        g0 = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
-        (gsum, lsum), _ = jax.lax.scan(body, (g0, jnp.zeros(())), micro)
-        gmean = jax.tree.map(lambda a: a / accum_steps, gsum)
-        lmean = jax.lax.psum(lsum / accum_steps, axis) / dp_size
-        if not ef_on:
-            grads = jax.tree.map(
-                functools.partial(compression.compressed_psum_mean,
-                                  axis_name=axis, level=dp_reduce.level,
-                                  detail_dtype=dp_reduce.detail_dtype,
-                                  impl=wire_impl), gmean)
-            return grads, lmean
-        g_leaves, treedef = jax.tree.flatten(gmean)
-        e_leaves = treedef.flatten_up_to(ef)
-        pairs = [compression.compressed_psum_mean_ef(
-            g, e[0], axis_name=axis, level=dp_reduce.level,
-            detail_dtype=dp_reduce.detail_dtype, impl=wire_impl)
-            for g, e in zip(g_leaves, e_leaves)]
-        grads = jax.tree_util.tree_unflatten(treedef,
-                                             [p[0] for p in pairs])
-        new_ef = jax.tree_util.tree_unflatten(treedef,
-                                              [p[1][None] for p in pairs])
-        return grads, lmean, new_ef
+        # named scopes (DESIGN.md §12) label the compiled ops; no op changes
+        with jax.named_scope("train.fwd_bwd"):
+            g0 = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32),
+                              params)
+            (gsum, lsum), _ = jax.lax.scan(body, (g0, jnp.zeros(())), micro)
+        with jax.named_scope("train.dp_reduce"):
+            gmean = jax.tree.map(lambda a: a / accum_steps, gsum)
+            lmean = jax.lax.psum(lsum / accum_steps, axis) / dp_size
+            if not ef_on:
+                grads = jax.tree.map(
+                    functools.partial(compression.compressed_psum_mean,
+                                      axis_name=axis, level=dp_reduce.level,
+                                      detail_dtype=dp_reduce.detail_dtype,
+                                      impl=wire_impl), gmean)
+                return grads, lmean
+            g_leaves, treedef = jax.tree.flatten(gmean)
+            e_leaves = treedef.flatten_up_to(ef)
+            pairs = [compression.compressed_psum_mean_ef(
+                g, e[0], axis_name=axis, level=dp_reduce.level,
+                detail_dtype=dp_reduce.detail_dtype, impl=wire_impl)
+                for g, e in zip(g_leaves, e_leaves)]
+            grads = jax.tree_util.tree_unflatten(treedef,
+                                                 [p[0] for p in pairs])
+            new_ef = jax.tree_util.tree_unflatten(
+                treedef, [p[1][None] for p in pairs])
+            return grads, lmean, new_ef
 
     def train_step(params, opt_state, batch):
         ef_state = None
@@ -585,13 +589,15 @@ def make_sharded_train_step(cfg, optimizer, loss, *, ctx: MeshContext,
             grads, loss_mean, new_ef = fn(*args)
         else:
             grads, loss_mean = fn(*args)
-        grads = jax.tree.map(lambda g: g.astype(cfg.dtype), grads)
+        with jax.named_scope("train.fwd_bwd"):
+            grads = jax.tree.map(lambda g: g.astype(cfg.dtype), grads)
         if shardings is not None:
             # pin the (replicated) reduced grads to the parameter layout so
             # the update partitions like the state it writes
             grads = jax.tree.map(jax.lax.with_sharding_constraint,
                                  grads, shardings.params)
-        new_params, new_opt = optimizer.update(grads, opt_state, params)
+        with jax.named_scope("train.update"):
+            new_params, new_opt = optimizer.update(grads, opt_state, params)
         if shardings is not None:
             new_params = jax.tree.map(jax.lax.with_sharding_constraint,
                                       new_params, shardings.params)
@@ -659,7 +665,6 @@ def make_train_step(cfg, optimizer, accum_steps: int = 1,
         # resolve the ambient fallback at trace time, not build time: the
         # launcher may build the step outside the mesh context and jit it in
         c = ctx if ctx is not None else MeshContext.ambient()
-        micro = microbatch_split(batch, accum_steps, ctx=c)
 
         def accum_body(carry, mb):
             gsum, lsum = carry
@@ -671,32 +676,39 @@ def make_train_step(cfg, optimizer, accum_steps: int = 1,
             gsum = jax.tree.map(lambda a, b: a + b.astype(jnp.float32), gsum, g)
             return (gsum, lsum + l), None
 
-        if accum_steps == 1:
-            # one microbatch: the f32 accumulator would hold g + 0 (exact)
-            # at 4 bytes per parameter — 5 GB of a 16 GB chip at llama-1b
-            lsum, grads = jax.value_and_grad(
-                lambda p: loss(cfg, p, jax.tree.map(lambda x: x[0], micro),
-                               ctx=c))(params)
-            if grad_shardings is not None:
-                grads = jax.tree.map(jax.lax.with_sharding_constraint,
-                                     grads, grad_shardings)
-            grads = jax.tree.map(lambda g: g.astype(cfg.dtype), grads)
-        else:
-            g0 = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32),
-                              params)
-            if grad_shardings is not None:
-                g0 = jax.tree.map(jax.lax.with_sharding_constraint, g0,
-                                  grad_shardings)
-            (gsum, lsum), _ = jax.lax.scan(accum_body, (g0, jnp.zeros(())),
-                                           micro)
-            grads = jax.tree.map(lambda g: (g / accum_steps).astype(cfg.dtype),
-                                 gsum)
-        if taps:
-            new_params, new_opt, tp = optimizer.tapped_update(
-                grads, opt_state, params)
-            return new_params, new_opt, {"loss": lsum / accum_steps,
-                                         "taps": tp}
-        new_params, new_opt = optimizer.update(grads, opt_state, params)
+        # named scopes (DESIGN.md §12) label the compiled ops for the
+        # profiler's trace; they change no op
+        with jax.named_scope("train.fwd_bwd"):
+            micro = microbatch_split(batch, accum_steps, ctx=c)
+            if accum_steps == 1:
+                # one microbatch: the f32 accumulator would hold g + 0
+                # (exact) at 4 bytes per parameter — 5 GB of a 16 GB chip
+                # at llama-1b
+                lsum, grads = jax.value_and_grad(
+                    lambda p: loss(cfg, p,
+                                   jax.tree.map(lambda x: x[0], micro),
+                                   ctx=c))(params)
+                if grad_shardings is not None:
+                    grads = jax.tree.map(jax.lax.with_sharding_constraint,
+                                         grads, grad_shardings)
+                grads = jax.tree.map(lambda g: g.astype(cfg.dtype), grads)
+            else:
+                g0 = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32),
+                                  params)
+                if grad_shardings is not None:
+                    g0 = jax.tree.map(jax.lax.with_sharding_constraint, g0,
+                                      grad_shardings)
+                (gsum, lsum), _ = jax.lax.scan(accum_body,
+                                               (g0, jnp.zeros(())), micro)
+                grads = jax.tree.map(
+                    lambda g: (g / accum_steps).astype(cfg.dtype), gsum)
+        with jax.named_scope("train.update"):
+            if taps:
+                new_params, new_opt, tp = optimizer.tapped_update(
+                    grads, opt_state, params)
+                return new_params, new_opt, {"loss": lsum / accum_steps,
+                                             "taps": tp}
+            new_params, new_opt = optimizer.update(grads, opt_state, params)
         return new_params, new_opt, {"loss": lsum / accum_steps}
 
     if donate:

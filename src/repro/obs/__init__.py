@@ -15,9 +15,11 @@ Three layers, composable and individually optional:
   through every constructor.
 
 The default global is a *null* Telemetry: ``emit`` drops the record,
-``span`` yields a shared no-op context, ``log`` only prints.  Hot-path
-call sites therefore never need an ``if enabled`` guard — the disabled
-cost is one attribute load and a dict drop.  On-device tap *values* are
+``span`` opens only a ``jax.profiler.TraceAnnotation`` (one C++ call when
+no profiler session is active), ``log`` only prints.  Hot-path call sites
+therefore never need an ``if enabled`` guard.  Because every span is a
+profiler annotation, a ``jax.profiler`` trace holds the program's spans on
+the clock of the device's ops.  On-device tap *values* are
 not routed through here at all (they live in the jitted step's metrics
 output and are fetched at ``log_every`` boundaries by the train loop);
 this layer only receives the already-fetched host scalars.

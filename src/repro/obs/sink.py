@@ -19,8 +19,10 @@ from __future__ import annotations
 import json
 import os
 import time
-from contextlib import nullcontext
+from contextlib import contextmanager
 from typing import Any, Dict, Optional, Protocol, runtime_checkable
+
+from jax.profiler import TraceAnnotation
 
 from repro.obs.trace import Tracer
 
@@ -125,7 +127,10 @@ class JsonlSink:
             self._f.close()
 
 
-_NULL_SPAN = nullcontext()
+@contextmanager
+def _both(annotation, span):
+    with annotation, span as args:
+        yield args
 
 
 class Telemetry:
@@ -155,11 +160,18 @@ class Telemetry:
         print(msg)
         self.sink.emit({"kind": kind, "msg": msg, **fields})
 
-    def span(self, name: str, cat: str = "train", tid: int = 0,
-             **args: Any):
+    def span(self, name: str, **args: Any):
+        """A span named ``<layer>.<phase>`` (``train.dispatch``,
+        ``serve.decode``).  It is always a profiler ``TraceAnnotation``, so
+        a ``jax.profiler`` trace holds it on the host plane, on the clock
+        of the device ops; with no profiler session it costs one C++ call.
+        With a :class:`Tracer` configured it is recorded there too, under
+        the category ``<layer>``."""
+        annotation = TraceAnnotation(name, **args)
         if self.tracer is None:
-            return _NULL_SPAN
-        return self.tracer.span(name, cat=cat, tid=tid, **args)
+            return annotation
+        return _both(annotation, self.tracer.span(
+            name, cat=name.partition(".")[0], **args))
 
     def counter(self, name: str, cat: str = "train", **values: Any) -> None:
         if self.tracer is not None:

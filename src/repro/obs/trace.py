@@ -12,13 +12,16 @@ The export is the Trace Event Format's JSON-object flavor::
      "displayTimeUnit": "ms", "otherData": {...}}
 
 * complete spans: ``ph = "X"`` with ``ts``/``dur`` in microseconds,
-* counters:       ``ph = "C"`` with the sampled values in ``args``,
-* instants:       ``ph = "i"`` with scope ``"p"`` (process).
+* counters:       ``ph = "C"`` with the sampled values in ``args``.
 
 Open the file in https://ui.perfetto.dev or ``chrome://tracing``.
 Timestamps are relative to tracer construction (``perf_counter`` is an
 arbitrary-epoch monotonic clock); the wall-clock origin is recorded in
 ``otherData.t0_unix`` for correlation with JSONL metric ``ts`` fields.
+
+The same spans also reach a ``jax.profiler`` trace (``Telemetry.span``
+opens a ``TraceAnnotation`` for each): that trace, not this file, is
+where they share one clock with the device's ops.
 """
 
 from __future__ import annotations
@@ -29,7 +32,7 @@ import time
 from contextlib import contextmanager
 from typing import Any, Dict, List, Optional
 
-PHASES = ("X", "C", "i")
+PHASES = ("X", "C")
 
 
 class Tracer:
@@ -69,13 +72,6 @@ class Tracer:
             "name": name, "ph": "C", "ts": self.now_us(),
             "pid": 0, "tid": tid, "cat": cat,
             "args": {k: float(v) for k, v in values.items()},
-        })
-
-    def instant(self, name: str, cat: str = "train", tid: int = 0,
-                **args: Any) -> None:
-        self.events.append({
-            "name": name, "ph": "i", "s": "p", "ts": self.now_us(),
-            "pid": 0, "tid": tid, "cat": cat, "args": dict(args),
         })
 
     def export(self) -> Dict[str, Any]:

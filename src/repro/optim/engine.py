@@ -378,8 +378,11 @@ def build(assign: Callable[[str, Any], LeafRule],
                 np_stk = jnp.stack([o[0] for o in outs])
                 ns = _stack_states([o[1] for o in outs])
             else:
-                g_stk = jnp.stack([gleaves[i] for i in b.indices])
-                p_stk = jnp.stack([pleaves[i] for i in b.indices])
+                # ``optim.pack`` names the ops that bring a bucket's leaves
+                # into the stacked layout and back (DESIGN.md §12)
+                with jax.named_scope("optim.pack"):
+                    g_stk = jnp.stack([gleaves[i] for i in b.indices])
+                    p_stk = jnp.stack([pleaves[i] for i in b.indices])
                 if b.rule.vector_update is not None:
                     if coded and b.rule.codec_native:
                         np_stk, ns = b.rule.vector_update(
@@ -413,8 +416,9 @@ def build(assign: Callable[[str, Any], LeafRule],
                     for k, v in tp.items():
                         taps[f"{b.name}/{k}"] = jnp.asarray(v, jnp.float32)
             new_buckets[b.name] = _constrain_bucket(ns, hints.get(b.name))
-            for j, i in enumerate(b.indices):
-                new_leaves[i] = np_stk[j]
+            with jax.named_scope("optim.pack"):
+                for j, i in enumerate(b.indices):
+                    new_leaves[i] = np_stk[j]
         out = {"step": step + 1, "buckets": new_buckets}
         if quant:
             out["codec_key"] = key

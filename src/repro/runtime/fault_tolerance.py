@@ -222,6 +222,9 @@ class TrainLoop:
         self.preempt = PreemptionHandler()
         self._superstep = None  # built lazily, reused across run() calls
         self._tap_keys = None   # tap names, recorded at superstep trace
+        # chunk lengths dispatched by this loop: a new one compiles (or
+        # loads from the compile cache), named in the trace as such
+        self._dispatched: set = set()
         # Align the chunk grid to log_every when a reasonable divisor
         # exists: uniform chunk lengths mean ONE superstep compilation
         # instead of one per distinct length (log_every=20, max_chunk=16
@@ -351,7 +354,7 @@ class TrainLoop:
             return
         t0 = time.monotonic()
         tel = obs.get()
-        with tel.span("eval", step=step):
+        with tel.span("train.eval", step=step):
             r = self.evaluator(params, step)
         self.watchdog.block(time.monotonic() - t0, k)
         tel.emit("eval", step=step, loss=float(r["loss"]),
@@ -401,7 +404,7 @@ class TrainLoop:
             if not window:
                 return
             t0 = time.monotonic()
-            with tel.span("block", steps=nwin):
+            with tel.span("train.block", steps=nwin):
                 fetched = jax.device_get([ys for _, ys in window])
             self.watchdog.block(time.monotonic() - t0, nwin)
             emit = getattr(tel.sink, "enabled", True)
@@ -436,7 +439,7 @@ class TrainLoop:
                 end = self._chunk_end(step, num_steps)
                 k = end - step
                 batches = []
-                with tel.span("prefetch", steps=k):
+                with tel.span("train.input_wait", steps=k):
                     for j in range(k):
                         i, b = next(pf)
                         if i != step + j:   # bit-determinism depends on this
@@ -444,12 +447,16 @@ class TrainLoop:
                                 f"data stream desync: got batch "
                                 f"{i}, want {step + j}")
                         batches.append(b)
+                with tel.span("train.place", steps=k):
                     chunk = {kk: self._place(kk, v)
                              for kk, v in stack_batches(batches).items()}
                 self.watchdog.start()
-                with tel.span("dispatch", step=step, steps=k):
+                phase = "train.dispatch" if k in self._dispatched \
+                    else "train.dispatch_first"
+                with tel.span(phase, step=step, steps=k):
                     params, opt_state, lchunk = self._superstep(
                         params, opt_state, chunk)
+                self._dispatched.add(k)
                 dt = self.watchdog.stop(step, k,
                                         record=k in compiled_sizes)
                 compiled_sizes.add(k)
@@ -457,16 +464,19 @@ class TrainLoop:
                 nwin += k
                 step = end
                 if self.log_every and step % self.log_every == 0:
-                    flush()
-                    self.log(f"step {step}: loss={losses[-1]:.4f} "
-                             f"(dispatch {dt / k * 1e3:.1f}ms/step, blocked "
-                             f"{(self.watchdog.block_ema or 0) * 1e3:.1f}"
-                             f"ms/step)")
+                    # the boundary: the loss fetch (train.block) and the
+                    # log line, so no stretch of it is out of a span
+                    with tel.span("train.log", step=step):
+                        flush()
+                        blocked = (self.watchdog.block_ema or 0) * 1e3
+                        self.log(f"step {step}: loss={losses[-1]:.4f} "
+                                 f"(dispatch {dt / k * 1e3:.1f}ms/step, "
+                                 f"blocked {blocked:.1f}ms/step)")
                 self._maybe_eval(step, params, k)
                 if self.ckpt is not None and self.ckpt_every \
                         and step % self.ckpt_every == 0:
                     t0 = time.monotonic()
-                    with tel.span("save", step=step):
+                    with tel.span("train.save", step=step):
                         self._save(step, params, opt_state, snapshot=True)
                     last_saved = step
                     self.watchdog.block(time.monotonic() - t0, k)
@@ -478,7 +488,8 @@ class TrainLoop:
                         self._save(step, params, opt_state, blocking=True)
                     break
         finally:
-            pf.close()
+            with tel.span("train.close"):
+                pf.close()
         flush()
         self._finalize(step, params, opt_state, preempted, last_saved)
         # fold the watchdog's phase split into the sink (ring-buffered

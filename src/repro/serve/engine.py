@@ -277,7 +277,7 @@ class Engine:
         chunk = list(req.prompt[s["filled"]:s["filled"] + C])
         real = len(chunk)
         tokens = jnp.asarray([chunk + [0] * (C - real)], jnp.int32)
-        with self._tel.span("prefill", cat="serve", slot=slot,
+        with self._tel.span("serve.prefill", slot=slot,
                             rid=req.rid, tokens=real):
             greedy, self.pools = self._chunk_step(
                 self.params, self.pools,
@@ -312,7 +312,7 @@ class Engine:
             tokens[i, 0] = self.slots[i]["last"]
             pt[i] = self.page_table[i]
             ln[i] = self.lens[i]
-        with self._tel.span("decode", cat="serve", active=len(active)):
+        with self._tel.span("serve.decode", active=len(active)):
             greedy, self.pools = self._decode_step(
                 self.params, self.pools, jnp.asarray(pt), jnp.asarray(ln),
                 jnp.asarray(tokens))

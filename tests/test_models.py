@@ -2,6 +2,8 @@
 arch instantiates a REDUCED config, runs forward + one GWT train step +
 (where applicable) prefill/decode, asserting shapes and finiteness."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -118,6 +120,35 @@ def test_encdec_decode_matches_teacher_forcing(key):
             np.asarray(logits_d[:, 0], np.float32),
             np.asarray(full_logits[:, t], np.float32),
             atol=0.05, rtol=0.05)
+
+
+def _scopes(op_name):
+    """Name-stack components with the transformations' wrappers taken
+    off: ``train.fwd_bwd/transpose(jvp(dot))`` -> train.fwd_bwd, dot."""
+    return set(re.sub(r"[\w\-]+\(", "", op_name).replace(")", "")
+               .split("/"))
+
+
+@pytest.mark.parametrize("accum", [1, 2])
+def test_train_step_scopes_label_the_compiled_ops(accum, key):
+    """The step's named scopes (DESIGN.md §12) reach the compiled HLO's
+    ``op_name`` metadata: forward/backward and the update label disjoint
+    instructions, backward ops included; the bucketed engine's packing and
+    the fused kernel's launch lie under the update."""
+    cfg = configs.get_smoke("llama-60m")
+    params = lm.init(cfg, key)
+    opt = optim.make("gwt", lr=1e-3, level=2, impl="interpret")
+    step = jax.jit(lm.make_train_step(cfg, opt, accum_steps=accum))
+    hlo = step.lower(params, opt.init(params),
+                     _batch(cfg, key, 2, 32)).compile().as_text()
+    names = [(n, _scopes(n)) for n in re.findall(r'op_name="([^"]*)"', hlo)]
+    fwd_bwd = [n for n, sc in names if "train.fwd_bwd" in sc]
+    update = [sc for n, sc in names if "train.update" in sc]
+    assert fwd_bwd and update
+    assert not any("train.fwd_bwd" in sc for sc in update)
+    assert any("transpose(" in n for n in fwd_bwd)       # backward ops
+    assert any("optim.pack" in sc for sc in update)
+    assert any("gwt.kernel" in sc for sc in update)
 
 
 def test_param_builder_trees_consistent():

@@ -271,6 +271,56 @@ def test_trainloop_metrics_off_is_invariant_under_telemetry():
     np.testing.assert_array_equal(np.asarray(p0["n"]), np.asarray(p1["n"]))
 
 
+LOOP_SPANS = {"train.input_wait", "train.place", "train.dispatch_first",
+              "train.dispatch", "train.log", "train.block", "train.close"}
+
+
+def _run_two_chunks():
+    step, _ = _toy_steps()
+    loop = TrainLoop(step, None, _CountSource(), log_every=4, max_chunk=4,
+                     log=lambda s: None)
+    loop.run({"n": jnp.float32(0)}, {}, num_steps=8)
+
+
+def test_trainloop_spans_reach_the_profiler_trace(tmp_path):
+    """With the null Telemetry the loop's spans are still profiler
+    annotations: a ``jax.profiler`` trace holds them on its host plane,
+    on the clock of the annotation around the run."""
+    from jax.profiler import ProfileData
+    obs.shutdown()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with jax.profiler.TraceAnnotation("test.window"):
+            _run_two_chunks()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.rglob("*.xplane.pb")
+    events = [(ev.name, ev.start_ns, ev.end_ns)
+              for plane in ProfileData.from_file(str(path)).planes
+              if plane.name.startswith("/host:")
+              for line in plane.lines for ev in line.events]
+    (lo, hi), = [(s, e) for n, s, e in events if n == "test.window"]
+    spans = [(n, s, e) for n, s, e in events if n.startswith("train.")]
+    assert {n for n, _, _ in spans} == LOOP_SPANS
+    assert all(lo <= s <= e <= hi for _, s, e in spans)
+    # each loss fetch lies inside its log boundary
+    logs = [(s, e) for n, s, e in spans if n == "train.log"]
+    blocks = [(s, e) for n, s, e in spans if n == "train.block"]
+    assert len(logs) == len(blocks) == 2
+    assert all(a <= s <= e <= b for (a, b), (s, e) in zip(logs, blocks))
+    # the first chunk length compiles; the second chunk reuses it
+    assert [n for n, _, _ in spans if n.startswith("train.dispatch")] \
+        == ["train.dispatch_first", "train.dispatch"]
+
+
+def test_trainloop_spans_reach_a_configured_tracer():
+    obs.configure(sink=MemorySink(), tracer=obs_trace.Tracer())
+    _run_two_chunks()
+    evs = obs.get().tracer.events
+    assert {e["name"] for e in evs} == LOOP_SPANS
+    assert {e["cat"] for e in evs} == {"train"}
+
+
 # ---------------------------------------------------------------------------
 # Trace export: schema round-trip
 # ---------------------------------------------------------------------------
@@ -282,7 +332,6 @@ def test_trace_schema_roundtrip(tmp_path):
             pass
         args["extra"] = 7            # body-added arg lands in the event
     tr.counter("sched", cat="serve", queue_depth=2, slots_busy=1.0)
-    tr.instant("admit", cat="serve", rid=0)
     path = tr.write(str(tmp_path / "trace.json"))
     doc = json.load(open(path))
     obs_trace.validate(doc)          # the round-trip IS the schema check
@@ -298,7 +347,6 @@ def test_trace_schema_roundtrip(tmp_path):
     assert by_name["inner"]["dur"] <= by_name["outer"]["dur"]
     assert by_name["sched"]["args"] == {"queue_depth": 2.0,
                                         "slots_busy": 1.0}
-    assert by_name["admit"]["ph"] == "i" and by_name["admit"]["s"] == "p"
     # events come out time-sorted (Perfetto does not require it, humans
     # reading the JSON do)
     ts = [e["ts"] for e in evs[1:]]
@@ -377,14 +425,15 @@ def test_configure_metrics_dir_builds_jsonl_and_trace(tmp_path):
     tel = obs.configure(str(d), run={"cmd": "t"})
     assert tel is obs.get() and tel.enabled
     tel.emit("train_step", step=1, loss=1.0)
-    with tel.span("dispatch", steps=2):
+    with tel.span("train.dispatch", steps=2):
         pass
     obs.shutdown()
     recs = [json.loads(l) for l in open(d / "metrics.jsonl")]
     assert [r["kind"] for r in recs] == ["run", "train_step"]
     doc = json.load(open(d / "trace.json"))
     obs_trace.validate(doc)
-    assert any(e["name"] == "dispatch" for e in doc["traceEvents"])
+    assert any(e["name"] == "train.dispatch" and e["cat"] == "train"
+               for e in doc["traceEvents"])
     assert isinstance(obs.get().sink, NullSink)   # reset after shutdown
 
 
@@ -421,4 +470,5 @@ def test_serve_engine_emits_request_records_at_retirement():
     tr = obs.get().tracer
     cats = {e.get("cat") for e in tr.events}
     names = {e.get("name") for e in tr.events}
-    assert "serve" in cats and {"prefill", "decode", "sched"} <= names
+    assert "serve" in cats
+    assert {"serve.prefill", "serve.decode", "sched"} <= names

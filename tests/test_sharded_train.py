@@ -258,6 +258,10 @@ def test_donation_single_buffered_under_sharding():
     assert ld < lp, (ld, lp)
     ma = donated.memory_analysis()
     assert ma.alias_size_in_bytes > 0
+    # the step's named scopes label the compiled ops, the reduction apart
+    hlo = donated.as_text()
+    for scope in ("train.fwd_bwd/", "train.dp_reduce/", "train.update/"):
+        assert scope in hlo, scope
     print("DONATION_OK", lp, ld)
     """
     r = run_in_devices(8, code)
