@@ -8,7 +8,11 @@ One chip:
 (a) the device JAX found (the script fails when it is not a TPU);
 (b) kernel parity: the fused GWT-Adam kernels (f32 and int8 moments) and
     the compressed DP wire's DWT, ``impl="pallas"`` against the jnp oracle
-    at llama-1b bucket widths;
+    at llama-1b bucket widths; and the fused kernel fed bf16 gradients
+    (its one-pass schedule) bitwise against the same kernel run through
+    the f32 schedule's three-pass shuffles, at the benchmark cells'
+    bucket widths (``python chip_smoke.py --parity`` runs this phase
+    alone);
 (c) llama-1b GWT level-2 training through ``repro.launch.train.main``:
     f32 moments, then the int8 state codec.  Before each run the train
     step is compiled once more on its own to check that every GWT bucket
@@ -31,6 +35,8 @@ of one run, compilation included; they are not benchmark numbers.
 from __future__ import annotations
 
 import argparse
+import contextlib
+import functools
 import gc
 import json
 import math
@@ -241,6 +247,76 @@ def kernel_parity():
                   dict(rtol=2.0 ** -7, atol=1e-6))
 
 
+# The benchmark cells' GWT buckets (L, rows, n) at level 2: Qwen2.5-3B's
+# gate/up, down, q/o and k/v stacks of 9 layers, Mistral-7B's of 4.  The
+# schedule parity cuts each to four of its row tiles.
+CELL_BUCKETS = [(2, 18432, 11008), (1, 99072, 2048), (2, 18432, 2048),
+                (2, 18432, 256), (2, 16384, 14336), (1, 57344, 4096),
+                (2, 16384, 4096), (2, 16384, 1024)]
+
+
+@contextlib.contextmanager
+def three_pass_schedule():
+    """Inside, the fused kernel runs every gradient tile through the f32
+    schedule (three-pass shuffles, rounded to the gradient's dtype at the
+    end): the kernel as it was before the bf16 schedule.  Takes effect
+    for kernels traced inside."""
+    import jax.numpy as jnp
+    from repro.kernels.gwt_adam import kernel as kg
+    core = kg._dht_adam_core
+
+    def shuffled(x, m_st, v_st, level, b1, b2, eps, xla=False):
+        out, m, v = kg._core_shuffled(x.astype(jnp.float32), m_st, v_st,
+                                      level, b1, b2, eps)
+        return out.astype(x.dtype), m, v
+
+    kg._dht_adam_core = shuffled
+    try:
+        yield
+    finally:
+        kg._dht_adam_core = core
+
+
+def schedule_parity():
+    """The fused f32-moment kernel fed bf16 ``g``/``p`` (the cells'
+    dtypes) returns ``new_p, m, v, new_norm`` bitwise equal to the same
+    kernel run through the three-pass schedule, at every cell bucket
+    width, with the limiter on (as in the cells) and off."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from repro.kernels.gwt_adam import kernel as kg
+
+    for (L, rows, n), use_limiter in [(b, True) for b in CELL_BUCKETS] + [
+            (CELL_BUCKETS[0], False)]:
+        m = 4 * kg.fused_row_block(rows, n, LEVEL)
+        k = jax.random.key(n + m)
+        g = (jax.random.normal(k, (L, m, n)) * 1e-3).astype(jnp.bfloat16)
+        p = (jax.random.normal(jax.random.fold_in(k, 1), (L, m, n))
+             * 0.02).astype(jnp.bfloat16)
+        na = n >> LEVEL
+        ms = jax.random.normal(jax.random.fold_in(k, 2), (L, m, na)) * 1e-4
+        vs = jnp.abs(jax.random.normal(jax.random.fold_in(k, 3),
+                                       (L, m, na))) * 1e-8
+        pn = jnp.arange(L, dtype=jnp.float32) * 0.3
+        args = (g, p, ms, vs, pn, jnp.float32(0.0025), jnp.float32(0.0))
+        run = lambda: jax.device_get(jax.jit(functools.partial(
+            kg.gwt_adam_tile_fused, level=LEVEL, gamma=1.01,
+            use_limiter=use_limiter, weight_decay=False))(*args))
+        new = run()
+        with three_pass_schedule():
+            old = run()
+        tag = f"schedule bf16 {(L, m, n)} limiter={use_limiter}"
+        bad = {}
+        for name, a, b in zip(("new_p", "m", "v", "new_norm"), new, old):
+            a, b = np.asarray(a), np.asarray(b)
+            bad[name] = int((a.view(f"u{a.itemsize}")
+                             != b.view(f"u{b.itemsize}")).sum())
+        say(f"parity {tag}: elements whose bits differ {bad}")
+        check(not any(bad.values()), f"parity {tag}: {bad}")
+        del g, p, ms, vs, new, old
+
+
 # ---------------------------------------------------------------------------
 # (c) training through the launcher
 # ---------------------------------------------------------------------------
@@ -316,6 +392,7 @@ def one_chip():
     import jax
     t0 = time.time()
     kernel_parity()
+    schedule_parity()
     say(f"kernel parity passed in {time.time() - t0:.1f}s")
     for codec, steps in (("f32", 20), ("int8", 10)):
         compiled_step_check(codec, BATCH)
@@ -378,6 +455,8 @@ def four_chips():
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--chips", type=int, default=1, choices=[1, 4])
+    ap.add_argument("--parity", action="store_true",
+                    help="one chip: run the bf16 schedule parity alone")
     args = ap.parse_args(argv)
     if not (SRC / "repro").is_dir():
         print(f"chip_smoke: the repro package is not at {SRC}; run this "
@@ -391,6 +470,8 @@ def main(argv=None) -> int:
         t0 = time.time()
         if args.chips == 4:
             four_chips()
+        elif args.parity:
+            schedule_parity()
         else:
             one_chip()
         say(f"all phases passed in {time.time() - t0:.1f}s")

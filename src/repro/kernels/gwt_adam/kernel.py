@@ -9,21 +9,31 @@ Per ``(bm, n)`` row stripe of the gradient, in a single VMEM residency:
     inverse butterfly → G̃ tile
     partial ‖G̃‖² per tile                          [for the norm-growth limiter]
 
-HBM traffic: read G + read/write M,V (at ``1/2^l`` of the width) + write
-G̃ — about ``2 + 4/2^l`` elements per gradient element, against ``≥ 6``
-for the op-by-op schedule.  The detail bands are *never* materialized in
-HBM — the paper's "temporary information generated during the wavelet
-transform" observation (§V), taken to its architectural conclusion.  What
-that buys on a chip is not measured yet.
+HBM traffic of one pass: read G + read/write M,V (at ``1/2^l`` of the
+width) + write G̃ — about ``2 + 4/2^l`` elements per gradient element,
+against ``≥ 6`` for the op-by-op schedule.  The detail bands are *never*
+materialized in HBM — the paper's "temporary information generated during
+the wavelet transform" observation (§V), taken to its architectural
+conclusion.  With the norm-growth limiter on (the default) the fused-write
+kernel below makes two passes (phase 0 sums ‖G̃‖² and writes P, M, V
+through unchanged, phase 1 recomputes and writes): with bf16 G and P and
+f32 moments at level 2, 20 B per gradient element against the 10 B of
+the least one-pass traffic.  With three-pass shuffles it ran at 19% of
+that 10 B roofline on a TPU v5e, bound by the MXU's shuffles.
 
 The butterfly's even/odd lane pairing is a product with a 0/1 selection
-matrix on the MXU (``repro.kernels.lanes``): exact in f32, so every tile
-is bitwise the jnp butterfly's.  Stripes span the full row (a block whose
-last dimension is the array's is always tile-legal, whatever the width);
-the row tile is bounded by the VMEM budget and the grid is
-``pl.cdiv(m, bm)``, so a partial last tile masks its out-of-range rows
-out of every norm.  Scalars (per-leaf limiter state, step size, weight
-decay, rounding salts) and the per-tile norm partials live in SMEM.
+matrix on the MXU (``repro.kernels.lanes``), exact, so every tile is
+bitwise the jnp butterfly's.  A bf16 operand takes one MXU pass, an f32
+one three.  So a bf16 gradient tile runs the butterfly's arithmetic on
+its stride-``2^l`` phases, split off and merged back in bf16
+(``_core_phases``: 2,048 MXU FLOPs per element at level 2); any other
+dtype runs it on f32 bands (``_core_shuffled``: 5,376).  Both give the
+same bits.  Stripes span the full row (a block whose last dimension is
+the array's is always tile-legal, whatever the width); the row tile is
+bounded by the VMEM budget and the grid is ``pl.cdiv(m, bm)``, so a
+partial last tile masks its out-of-range rows out of every norm.
+Scalars (per-leaf limiter state, step size, weight decay, rounding salts)
+and the per-tile norm partials live in SMEM.
 
 Bias correction (``lr_mult``) and the norm-growth limiter ratio are applied
 by the caller (ops.py) on the staged path — the limiter needs the global
@@ -61,9 +71,31 @@ _SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
 _TEMPS = 16  # f32 copies of a stripe the butterfly keeps live (VMEM estimate)
 
 
-def _dht_adam_core(x, m_st, v_st, level, b1, b2, eps):
-    """Forward butterfly → Adam on A → scaled-detail inverse butterfly.
-    Shared by the f32 and the q8 (blocked-int8 moments) bodies."""
+def _adam(a, m_st, v_st, b1, b2, eps):
+    """Adam's moments on the approximation band, and ``1/(√V+ε)``."""
+    m = b1 * m_st + (1.0 - b1) * a
+    v = b2 * v_st + (1.0 - b2) * a * a
+    return m, v, 1.0 / (jnp.sqrt(v) + eps)
+
+
+def _dht_adam_core(x, m_st, v_st, level, b1, b2, eps, xla=False):
+    """Forward butterfly → Adam on A → scaled-detail inverse butterfly of
+    a raw gradient tile ``x``.  Shared by the f32 and the q8 (blocked-int8
+    moments) bodies and by ``ref.py``.  Returns ``(out, m, v)``: ``out``
+    is G̃ in ``x``'s dtype for a bf16 ``x`` (:func:`_core_phases`), in f32
+    otherwise (:func:`_core_shuffled`); rounding it to ``x``'s dtype gives
+    the same bits either way.  ``xla``: the body is compiled by XLA
+    (interpret mode, the oracles), not by Mosaic."""
+    if x.dtype == jnp.bfloat16:
+        return _core_phases(x, m_st, v_st, level, b1, b2, eps, xla)
+    return _core_shuffled(x.astype(jnp.float32), m_st, v_st, level, b1, b2,
+                          eps)
+
+
+def _core_shuffled(x, m_st, v_st, level, b1, b2, eps):
+    """The f32 schedule: each level's even/odd split and merge is a lane
+    shuffle of an f32 band (three MXU passes each, 5,376 MXU FLOPs per
+    element at level 2, the level-1 details' ``repeat_lanes`` included)."""
     a = x
     details = []
     for _ in range(level):
@@ -71,15 +103,68 @@ def _dht_adam_core(x, m_st, v_st, level, b1, b2, eps):
         a = (even + odd) * INV_SQRT2
         details.append((even - odd) * INV_SQRT2)
 
-    m = b1 * m_st + (1.0 - b1) * a
-    v = b2 * v_st + (1.0 - b2) * a * a
-    inv_denom = 1.0 / (jnp.sqrt(v) + eps)
-
+    m, v, inv_denom = _adam(a, m_st, v_st, b1, b2, eps)
     x = m * inv_denom
     for k in range(level, 0, -1):
         d_t = details[k - 1] * lanes.repeat_lanes(inv_denom, 1 << (level - k))
         x = lanes.interleave((x + d_t) * INV_SQRT2, (x - d_t) * INV_SQRT2)
     return x, m, v
+
+
+def _core_phases(x, m_st, v_st, level, b1, b2, eps, xla):
+    """The bf16 schedule: the butterfly's arithmetic runs on the ``2^l``
+    stride phases ``x[:, j::2^l]``, each as wide as the moments.
+
+    ``l`` rounds of ``deinterleave`` split the bf16 tile into its phases
+    (one MXU pass each: the values stay bf16).  In phase form a band of
+    level ``k`` is its ``2^(l-k)`` phases, and level ``k+1``'s phase ``j``
+    is ``(B[2j] ± B[2j+1])·c`` of level ``k``'s: the f32 ops of
+    :func:`_core_shuffled` on the same elements in the same order, so the
+    bands, the moments and every output element are bitwise its own.  The
+    details of every level are scaled by ``1/(√V+ε)`` phase by phase, with
+    no ``repeat_lanes``.  The output phases are rounded to bf16 and
+    interleaved in ``l`` one-pass rounds: a permutation commutes with the
+    rounding.  At level 2 that is 2,048 MXU FLOPs per element.
+
+    Under XLA a :func:`lanes.fence` stands wherever
+    :func:`_core_shuffled` puts a shuffle between two f32 ops: between
+    the forward levels, after each inverse level, and on the ``1/(√V+ε)``
+    that scales the details below level ``l``.  Without them XLA:CPU
+    contracts a multiply into the next level's add (an FMA) where the f32
+    schedule cannot, and the interpret-mode kernel and the oracles drift
+    from it by an ulp (an ``optimization_barrier`` does not stop that).
+    Mosaic runs no fence; the chip parity phase of ``chip_smoke.py`` pins
+    the compiled kernel to the f32 schedule's bits."""
+    fence = lanes.fence if xla else (lambda t: t)
+    ph = [x]                     # ph[j] == x[:, j::len(ph)]
+    for _ in range(level):
+        halves = [lanes.deinterleave(p) for p in ph]
+        ph = [e for e, _ in halves] + [o for _, o in halves]
+
+    band = [p.astype(jnp.float32) for p in ph]
+    details = []
+    for k in range(level):
+        if k:
+            band = [fence(b) for b in band]
+        even, odd = band[0::2], band[1::2]
+        band = [(e + o) * INV_SQRT2 for e, o in zip(even, odd)]
+        details.append([(e - o) * INV_SQRT2 for e, o in zip(even, odd)])
+
+    m, v, inv_denom = _adam(band[0], m_st, v_st, b1, b2, eps)
+    y = [m * inv_denom]
+    for k in range(level, 0, -1):
+        scale = inv_denom if k == level else fence(inv_denom)
+        nxt = []
+        for yj, dj in zip(y, details[k - 1]):
+            d_t = dj * scale
+            nxt += [(yj + d_t) * INV_SQRT2, (yj - d_t) * INV_SQRT2]
+        y = [fence(u) for u in nxt]
+
+    out = [yj.astype(x.dtype) for yj in y]
+    while len(out) > 1:          # out[j] == G̃[:, j::len(out)]
+        half = len(out) // 2
+        out = [lanes.interleave(out[j], out[j + half]) for j in range(half)]
+    return out[0], m, v
 
 
 def _row_bytes(n: int, level: int, stream_cols: float) -> int:
@@ -93,13 +178,14 @@ def _check_width(n: int, level: int) -> None:
         raise ValueError(f"n={n} not divisible by 2^{level}")
 
 
-def _body(level: int, b1: float, b2: float, eps: float, rows: int,
-          g_ref, m_ref, v_ref, gt_ref, m_out_ref, v_out_ref, ssq_ref):
+def _body(level: int, b1: float, b2: float, eps: float, xla: bool,
+          rows: int, g_ref, m_ref, v_ref, gt_ref, m_out_ref, v_out_ref,
+          ssq_ref):
     i = pl.program_id(0)
-    x = g_ref[...].astype(jnp.float32)
+    x = g_ref[...]
     out, m, v = _dht_adam_core(x, m_ref[...].astype(jnp.float32),
                                v_ref[...].astype(jnp.float32),
-                               level, b1, b2, eps)
+                               level, b1, b2, eps, xla)
     out = out.astype(gt_ref.dtype)
     gt_ref[...] = out
     m_out_ref[...] = m.astype(m_out_ref.dtype)
@@ -127,7 +213,7 @@ def gwt_adam_tile(g: jax.Array, m_st: jax.Array, v_st: jax.Array, *,
     gm = pl.cdiv(mm, bm)
     tile = lambda w: pl.BlockSpec((bm, w), lambda i: (i, 0))
     return pl.pallas_call(
-        functools.partial(_body, level, b1, b2, eps, mm),
+        functools.partial(_body, level, b1, b2, eps, interpret, mm),
         grid=(gm,),
         in_specs=[tile(nn), tile(na), tile(na)],
         out_specs=[tile(nn), tile(na), tile(na), _SMEM],
@@ -175,8 +261,8 @@ def _limiter_scale(norm, prev, gamma: float):
                      jnp.float32(1.0))
 
 
-def _body_fused(level: int, b1: float, b2: float, eps: float, gamma: float,
-                use_limiter: bool, wd: bool, rows: int,
+def _body_fused(level: int, b1: float, b2: float, eps: float, xla: bool,
+                gamma: float, use_limiter: bool, wd: bool, rows: int,
                 pn_ref, sc_ref, g_ref, p_ref, m_ref, v_ref,
                 p_out_ref, m_out_ref, v_out_ref, norm_ref):
     """Grid ``(L, phases, gm)`` — leaf outermost, row tiles innermost; the
@@ -190,10 +276,10 @@ def _body_fused(level: int, b1: float, b2: float, eps: float, gamma: float,
     phase = pl.program_id(1)
     i = pl.program_id(2)
     gm = pl.num_programs(2)
-    x = g_ref[0].astype(jnp.float32)
+    x = g_ref[0]
     out, m, v = _dht_adam_core(x, m_ref[0].astype(jnp.float32),
                                v_ref[0].astype(jnp.float32),
-                               level, b1, b2, eps)
+                               level, b1, b2, eps, xla)
     gt = out.astype(g_ref.dtype)
     prev = pn_ref[leaf]
 
@@ -268,7 +354,7 @@ def gwt_adam_tile_fused(g: jax.Array, p: jax.Array, m_st: jax.Array,
     scalars = jnp.stack([jnp.asarray(step_size, jnp.float32),
                          jnp.asarray(wd_coef, jnp.float32)])
     return pl.pallas_call(
-        functools.partial(_body_fused, level, b1, b2, eps, gamma,
+        functools.partial(_body_fused, level, b1, b2, eps, interpret, gamma,
                           use_limiter, weight_decay, mm),
         grid=(L, phases, pl.cdiv(mm, bm)),
         in_specs=[_SMEM, _SMEM] + _fused_specs(bm, [nn, nn, na, na]),
@@ -326,7 +412,7 @@ def _requant(arr, salt, row0, block: int):
     return jnp.clip(q, -127.0, 127.0).astype(jnp.int8), scales
 
 
-def _body_fused_q8(level: int, b1: float, b2: float, eps: float,
+def _body_fused_q8(level: int, b1: float, b2: float, eps: float, xla: bool,
                    gamma: float, use_limiter: bool, wd: bool, block: int,
                    rows: int, pn_ref, sc_ref, salt_ref,
                    g_ref, p_ref, qm_ref, sm_ref, qv_ref, sv_ref,
@@ -342,7 +428,7 @@ def _body_fused_q8(level: int, b1: float, b2: float, eps: float,
     i = pl.program_id(2)
     gm = pl.num_programs(2)
     nleaves = pl.num_programs(0)
-    x = g_ref[0].astype(jnp.float32)
+    x = g_ref[0]
     bm = x.shape[0]
     na = x.shape[1] >> level
 
@@ -351,7 +437,8 @@ def _body_fused_q8(level: int, b1: float, b2: float, eps: float,
             jnp.transpose(s_ref[0]), na, block)
 
     out, m, v = _dht_adam_core(x, dequant(qm_ref, sm_ref),
-                               dequant(qv_ref, sv_ref), level, b1, b2, eps)
+                               dequant(qv_ref, sv_ref), level, b1, b2, eps,
+                               xla)
     gt = out.astype(g_ref.dtype)
     prev = pn_ref[leaf]
 
@@ -432,8 +519,8 @@ def gwt_adam_tile_fused_q8(g: jax.Array, p: jax.Array, qm: jax.Array,
     stile = pl.BlockSpec((1, nbr, bm), lambda l, ph, i: (l, 0, i))
     tiles = tiles + [stile, tiles[2], stile]
     return pl.pallas_call(
-        functools.partial(_body_fused_q8, level, b1, b2, eps, gamma,
-                          use_limiter, weight_decay, block, mm),
+        functools.partial(_body_fused_q8, level, b1, b2, eps, interpret,
+                          gamma, use_limiter, weight_decay, block, mm),
         grid=(L, phases, pl.cdiv(mm, bm)),
         in_specs=[_SMEM, _SMEM, _SMEM] + tiles,
         out_specs=tiles[1:] + [_SMEM],

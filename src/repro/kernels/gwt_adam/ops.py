@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
+from repro import compat, obs
 from repro.kernels.gwt_adam import kernel, ref
 
 
@@ -53,6 +53,7 @@ def fused_update(g: jax.Array, state: dict, step: jax.Array, *,
 
 @functools.partial(jax.jit, static_argnames=("level", "b1", "b2", "eps", "impl"))
 def _fused_update(g, state, step, *, level, b1, b2, eps, impl):
+    _count_schedule(g)
     fn = _tile_fn(impl, level, b1, b2, eps)
     if g.ndim > 2:  # stacked scan leaves (L, m, n)
         lead = g.shape[:-2]
@@ -108,6 +109,20 @@ def _on_each_device(call):
     return run
 
 
+def _count_schedule(g: jax.Array) -> None:
+    """At trace time, one sample of the counter ``gwt.kernel.one_pass``
+    (category ``gwt``) for this bucket: whether its gradient takes the
+    one-pass lane shuffles (bf16, ``kernel._core_phases``) or the
+    three-pass ones (any other dtype), in buckets and in gradient
+    elements.  The samples of a traced step sum to its split."""
+    one = g.dtype == jnp.bfloat16
+    obs.get().counter("gwt.kernel.one_pass", cat="gwt",
+                      buckets_one_pass=int(one),
+                      elements_one_pass=g.size if one else 0,
+                      buckets_three_pass=int(not one),
+                      elements_three_pass=0 if one else g.size)
+
+
 def _norm_shapes(g):
     """Normalize a leaf stack to ``(L, rows, n)``: 2-D single leaves gain a
     unit leaf axis; 3-D+ leaves merge extra dims into the row axis (the
@@ -153,6 +168,7 @@ def _fused_write_update(g, p, m_st, v_st, prev_norm, step, lr_t, *,
                         alpha, weight_decay, gamma, use_limiter, level,
                         b1, b2, eps, impl):
     from repro.kernels.gwt_adam import kernel, ref  # noqa: F811 — local
+    _count_schedule(g)
     step_size, wd_coef = _step_scalars(step, lr_t, alpha, weight_decay,
                                        b1, b2)
     with jax.named_scope("optim.pack"):
@@ -213,6 +229,7 @@ def _fused_write_update_q8(g, p, qm, sm, qv, sv, prev_norm, step, key,
                            use_limiter, level, block, b1, b2, eps, impl):
     from repro.kernels.gwt_adam import kernel, ref  # noqa: F811 — local
     from repro.optim import codec as codec_lib
+    _count_schedule(g)
     step_size, wd_coef = _step_scalars(step, lr_t, alpha, weight_decay,
                                        b1, b2)
     with jax.named_scope("optim.pack"):

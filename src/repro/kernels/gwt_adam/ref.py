@@ -11,7 +11,8 @@ The kernel's lane shuffles are matmuls (``repro.kernels.lanes``), so each
 butterfly level starts from a materialized array, and XLA:CPU may contract
 the jnp butterfly's multiply of one level into the next level's add (an
 FMA) where the kernel cannot.  The oracle therefore runs the kernel's own
-per-tile math (``kernel._dht_adam_core``) on whole leaves; what it checks
+per-tile math (``kernel._dht_adam_core``, compiled by XLA, so with the
+bf16 schedule's fences) on whole leaves; what it checks
 independently is the tiling, the two-phase limiter norm, the write chain,
 the int8 requantize and the SMEM/aliasing plumbing.  The shuffles
 themselves are pinned bitwise against jnp reshapes (tests/test_kernels.py)
@@ -34,10 +35,9 @@ from repro.kernels.gwt_adam import kernel
 def gwt_adam_tile(g: jax.Array, m_st: jax.Array, v_st: jax.Array, *,
                   level: int, b1: float = 0.9, b2: float = 0.999,
                   eps: float = 1e-6) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
-    out, m, v = kernel._dht_adam_core(g.astype(jnp.float32),
-                                      m_st.astype(jnp.float32),
-                                      v_st.astype(jnp.float32), level,
-                                      b1, b2, eps)
+    out, m, v = kernel._dht_adam_core(g, m_st.astype(jnp.float32),
+                                      v_st.astype(jnp.float32), level, b1,
+                                      b2, eps, xla=True)
     gt = out.astype(g.dtype)
     # limiter norm partials over the ROUNDED output — the norm of the g̃
     # actually emitted, matching the kernel's ssq_ref
@@ -71,8 +71,8 @@ def gwt_adam_tile_q8(g: jax.Array, qm: jax.Array, sm: jax.Array,
             s.reshape(-1, rows).T, na, block)
 
     m_st, v_st = dequant(qm, sm), dequant(qv, sv)
-    out, m, v = kernel._dht_adam_core(g.astype(jnp.float32), m_st, v_st,
-                                      level, b1, b2, eps)
+    out, m, v = kernel._dht_adam_core(g, m_st, v_st, level, b1, b2, eps,
+                                      xla=True)
     gt = out.astype(g.dtype)
     gr = gt.astype(jnp.float32)
     ssq = jnp.sum(gr * gr)[None, None]

@@ -10,12 +10,17 @@ input lanes (or 128 output pairs) at a time so the matrices stay small:
 * ``deinterleave(x) -> (x[:, 0::2], x[:, 1::2])``
 * ``interleave(e, o) -> z`` with ``z[:, 0::2] = e``, ``z[:, 1::2] = o``
 * ``repeat_lanes(x, r) == jnp.repeat(x, r, axis=-1)``
+* ``fence(x) == x``, through identity selections
 
-The MXU multiplies bf16, so an f32 operand is first cut into three bf16
-parts whose sum is exactly the operand (``_split3``); each output lane
-receives one part-product per matmul and zeros otherwise, so the three
-products add back to the selected f32 value bit for bit.  A non-finite
-value in a 256-lane piece turns that piece's row to NaN.
+The MXU multiplies bf16.  A bf16 operand takes one pass: each output lane
+receives its one selected value times 1 and zeros otherwise, accumulated
+in f32, which is exact.  An f32 operand is first cut into three bf16
+parts whose sum is exactly the operand (``_split3``), so it takes three
+passes, whose products add back to the selected f32 value bit for bit.
+Per element moved, one pass of ``deinterleave`` or ``interleave`` costs
+512 MXU FLOPs (a 256 x 128 selection per 256 inputs, or per 256
+outputs); the shuffles keep their operand's dtype.  A non-finite value
+in a 256-lane piece turns that piece's row to NaN.
 
 ``row_block`` bounds a kernel's row tile by its VMEM working set; the
 kernels run a ``pl.cdiv`` grid over rows, so the last tile may be partial
@@ -51,12 +56,15 @@ def _split3(x: jax.Array):
 
 
 def _select_dot(x: jax.Array, rows: int, cols: int, pick) -> jax.Array:
-    """``x @ S`` with ``S[r, c] = pick(r, c)`` (0/1), exact in f32."""
+    """``x @ S`` with ``S[r, c] = pick(r, c)`` (0/1), exact in f32: one
+    MXU pass for a bf16 ``x``, three for any other dtype."""
     r = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 0)
     c = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1)
     s = pick(r, c).astype(jnp.bfloat16)
-    hi, mid, lo = _split3(x.astype(jnp.float32))
     dot = lambda a: jnp.dot(a, s, preferred_element_type=jnp.float32)
+    if x.dtype == jnp.bfloat16:
+        return dot(x)
+    hi, mid, lo = _split3(x.astype(jnp.float32))
     return (dot(hi) + dot(mid)) + dot(lo)
 
 
@@ -65,7 +73,8 @@ def _cat(parts):
 
 
 def deinterleave(x: jax.Array):
-    """``(x[:, 0::2], x[:, 1::2])`` of a 2-D f32 value (even width)."""
+    """``(x[:, 0::2], x[:, 1::2])`` of a 2-D f32 or bf16 value (even
+    width), in ``x``'s dtype."""
     w = x.shape[1]
     even, odd = [], []
     for s in range(0, w, _PIECE):
@@ -73,11 +82,12 @@ def deinterleave(x: jax.Array):
         pw = p.shape[1]
         even.append(_select_dot(p, pw, pw // 2, lambda r, c: r == 2 * c))
         odd.append(_select_dot(p, pw, pw // 2, lambda r, c: r == 2 * c + 1))
-    return _cat(even), _cat(odd)
+    return _cat(even).astype(x.dtype), _cat(odd).astype(x.dtype)
 
 
 def interleave(e: jax.Array, o: jax.Array) -> jax.Array:
-    """Inverse of :func:`deinterleave`: ``(m, w), (m, w) -> (m, 2w)``."""
+    """Inverse of :func:`deinterleave`: ``(m, w), (m, w) -> (m, 2w)``, in
+    ``e``'s dtype (``e`` and ``o`` share it)."""
     w = e.shape[1]
     out = []
     for s in range(0, w, _PIECE // 2):
@@ -86,7 +96,20 @@ def interleave(e: jax.Array, o: jax.Array) -> jax.Array:
         pw = pe.shape[1]
         out.append(_select_dot(pe, pw, 2 * pw, lambda r, c: c == 2 * r)
                    + _select_dot(po, pw, 2 * pw, lambda r, c: c == 2 * r + 1))
-    return _cat(out)
+    return _cat(out).astype(e.dtype)
+
+
+def fence(x: jax.Array) -> jax.Array:
+    """``x`` itself, through identity selections on the MXU: bitwise a
+    copy, and a boundary that XLA fuses nothing across, as the shuffles
+    are.  No multiply before it is contracted with an add after it."""
+    w = x.shape[1]
+    out = []
+    for s in range(0, w, _PIECE):
+        p = x[:, s:min(w, s + _PIECE)]
+        pw = p.shape[1]
+        out.append(_select_dot(p, pw, pw, lambda r, c: r == c))
+    return _cat(out).astype(x.dtype)
 
 
 def repeat_lanes(x: jax.Array, reps: int) -> jax.Array:

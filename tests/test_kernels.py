@@ -380,6 +380,94 @@ def test_lane_shuffles_exact(width):
                                   np.asarray(jnp.repeat(e, 4, axis=-1)))
 
 
+@pytest.mark.parametrize("width", [256, 2048, 5504, 11008])
+def test_lane_shuffles_one_pass_bf16_exact(width):
+    """On bf16 the shuffles take one MXU pass (two matmuls per 256-lane
+    piece, one per parity) and stay bitwise the jnp slices: a short last
+    piece (5504 = 21.5 pieces) and tiny and huge magnitudes included."""
+    from repro.kernels import lanes
+    x = (jax.random.normal(jax.random.key(width), (16, width)) * 3.0
+         ).astype(jnp.bfloat16)
+    x = x.at[0, 0].set(1e-30).at[1, 1].set(-3e30).at[2, 2].set(3e38)
+    e, o = jax.jit(lanes.deinterleave)(x)
+    assert e.dtype == o.dtype == jnp.bfloat16
+    np.testing.assert_array_equal(np.asarray(e), np.asarray(x[:, 0::2]))
+    np.testing.assert_array_equal(np.asarray(o), np.asarray(x[:, 1::2]))
+    z = jax.jit(lanes.interleave)(e, o)
+    assert z.dtype == jnp.bfloat16
+    np.testing.assert_array_equal(np.asarray(z), np.asarray(x))
+    dots = lambda f, *a: str(jax.make_jaxpr(f)(*a)).count("dot_general")
+    pieces = -(-width // 256)
+    assert dots(lanes.deinterleave, x) == 2 * pieces
+    assert dots(lanes.interleave, e, o) == 2 * pieces
+    assert dots(lanes.deinterleave, x.astype(jnp.float32)) == 6 * pieces
+
+
+def _three_pass_core(x, m_st, v_st, level, b1, b2, eps, xla=False):
+    """The fused kernel's core as it was before the bf16 schedule: the
+    tile through the f32 schedule's three-pass shuffles, G̃ rounded to the
+    gradient's dtype at the end."""
+    out, m, v = kg._core_shuffled(x.astype(jnp.float32), m_st, v_st, level,
+                                  b1, b2, eps)
+    return out.astype(x.dtype), m, v
+
+
+@pytest.mark.parametrize("n", [256, 2048, 11008])
+@pytest.mark.parametrize("level", [1, 2, 3])
+@pytest.mark.parametrize("use_limiter", [True, False])
+def test_fused_write_bf16_schedule_bitwise_vs_three_pass(monkeypatch, n,
+                                                         level, use_limiter):
+    """Fed bf16 g/p, the interpret-mode fused kernel (one-pass shuffles,
+    butterfly on the stride-2^l phases) returns new_p, m, v and new_norm
+    bit for bit as the same kernel through the three-pass schedule.
+    40 rows: two row tiles at width 11008, the last one partial."""
+    k = jax.random.key(n + level)
+    L, m = 2, 40
+    g = jax.random.normal(k, (L, m, n), jnp.bfloat16)
+    p = jax.random.normal(jax.random.fold_in(k, 1), (L, m, n), jnp.bfloat16)
+    na = n >> level
+    ms = jax.random.normal(jax.random.fold_in(k, 2), (L, m, na)) * 0.1
+    vs = jnp.abs(jax.random.normal(jax.random.fold_in(k, 3),
+                                   (L, m, na))) * 0.01
+    pn = jnp.arange(L, dtype=jnp.float32) * 0.3
+    run = lambda: kg.gwt_adam_tile_fused(
+        g, p, ms, vs, pn, jnp.float32(0.0025), jnp.float32(0.0),
+        level=level, gamma=1.01, use_limiter=use_limiter,
+        weight_decay=False, interpret=True)
+    new = run()
+    monkeypatch.setattr(kg, "_dht_adam_core", _three_pass_core)
+    old = run()
+    assert new[0].dtype == jnp.bfloat16
+    for tag, a, b in zip(("new_p", "m", "v", "new_norm"), new, old):
+        a, b = np.asarray(a), np.asarray(b)
+        np.testing.assert_array_equal(a.view(f"u{a.itemsize}"),
+                                      b.view(f"u{b.itemsize}"), err_msg=tag)
+
+
+def test_fused_write_counts_one_pass_schedule():
+    """At trace time each fused wrapper records, as the counter
+    ``gwt.kernel.one_pass``, whether its bucket takes the one-pass (bf16)
+    or the three-pass schedule, in buckets and gradient elements."""
+    from repro import obs
+    kw = _fused_write_kw(2)
+    tracer = obs.Tracer()
+    obs.configure(tracer=tracer)
+    try:
+        for dtype, L in ((jnp.bfloat16, 2), (jnp.float32, 1)):
+            g, p, st, pn = _fused_write_inputs(L, 8, 256, 2, dtype=dtype)
+            jax.eval_shape(lambda *a: gops.fused_write_update(
+                *a, impl="interpret", **kw), g, p, st, jnp.int32(0), pn)
+    finally:
+        obs.shutdown()
+    got = [e for e in tracer.events if e["name"] == "gwt.kernel.one_pass"]
+    assert [e["cat"] for e in got] == ["gwt", "gwt"]
+    assert [e["args"] for e in got] == [
+        {"buckets_one_pass": 1.0, "elements_one_pass": 2 * 8 * 256.0,
+         "buckets_three_pass": 0.0, "elements_three_pass": 0.0},
+        {"buckets_one_pass": 0.0, "elements_one_pass": 0.0,
+         "buckets_three_pass": 1.0, "elements_three_pass": 8 * 256.0}]
+
+
 def test_fused_update_backend_sweep(kernel_impl):
     """Backend-sweep tier (conftest fixture): the optimizer-facing
     fused_update entry point agrees with the pure-jnp ref oracle under

@@ -72,7 +72,17 @@ def _fused_q8_args(L, m, n):
             ((L,), U32), ((L,), U32), ((L,), F32), ((), F32), ((), F32)]
 
 
-@pytest.mark.parametrize("shape", [ATTN, MLP], ids=["attn", "mlp"])
+# The benchmark cells' widest and most distinct buckets (bf16 g/p, so the
+# one-pass schedule), rows cut to four row tiles: Qwen2.5-3B's gate/up
+# (11008), down (2048) and k/v (256) stacks, Mistral-7B's gate/up (14336)
+# and down (4096).
+CELLS = [(2, 128, 11008), (1, 256, 2048), (2, 128, 14336), (1, 128, 4096),
+         (2, 2048, 256)]
+
+
+@pytest.mark.parametrize("shape", [ATTN, MLP] + CELLS,
+                         ids=["attn", "mlp", "qwen-gate_up", "qwen-down",
+                              "mistral-gate_up", "mistral-down", "qwen-kv"])
 def test_fused_f32_compiles(one_chip, shape):
     fn = functools.partial(kg.gwt_adam_tile_fused, level=LEVEL, gamma=1.01,
                            use_limiter=True, weight_decay=False)
