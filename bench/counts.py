@@ -5,37 +5,24 @@ divides them by a measured time.  They count the work the algorithm
 requires, not what an implementation happens to do (recomputation, lane
 shuffles done as matrix products), so a faster implementation can never
 read above 100%.
+
+This module keeps the arithmetic of the GWT update, which every
+architecture shares, over the architecture's own ``layout``; what a
+token's forward and backward need is the architecture module's
+``model_flops_per_token`` (``bench/model.py``).
 """
 
 from __future__ import annotations
 
-from bench.model import Spec, layout, numel
+from bench.model import numel
 
 # bytes of one element of each dtype name the configurations use
 DTYPE_BYTES = {"bfloat16": 2, "float16": 2, "float32": 4}
 
 
-def matmul_params(spec: Spec) -> int:
-    """Non-embedding matrix parameters plus the output head (PaLM's N):
-    the input embedding is a gather, not a product; norms and biases are
-    not matrices."""
-    d, ff = spec.d, spec.ff
-    per_layer = (d * spec.q_width + 2 * d * spec.kv_width
-                 + spec.q_width * d + 3 * d * ff)
-    return spec.layers * per_layer + d * spec.vocab
-
-
-def model_flops_per_token(spec: Spec, seq: int) -> float:
-    """PaLM's model FLOPs per trained token (Chowdhery et al. 2022, App. B):
-    ``6·N + 12·L·H·Q·T``, forward and backward, recomputation not
-    counted."""
-    attn = 12 * spec.layers * spec.heads * spec.head_dim * seq
-    return 6.0 * matmul_params(spec) + attn
-
-
-def gwt_elements(spec: Spec, level: int) -> int:
+def gwt_elements(arch, spec, level: int) -> int:
     """Parameters the wavelet rule updates (the fused kernel's input)."""
-    return sum(numel(lf.shape) for lf in layout(spec, level)
+    return sum(numel(lf.shape) for lf in arch.layout(spec, level)
                if lf.rule == "gwt")
 
 
@@ -66,9 +53,9 @@ def gwt_flops_per_element(level: int) -> float:
     return 2 * butterfly + 11 * band + (1 - band) + 2 + 3
 
 
-def gwt_kernel_work(spec: Spec, level: int) -> tuple:
+def gwt_kernel_work(arch, spec, level: int) -> tuple:
     """``(flops, bytes)`` of one optimizer step's wavelet updates."""
-    n = gwt_elements(spec, level)
+    n = gwt_elements(arch, spec, level)
     b = DTYPE_BYTES[spec.dtype]
     return (n * gwt_flops_per_element(level),
             n * gwt_bytes_per_element(level, b, b))

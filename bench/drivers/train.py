@@ -13,8 +13,18 @@ timing the last ``log_every`` steps to size the window.  The window runs the sam
 segments, enough of them to fill ``--seconds``, timed from the first
 dispatch to the moment the last step's outputs are ready.  After the
 window the peak device memory is read, the program's state freed, and the
-plain reference (``bench/reference.py``) trains the same first steps from
-the same seed.
+architecture's plain reference trains the same first steps from the same
+seed.  Everything architecture-specific — the configuration's reading, the
+parameter layout, the program's configuration object and the reference —
+comes from the configuration's module ``bench/arch/<bench_arch>.py``, so
+this one driver runs every architecture.
+
+In a ``--trace 1`` run the window is traced, and after it the window's
+superstep is traced and compiled once more (:meth:`Program.traced_step`):
+the device time of each of the program's named scopes and the idle time
+under its host spans come from the trace and that compile's HLO text
+(``bench/scopes.py``), and the program's trace-time counters from that
+tracing.
 
 The loop keeps the launcher's chunking: supersteps end on an absolute
 grid of ``log_every`` steps (``TrainLoop``'s default ``max_chunk``), so
@@ -27,6 +37,7 @@ compiles (or loads) every one of them.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import gc
 import math
@@ -34,28 +45,36 @@ import shutil
 import sys
 import tempfile
 import time
-from typing import Optional
+from typing import Any, Dict, Optional
 
 import numpy as np
 
-from bench import check, data, peaks
+from bench import check, data, peaks, scopes
 from bench import trace as trace_lib
 from bench import weights as wlib
 from bench.harness import NoAccelerator
-from bench.model import Spec, load_spec
-from bench.reference import Reference, slice_norms
+from bench.reference import slice_norms
 
 
 @dataclasses.dataclass
 class RunInfo:
-    """What the per-layer readers (``bench/metrics``) read."""
-    spec: Spec
+    """What the per-layer readers (``bench/metrics``) read.  The last five
+    are read in ``--trace 1`` runs only (``None`` otherwise)."""
+    arch: Any                  # the architecture module (bench/model.py)
+    spec: Any                  # its Spec of the configuration
     traffic: dict
     chips: int
     peaks: dict
     tokens_per_s: float
     steps: int
-    trace: Optional[trace_lib.Summary]
+    trace: Optional[scopes.Summary]
+    # device ms per step of each of scopes.SCOPES
+    scopes: Optional[Dict[str, float]] = None
+    # idle ms per step under each group of scopes.IDLE_UNDER
+    idle_under: Optional[Dict[str, Optional[float]]] = None
+    # trace-time counters: name -> value -> sum over the step's samples
+    counters: Optional[Dict[str, Dict[str, float]]] = None
+    hlo: Optional[str] = None  # the window superstep's compiled HLO text
 
 
 @dataclasses.dataclass
@@ -88,22 +107,6 @@ def opt_settings(traffic: dict) -> dict:
                               "eps", "gamma")}
 
 
-def program_config(spec: Spec, seq: int):
-    """The program's configuration object for ``spec``."""
-    from repro.configs.base import ModelConfig
-    if spec.sliding_window and spec.sliding_window < seq:
-        raise ValueError(f"{spec.name}: a sliding window of "
-                         f"{spec.sliding_window} below seq {seq} is not run "
-                         f"by this driver")
-    return ModelConfig(
-        name=spec.name, n_layers=spec.layers, d_model=spec.d,
-        n_heads=spec.heads, n_kv_heads=spec.kv_heads,
-        head_dim=spec.head_dim, d_ff=spec.ff, vocab=spec.vocab,
-        pattern=("attn",), qkv_bias=spec.qkv_bias,
-        rope_theta=spec.rope_theta, tie_embeddings=spec.tied,
-        norm_eps=spec.norm_eps, dtype=spec.dtype, remat=True)
-
-
 def check_layout(flat: dict, cfg) -> None:
     """The benchmark's weights must be the tree the program would build."""
     import jax
@@ -128,14 +131,42 @@ def band_norms(plan, opt_state, b1: float) -> dict:
     return out
 
 
-def change_norms(params: dict, spec: Spec, seed: int, level: int) -> dict:
+def change_norms(params: dict, arch, spec, seed: int, level: int) -> dict:
     """Per leaf and layer, ``‖p - p0‖`` with ``p0`` drawn again from the
     seed (the program's own first weights were donated)."""
-    p0 = wlib.make_weights(spec, seed, level)
+    p0 = wlib.make_weights(arch, spec, seed, level)
     flat = wlib.flatten(params)
     out = {path: slice_norms(path, flat[path], p0[path]) for path in p0}
     del p0
     return out
+
+
+def counter_sums(events) -> Dict[str, Dict[str, float]]:
+    """The counter samples (``ph`` ``C``) of an ``obs.Tracer``'s events,
+    summed per counter name and value."""
+    out: Dict[str, Dict[str, float]] = {}
+    for ev in events:
+        if ev["ph"] == "C":
+            acc = out.setdefault(ev["name"], {})
+            for k, v in ev["args"].items():
+                acc[k] = acc.get(k, 0.0) + v
+    return out
+
+
+@contextlib.contextmanager
+def no_compile_cache():
+    """JAX's persistent compilation cache off inside the ``with``, back as
+    it was after it."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", before)
+        cc.reset_cache()
 
 
 class Watch:
@@ -181,10 +212,11 @@ class Program:
         from repro.launch.mesh import make_mesh_context
         from repro.models import lm
         from repro.runtime.fault_tolerance import TrainLoop
-        self.spec = load_spec(cell.config_path)
+        self.arch = cell.arch()
+        self.spec = self.arch.load_spec(cell.config_path)
         self.traffic = tr = cell.traffic
         self.opt = opt_settings(tr)
-        self.cfg = program_config(self.spec, tr["seq"])
+        self.cfg = self.arch.program_config(self.spec, tr["seq"])
         self.ctx = make_mesh_context(kernel_impl="auto")
         if require_tpu and self.ctx.kernel_impl != "pallas":
             raise RuntimeError(f"kernel impl resolved to "
@@ -212,7 +244,7 @@ class Program:
         # (what ended, when): the set-up split that run() logs
         marks = [("interpreter, imports, TPU init, program build",
                   time.monotonic())]
-        flat = wlib.make_weights(spec, seed, level)
+        flat = wlib.make_weights(self.arch, spec, seed, level)
         check_layout(flat, self.cfg)
         params = wlib.nest(flat)
         del flat
@@ -232,7 +264,8 @@ class Program:
             params, opt_state, more = self.loop.run(
                 params, opt_state, start_step=1, num_steps=n)
         readings = {"losses": list(losses) + list(more), "grad_band": grad,
-                    "change": change_norms(params, spec, seed, level)}
+                    "change": change_norms(params, self.arch, spec, seed,
+                                           level)}
         marks.append((f"steps 2-{n} (compile or cache load) and the "
                       f"check's readings", time.monotonic()))
         self.marks = marks
@@ -250,21 +283,47 @@ class Program:
         return self.loop._chunk_end(step, step + self.traffic["log_every"]) \
             - step
 
+    def window_batch(self, start: int) -> dict:
+        """The shapes of the batches of the superstep that starts at
+        ``start``."""
+        tr = self.traffic
+        sds = self.jax.ShapeDtypeStruct(
+            (self.chunk_at(start), tr["batch"], tr["seq"]), np.int32)
+        return {"tokens": sds, "labels": sds}
+
     def memory_analysis(self, params, opt_state, start: int) -> dict:
         """Bytes of the window's superstep (the one that starts at
         ``start``) by XLA's buffer assignment: the same program compiled
         once more (a compile-cache hit).  The allocator's
         ``peak_bytes_in_use`` does not count a program's temporaries on
         this chip, so the peak is the larger of the two."""
-        jax = self.jax
-        tr = self.traffic
-        sds = jax.ShapeDtypeStruct(
-            (self.chunk_at(start), tr["batch"], tr["seq"]), np.int32)
         comp = self.loop._superstep.lower(
-            params, opt_state, {"tokens": sds, "labels": sds}).compile()
+            params, opt_state, self.window_batch(start)).compile()
         ma = comp.memory_analysis()
         return {k: int(getattr(ma, k + "_size_in_bytes"))
                 for k in ("argument", "output", "alias", "temp")}
+
+    def traced_step(self, params, opt_state, start: int) -> tuple:
+        """``(hlo_text, counters)`` of the window's superstep, traced and
+        compiled once more after the window; the timed program is not
+        touched.  JAX's in-memory caches are cleared first, so that every
+        jitted function of the step is traced again and the program's
+        trace-time counters fire under an ``obs.Tracer``.  The compile
+        bypasses the persistent cache, whose key leaves out the name stack:
+        a cached executable carries the ``op_name``s of whichever build
+        wrote it."""
+        from repro import obs
+        self.jax.clear_caches()
+        tracer = obs.Tracer()
+        obs.configure(tracer=tracer)
+        try:
+            lowered = self.loop._superstep.lower(params, opt_state,
+                                                 self.window_batch(start))
+        finally:
+            obs.shutdown()
+        with no_compile_cache():
+            hlo = lowered.compile().as_text()
+        return hlo, counter_sums(tracer.events)
 
 
 def run(cell, *, seed: int, seconds: float, trace: bool, clock0: float,
@@ -284,7 +343,7 @@ def run(cell, *, seed: int, seconds: float, trace: bool, clock0: float,
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
 
     prog = Program(cell, require_tpu)
-    tr, spec = prog.traffic, prog.spec
+    tr, spec, arch = prog.traffic, prog.spec, prog.arch
     params, opt_state, prog_readings = prog.first_steps(seed)
     every = tr["log_every"]
     # warm-up: the superstep up to the first log boundary, then one of the
@@ -339,18 +398,25 @@ def run(cell, *, seed: int, seconds: float, trace: bool, clock0: float,
     log(f"window {window:.3f}s, {tokens_per_s:.1f} tokens/s; allocator "
         f"peak {allocator_peak} B, step {step_bytes} B {ma} (read in "
         f"{time.monotonic() - t:.2f}s)")
+    summary = hlo = counters = scope_ms = idle_ms = None
+    if trace:
+        t = time.monotonic()
+        hlo, counters = prog.traced_step(params, opt_state, start)
+        log(f"window superstep traced and compiled again in "
+            f"{time.monotonic() - t:.2f}s; counters {counters}")
     del params, opt_state, prog
     gc.collect()
 
-    summary = None
     if trace:
-        summary = trace_lib.summarize(trace_lib.find_xplane(trace_dir))
+        summary = scopes.summarize(trace_lib.find_xplane(trace_dir))
         if not keep_trace:
             shutil.rmtree(trace_dir, ignore_errors=True)
+        scope_ms, idle_ms = scopes.step_split(summary, hlo, steps)
+        log(f"ms per step by scope {scope_ms}, idle under {idle_ms}")
 
     t = time.monotonic()
-    ref = Reference(spec, opt_settings(tr), tr["seq"]).run(
-        wlib.make_weights(spec, seed, tr["optimizer"]["level"]),
+    ref = arch.Reference(spec, opt_settings(tr), tr["seq"]).run(
+        wlib.make_weights(arch, spec, seed, tr["optimizer"]["level"]),
         [data.make_source(seed, spec.vocab, tr).batch(i)
          for i in range(CHECK_STEPS)])
     log(f"reference {time.monotonic() - t:.2f}s")
@@ -376,8 +442,10 @@ def run(cell, *, seed: int, seconds: float, trace: bool, clock0: float,
         if require_tpu:
             raise
         pk = None
-    info = RunInfo(spec=spec, traffic=tr, chips=cell.chips, peaks=pk,
-                   tokens_per_s=tokens_per_s, steps=steps, trace=summary)
+    info = RunInfo(arch=arch, spec=spec, traffic=tr, chips=cell.chips,
+                   peaks=pk, tokens_per_s=tokens_per_s, steps=steps,
+                   trace=summary, scopes=scope_ms, idle_under=idle_ms,
+                   counters=counters, hlo=hlo)
     return Outcome(
         correct=correct, attempted=steps, failed=failed,
         end_to_end={"tokens_per_s": tokens_per_s, "peak_hbm_gb": peak / 1e9,

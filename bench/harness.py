@@ -7,15 +7,16 @@ its per-layer metrics, print the result line.
 Everything a cell needs is found by the names in ``BENCHMARK.json``:
 
 * ``bench/configs/<config>.json`` — the configuration (the ``file`` of its
-  ``configs`` entry);
+  ``configs`` entry); its ``bench_arch`` names ``bench/arch/<arch>.py``,
+  the architecture module (``bench/model.py`` says what it provides);
 * ``bench/traffic/<traffic>.json`` — the traffic mix or job; its
   ``driver`` names ``bench/drivers/<driver>.py``, the code that runs it;
 * ``bench/limits/<workload>.json`` — the limits of the correctness check;
 * ``bench/metrics/<metric>.py`` — one reader per per-layer metric, a
   ``read(run) -> float | None`` over what the driver measured.
 
-A new cell, configuration, traffic mix or metric is a new file and a new
-``BENCHMARK.json`` entry; no existing file changes.
+A new cell, configuration, architecture, traffic mix or metric is a new
+file and a new ``BENCHMARK.json`` entry; no existing file changes.
 
 The last line of standard output is the result: ``correct``,
 ``attempted``, ``failed``, ``metrics``, ``device``, with ``--trace 1``
@@ -57,6 +58,8 @@ class Cell:
                    if c["name"] == self.entry["config"])
         self.config_path = self.root / cfg["file"]
         here = self.root / "bench"
+        arch = json.loads(self.config_path.read_text())["bench_arch"]
+        self.arch_path = here / "arch" / f"{arch}.py"
         self.traffic_path = here / "traffic" / f"{self.entry['traffic']}.json"
         self.traffic = json.loads(self.traffic_path.read_text())
         self.limits = json.loads(
@@ -78,6 +81,9 @@ class Cell:
 
     def driver(self):
         return load_module(self.driver_path)
+
+    def arch(self):
+        return load_module(self.arch_path)
 
 
 def load_module(path: pathlib.Path):

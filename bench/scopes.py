@@ -13,6 +13,9 @@ as the device's ops.
 summary does not keep: every host event clipped to the window, and the
 idle intervals whose lengths ``idle_gaps`` lists.  The functions below are
 pure over those, so they can be checked on a hand-made trace.
+:func:`step_split` is the one reduction of a traced window to ms per step
+by scope and by group of host spans: the training driver fills its
+``RunInfo`` with it, and ``bench/tools/layers.py`` prints it.
 """
 
 from __future__ import annotations
@@ -31,6 +34,17 @@ _INSTR = re.compile(r'^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=.*?'
 # a transformation's wrapper around a name-stack component: ``jvp(``,
 # ``transpose(``, ``jit(``
 _WRAPPER = re.compile(r"[\w\-]+\(")
+
+# the step's named scopes (device time of each, ms per step)
+SCOPES = ("train.fwd_bwd", "train.update", "optim.pack", "gwt.kernel",
+          "train.dp_reduce")
+# host spans under which an idle gap counts as waiting for input, or for
+# the loop's own synchronisation (fetches, log lines, dispatch)
+IDLE_UNDER = {
+    "input": ("train.input_wait", "train.place", "train.close"),
+    "sync": ("train.block", "train.log", "train.dispatch",
+             "train.dispatch_first"),
+}
 
 
 @dataclasses.dataclass
@@ -93,6 +107,23 @@ def idle_under(summary: Summary, names: Iterable[str]) -> Optional[float]:
         if any(s <= mid <= e for s, e in spans):
             idle += hi - lo
     return idle / max(len(summary.chips), 1)
+
+
+def step_split(summary: Summary, hlo_text: str, steps: int) -> tuple:
+    """``(scopes, idle)`` of a traced window of ``steps`` steps and the
+    HLO text of its program, in ms per step: the device time of each of
+    :data:`SCOPES`, and the idle time under each group of
+    :data:`IDLE_UNDER` (``None`` where no span of the group is in the
+    window)."""
+    smap = scope_map(hlo_text)
+    per_step = 1e3 / steps
+    scoped = {sc: per_step * scope_seconds(summary, smap, sc)
+              for sc in SCOPES}
+    idle = {}
+    for group, names in IDLE_UNDER.items():
+        sec = idle_under(summary, names)
+        idle[group] = None if sec is None else per_step * sec
+    return scoped, idle
 
 
 def summarize(xplane_path: str) -> Summary:

@@ -4,17 +4,17 @@ fp8 control comes out not correct, and a machine without a TPU or a
 directory without the program gives no result."""
 
 import json
+import shutil
 import time
 
 import jax
 import pytest
 
-from bench import check, data, harness
+from bench import check, counts, data, harness, peaks, trace
 from bench import weights as wlib
+from bench.arch import dense
 from bench.drivers.train import CHECK_STEPS, opt_settings
-from bench.model import load_spec
-from bench.reference import Reference
-from bench.tests.tiny import CELL, LIMITS, make_root
+from bench.tests.tiny import BENCH, CELL, LIMITS, make_root
 
 SEED = 2**33 + 5     # past 32 bits: a seed is taken whole
 
@@ -53,17 +53,75 @@ def test_new_config_traffic_and_metric_are_found_by_name(tmp_path):
     (root / "BENCHMARK.json").write_text(json.dumps(bench))
     cell = harness.Cell(root, "tiny2.t16")
     assert cell.traffic["seq"] == 16
-    assert load_spec(cell.config_path).name == "tiny2"
+    assert cell.arch().load_spec(cell.config_path).name == "tiny2"
     assert cell.limits == LIMITS
     assert cell.driver().run.__module__.endswith("train")
 
     class Run:
         steps, trace, peaks, tokens_per_s = 7, None, None, 0.0
+        scopes = idle_under = counters = None
     assert harness.read_per_layer(cell, Run()) == {
         "steps_seen": {"value": 7.0, "unit": "steps"}}
     # the metric is the new cell's alone
     assert "steps_seen" not in harness.read_per_layer(
         harness.Cell(root, CELL), Run())
+    changed = [p for p, v in before.items() if p.read_bytes() != v
+               and p.name != "BENCHMARK.json"]
+    assert changed == []
+
+
+def test_a_new_architecture_enters_as_new_files(tmp_path):
+    """An architecture module, a configuration naming it, a traffic mix and
+    the cell's limits: the shared driver runs the cell, and the counts the
+    per-layer metrics read are the new module's."""
+    root = make_root(tmp_path)
+    before = {p: p.read_bytes() for p in root.rglob("*") if p.is_file()}
+    b = root / "bench"
+    shutil.copy(BENCH / "tests" / "toy_arch.py", b / "arch" / "toy.py")
+    cfg = json.loads((b / "configs" / "tiny.json").read_text())
+    cfg["bench_arch"] = "toy"
+    (b / "configs" / "toy.json").write_text(json.dumps(cfg))
+    tr = json.loads((b / "traffic" / "t32.json").read_text())
+    tr["seq"] = 24
+    (b / "traffic" / "t24.json").write_text(json.dumps(tr))
+    (b / "limits" / "toy.t24.json").write_text(json.dumps(LIMITS))
+    bench = json.loads((root / "BENCHMARK.json").read_text())
+    bench["configs"].append(dict(bench["configs"][0], name="toy",
+                                 file="bench/configs/toy.json"))
+    bench["workloads"].append({"name": "toy.t24", "config": "toy",
+                               "traffic": "t24", "chips": 1, "why": "test"})
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+
+    cell = harness.Cell(root, "toy.t24")
+    out = cell.driver().run(cell, seed=SEED, seconds=0.1, trace=False,
+                            clock0=time.monotonic(), require_tpu=False,
+                            compile_cache=False)
+    assert out.correct, out.check
+    run = out.run
+    toy = run.arch
+    assert toy.load_spec is not dense.load_spec
+    assert not any("ffn" in lf.path for lf in toy.layout(run.spec, 2))
+    # the counts come from the toy: a v5e's peaks and one kernel event of
+    # 1 ms per step stand in for a chip's
+    run.peaks = peaks.peaks_for("TPU v5 lite")
+    kernel = ('%_fused_write_update.1 = bf16[8] custom-call(bf16[8] %g), '
+              'custom_call_target="tpu_custom_call"')
+    run.trace = trace.Summary(
+        window_s=1.0, idle_gaps=[],
+        chips=[trace.Chip("/device:TPU:0",
+                          [(kernel, 0.0, 1e-3 * run.steps)], 0.5)])
+    read = lambda m: cell.reader(m).read(run)
+    flops = toy.model_flops_per_token(run.spec, 24)
+    assert read("step_mfu") == pytest.approx(
+        100.0 * flops * run.tokens_per_s / 197e12)
+    dense_spec = dense.load_spec(cell.config_path)
+    assert flops < dense.model_flops_per_token(dense_spec, 24)
+    n = sum(lf.shape[0] * lf.shape[1] * (lf.shape[2] if len(lf.shape) > 2
+                                         else 1)
+            for lf in toy.layout(run.spec, 2) if lf.rule == "gwt")
+    assert n < counts.gwt_elements(dense, dense_spec, 2)
+    assert read("gwt_kernel_roofline") == pytest.approx(
+        100.0 * max(10.0 * n / 819e9, 14.5 * n / 197e12) / 1e-3)
     changed = [p for p, v in before.items() if p.read_bytes() != v
                and p.name != "BENCHMARK.json"]
     assert changed == []
@@ -119,13 +177,13 @@ def no_compile_cache():
 
 def test_fp8_control_is_not_correct(root, no_compile_cache):
     cell = harness.Cell(root, CELL)
-    spec, tr = load_spec(cell.config_path), cell.traffic
-    opt = opt_settings(tr)
-    weights = wlib.make_weights(spec, SEED, opt["level"])
+    arch, tr = cell.arch(), cell.traffic
+    spec, opt = arch.load_spec(cell.config_path), opt_settings(tr)
+    weights = wlib.make_weights(arch, spec, SEED, opt["level"])
     batches = [data.make_source(SEED, spec.vocab, tr).batch(i)
                for i in range(CHECK_STEPS)]
-    ref = Reference(spec, opt, tr["seq"]).run(weights, batches)
-    control = Reference(spec, opt, tr["seq"], precision="fp8").run(
+    ref = arch.Reference(spec, opt, tr["seq"]).run(weights, batches)
+    control = arch.Reference(spec, opt, tr["seq"], precision="fp8").run(
         weights, batches)
     correct, nums = check.compare(control, ref, cell.limits)
     assert not correct, nums

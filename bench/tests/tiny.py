@@ -1,8 +1,9 @@
 """A tiny training cell in a scratch benchmark root, for tests on the CPU.
 
 The root holds its own ``BENCHMARK.json``, configuration, traffic mix and
-limits, and copies of the drivers and metric readers, so a test can add a
-file there and see the harness find it without touching the repository.
+limits, and copies of the architecture modules, drivers and metric
+readers, so a test can add a file there and see the harness find it
+without touching the repository.
 """
 
 import json
@@ -23,7 +24,7 @@ LIMITS = {"loss_gap": 1e-4, "grad_norm_gap": 0.05, "update_norm_gap": 0.08}
 
 def make_root(tmp: pathlib.Path) -> pathlib.Path:
     root = pathlib.Path(tmp)
-    for sub in ("drivers", "metrics"):
+    for sub in ("arch", "drivers", "metrics"):
         shutil.copytree(BENCH / sub, root / "bench" / sub)
     for sub in ("configs", "traffic", "limits"):
         (root / "bench" / sub).mkdir(parents=True)

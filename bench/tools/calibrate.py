@@ -3,9 +3,10 @@ cell's own size, on the chip, in one process:
 
 * the program's numbers (``bench/check.py``) on each seed — the lower
   readings;
-* the fp8 control (``Reference(precision="fp8")`` in the program's place)
-  and the half-batch fault (``Reference(fault="half_batch")``) on the first
-  ``--faults`` seeds — the upper readings;
+* the fp8 control (the architecture's ``Reference(precision="fp8")`` in
+  the program's place) and the half-batch fault
+  (``Reference(fault="half_batch")``) on the first ``--faults`` seeds —
+  the upper readings;
 * with ``--bf16``, on every seed, the reference with its products' operands
   rounded to bfloat16 (``Reference(precision="bf16")``), the program's own
   operand type: how far that rounding alone, with no code of the program,
@@ -37,7 +38,6 @@ import numpy as np  # noqa: E402
 
 from bench import check, data, harness  # noqa: E402
 from bench import weights as wlib  # noqa: E402
-from bench.reference import Reference  # noqa: E402
 
 TOP = 1e-3       # the share of a slice's elements that counts as its top
 
@@ -133,7 +133,7 @@ def calibrate(prog, seed: int, steps: int, refs: dict) -> dict:
     prog_params = host_params(wlib.flatten(params))
     del params, opt_state
     gc.collect()
-    weights = wlib.make_weights(spec, seed, opt["level"])
+    weights = wlib.make_weights(prog.arch, spec, seed, opt["level"])
     batches = [data.make_source(seed, spec.vocab, tr).batch(j)
                for j in range(steps)]
     (_, reference), *others = refs.items()
@@ -191,7 +191,8 @@ def main(argv=None):
             out.write(line + "\n")
             out.flush()
 
-    ref = lambda **kw: Reference(spec, opt, tr["seq"], limiter=lim, **kw)
+    ref = lambda **kw: prog.arch.Reference(spec, opt, tr["seq"], limiter=lim,
+                                           **kw)
     reference = ref()
     faults = {"control_fp8": ref(precision="fp8"),
               "fault_half_batch": ref(fault="half_batch")}

@@ -1,22 +1,20 @@
-"""Run one cell traced and split its step by the program's own names: device
-time per named scope of the step, idle device time per host span of the
-train loop, both read from the same profiler trace (``bench/scopes.py``).
+"""Run one cell traced and print its step split by the program's own names:
+device time per named scope of the step, idle device time per group of the
+train loop's host spans, both read from the same profiler trace.
 
     python3 bench/tools/layers.py --workload qwen2.5-3b-l9.s256 --seed 7 \
         --seconds 10 --out layers.jsonl
 
-The run is the benchmark's own ``--trace 1`` run of the cell, with the
-compile cache off so that every program is compiled here: after the
-window ``bench/drivers/train.py`` compiles the window's superstep again
-for its memory analysis, and this tool keeps that compile's HLO text,
-whose instruction names are the op events' names.  It prints one line:
+The run is the benchmark's own ``--trace 1`` run of the cell: the driver
+(``bench/drivers/train.py``) reduces the trace with ``bench/scopes.py``
+(``step_split``) into the ``RunInfo`` the per-layer metrics read, and this
+tool prints that same split with what it takes to read it.  One line:
 
 * ``ms_per_step``: device busy and idle time, the union of the ops under
-  each scope (``train.fwd_bwd``, ``train.update``, ``optim.pack``,
-  ``gwt.kernel``, ``train.dp_reduce``), the GWT kernel found by its
-  instruction name (as ``gwt_kernel_ms`` finds it), the busy time under
-  neither ``train.fwd_bwd`` nor ``train.update``, and the idle time whose
-  gap lies under ``INPUT`` spans and under ``SYNC`` spans;
+  each scope (``scopes.SCOPES``), the GWT kernel found by its instruction
+  name (as ``gwt_kernel_ms`` finds it), the busy time under neither
+  ``train.fwd_bwd`` nor ``train.update``, and the idle time whose gap lies
+  under the input spans and under the sync spans (``scopes.IDLE_UNDER``);
 * ``unscoped_ops``: the ops under neither scope, by device time;
 * ``gaps``: the longest idle gaps, each with the benchmark's label, the
   innermost program span (``train.*``) over its middle, and the program
@@ -32,9 +30,7 @@ import collections
 import json
 import math
 import pathlib
-import shutil
 import sys
-import tempfile
 import time
 
 CLOCK0 = time.monotonic()
@@ -42,11 +38,6 @@ ROOT = pathlib.Path(__file__).resolve().parents[2]
 sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
 from bench import harness, scopes, trace  # noqa: E402
-
-SCOPES = ("train.fwd_bwd", "train.update", "optim.pack", "gwt.kernel",
-          "train.dp_reduce")
-INPUT = ("train.input_wait", "train.place", "train.close")
-SYNC = ("train.block", "train.log", "train.dispatch", "train.dispatch_first")
 
 
 def _innermost(mid: float, spans) -> str:
@@ -58,14 +49,12 @@ def _innermost(mid: float, spans) -> str:
     return best[1] if best else ""
 
 
-def split(t: scopes.Summary, hlo: str, steps: int) -> dict:
-    """The step's split, in ms per step, from a traced window of ``steps``
-    steps and the HLO text of its program."""
-    smap = scopes.scope_map(hlo)
-    ms = {"busy": t.busy_s, "idle": t.window_s - t.busy_s,
-          "gwt_kernel_by_name": t.op_seconds(trace.is_gwt_kernel)}
-    for sc in SCOPES:
-        ms[sc] = scopes.scope_seconds(t, smap, sc)
+def report(run) -> dict:
+    """The split of a traced run (the driver's ``RunInfo``), in ms per
+    step, with the ops, gaps and spans behind it."""
+    t, steps = run.trace, run.steps
+    smap = scopes.scope_map(run.hlo)
+    per_step = 1e3 / steps
     own = {n: set(scopes.components(smap.get(trace.op_name(n), "")))
            for c in t.chips for n, _, _ in c.ops}
     main = {"train.fwd_bwd", "train.update"}
@@ -76,12 +65,15 @@ def split(t: scopes.Summary, hlo: str, steps: int) -> dict:
         for n, s, e in c.ops:
             if not own[n] & main:
                 rest[trace.op_name(n)] += (e - s) / len(t.chips)
-    ms["unscoped"] = t.busy_s - scoped / max(len(t.chips), 1)
-    ms["input_wait"] = scopes.idle_under(t, INPUT)
-    ms["sync_wait"] = scopes.idle_under(t, SYNC)
-    per_step = {k: None if v is None else 1e3 * v / steps
-                for k, v in ms.items()}
-    unscoped = [{"op": n, "ms_per_step": 1e3 * v / steps,
+    ms = {"busy": per_step * t.busy_s,
+          "idle": per_step * (t.window_s - t.busy_s),
+          "gwt_kernel_by_name": per_step * t.op_seconds(trace.is_gwt_kernel),
+          **run.scopes,
+          "unscoped": per_step * (t.busy_s
+                                  - scoped / max(len(t.chips), 1)),
+          "input_wait": run.idle_under["input"],
+          "sync_wait": run.idle_under["sync"]}
+    unscoped = [{"op": n, "ms_per_step": per_step * v,
                  "op_name": smap.get(n, "")}
                 for n, v in rest.most_common(12)]
     gaps = sorted(t.gap_spans, key=lambda g: g[0] - g[1])[:12]
@@ -99,48 +91,23 @@ def split(t: scopes.Summary, hlo: str, steps: int) -> dict:
     span_rows = {n: {"count": len(d), "total_ms": 1e3 * sum(d),
                      "max_ms": 1e3 * max(d)} for n, d in spans.items()}
     named = {sc: sum(sc in scopes.components(v) for k, v in smap.items()
-                     if not k.startswith("%")) for sc in SCOPES}
-    return {"ms_per_step": per_step, "unscoped_ops": unscoped,
+                     if not k.startswith("%")) for sc in scopes.SCOPES}
+    return {"ms_per_step": ms, "unscoped_ops": unscoped,
             "gaps": gap_rows, "spans": span_rows, "instructions": named}
 
 
 def layers(root, name: str, seed: int, seconds: float,
-           require_tpu: bool = True) -> dict:
+           require_tpu: bool = True, **run_kw) -> dict:
     """Run cell ``name`` of the benchmark at ``root`` traced; its split."""
-    import jax
-    import numpy as np
-    cache = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
     cell = harness.Cell(root, name)
-    drv = cell.driver()
-    hlo = []
-
-    class Program(drv.Program):
-        def memory_analysis(self, params, opt_state, start):
-            sds = jax.ShapeDtypeStruct(
-                (self.chunk_at(start), self.traffic["batch"],
-                 self.traffic["seq"]), np.int32)
-            hlo.append(self.loop._superstep.lower(
-                params, opt_state, {"tokens": sds, "labels": sds}
-            ).compile().as_text())
-            return super().memory_analysis(params, opt_state, start)
-
-    drv.Program = Program
-    tdir = tempfile.mkdtemp(prefix="bench-layers-")
-    try:
-        out = drv.run(cell, seed=seed, seconds=seconds, trace=True,
-                      clock0=CLOCK0, require_tpu=require_tpu,
-                      keep_trace=tdir, compile_cache=False)
-        t = scopes.summarize(trace.find_xplane(tdir))
-    finally:
-        shutil.rmtree(tdir, ignore_errors=True)
-        jax.config.update("jax_enable_compilation_cache", cache)
+    out = cell.driver().run(cell, seed=seed, seconds=seconds, trace=True,
+                            clock0=CLOCK0, require_tpu=require_tpu, **run_kw)
     line = harness.result_line(cell, out, True)
     return {"workload": name, "seed": seed, "correct": line["correct"],
             "steps": out.run.steps,
             "tokens_per_s": out.end_to_end["tokens_per_s"],
             "metrics": {k: v["value"] for k, v in line["metrics"].items()},
-            **split(t, hlo[-1], out.run.steps)}
+            **report(out.run)}
 
 
 def main(argv) -> int:

@@ -3,7 +3,7 @@
 Matrices are drawn ``N(0, initializer_range)`` (the configuration's own
 ``initializer_range``), norms and biases start at zero (the program's
 RMSNorm scales by ``1 + gamma``, so zero is the published unit scale).
-Leaf ``i`` of :func:`bench.model.layout` draws from ``fold_in(key, i)``:
+Leaf ``i`` of the architecture's ``layout`` draws from ``fold_in(key, i)``:
 the program and the reference get the same values from the same seed, and
 neither makes them.
 """
@@ -14,8 +14,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-
-from bench.model import Spec, layout
 
 
 def key_from_seed(seed: int) -> jax.Array:
@@ -50,21 +48,23 @@ def flatten(tree: dict, prefix: str = "") -> dict:
     return out
 
 
-@functools.partial(jax.jit, static_argnums=(0, 2))
-def _draw(spec: Spec, key, level: int) -> dict:
-    dtype = jnp.dtype(spec.dtype)
+@functools.partial(jax.jit, static_argnums=(0, 1, 2))
+def _draw(leaves: tuple, dtype_name: str, std: float, key) -> dict:
+    dtype = jnp.dtype(dtype_name)
     flat = {}
-    for i, lf in enumerate(layout(spec, level)):
+    for i, lf in enumerate(leaves):
         if lf.init == "zeros":
             flat[lf.path] = jnp.zeros(lf.shape, dtype)
         else:
             x = jax.random.normal(jax.random.fold_in(key, i), lf.shape,
                                   jnp.float32)
-            flat[lf.path] = (x * spec.init_std).astype(dtype)
+            flat[lf.path] = (x * std).astype(dtype)
     return flat
 
 
-def make_weights(spec: Spec, seed: int, level: int) -> dict:
-    """Flat ``{path: array}`` of the initial weights (the tree's dtype is
-    the configuration's ``torch_dtype``)."""
-    return _draw(spec, key_from_seed(seed), level)
+def make_weights(arch, spec, seed: int, level: int) -> dict:
+    """Flat ``{path: array}`` of the initial weights of ``spec`` under the
+    architecture module ``arch`` (the tree's dtype is the configuration's
+    ``torch_dtype``)."""
+    return _draw(tuple(arch.layout(spec, level)), spec.dtype, spec.init_std,
+                 key_from_seed(seed))
