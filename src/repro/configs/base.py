@@ -41,6 +41,12 @@ class ModelConfig:
     # (qwen2-moe's 60) shard EP-cleanly over the 16-way model axis instead
     # of falling back to TP-in-expert (beyond-paper optimization, §Perf).
     expert_padding: int = 0
+    # experts whose weights this chip holds, 0..experts_held-1 (0: all of
+    # n_experts).  The router keeps its n_experts outputs and top_k; the
+    # layer adds the part of the result its own experts give (the chip's
+    # share under expert parallelism, without the exchange).
+    experts_held: int = 0
+    router_aux_coef: float = 0.01        # weight of the load-balance loss
     # attention details
     window: int = 0                      # sliding window for attn_local
     attn_softcap: float = 0.0            # gemma-2 logit soft-capping

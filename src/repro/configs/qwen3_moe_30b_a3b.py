@@ -8,7 +8,7 @@ CONFIG = ModelConfig(
     d_ff=0, vocab=151936,
     pattern=("attn+moe",),
     n_experts=128, top_k=8, d_ff_expert=768,
-    qk_norm=True, rope_theta=1e6,
+    qk_norm=True, rope_theta=1e6, router_aux_coef=0.001,
     tie_embeddings=False, sub_quadratic=False,
 )
 

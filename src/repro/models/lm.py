@@ -23,7 +23,7 @@ from repro.models.layers import (Axes, Builder, cross_entropy, embed_apply,
                                  wsc as _wsc)
 from repro.runtime.context import MeshContext
 
-AUX_COEF = 0.01  # MoE load-balance loss weight
+# one line kept: compiled kernels record the line numbers of calls below
 
 
 def _sqrt_group(n_periods: int) -> int:
@@ -362,7 +362,7 @@ def loss_fn(cfg, params, batch, ctx: MeshContext = None) -> jax.Array:
     logits, _, aux = forward(cfg, params, batch["tokens"], mode="train",
                              mrope_positions=batch.get("mrope_positions"),
                              ctx=ctx)
-    return cross_entropy(logits, batch["labels"]) + AUX_COEF * aux
+    return cross_entropy(logits, batch["labels"]) + cfg.router_aux_coef * aux
 
 
 def constrain_batch(x, bdim: int = 0, seq: bool = False, seq_dim: int = 1,

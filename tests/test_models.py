@@ -225,3 +225,108 @@ def test_moe_expert_padding_is_semantically_invisible(key):
     np.testing.assert_allclose(np.asarray(y0, np.float32),
                                np.asarray(y4, np.float32), atol=1e-5)
     np.testing.assert_allclose(float(aux0), float(aux4), rtol=1e-6)
+
+
+@pytest.mark.parametrize("arch", ["qwen2-moe-a2.7b", "qwen3-moe-30b-a3b"])
+def test_moe_expert_padding_is_invisible_to_a_held_share(arch, key):
+    """A chip holding the first experts of the router's set: padding its
+    expert WEIGHTS leaves its routed outputs and aux loss as they were."""
+    from repro.models import moe as moe_lib
+    from repro.models.layers import Builder
+    cfg0 = configs.get_smoke(arch).with_(expert_padding=0, experts_held=3)
+    cfg4 = cfg0.with_(expert_padding=4)
+    p0 = moe_lib.moe_init(Builder("init", key, jnp.bfloat16), cfg0)
+    p4 = moe_lib.moe_init(Builder("init", key, jnp.bfloat16), cfg4)
+    assert p0["w_up"].shape[0] == 3 and p4["w_up"].shape[0] == 7
+    assert p0["router"].shape[1] == cfg0.n_experts
+    assert p0["router"].dtype == jnp.bfloat16      # stored in the tree dtype
+    for k in ("w_gate", "w_up", "w_down"):
+        p4[k] = p4[k].at[:3].set(p0[k])
+    p4["router"] = p0["router"]
+    if "shared" in p0:
+        p4["shared"] = p0["shared"]
+    x = jax.random.normal(key, (2, 16, cfg0.d_model), jnp.bfloat16)
+    y0, aux0 = moe_lib.moe_apply(p0, cfg0, x)
+    y4, aux4 = moe_lib.moe_apply(p4, cfg4, x)
+    np.testing.assert_array_equal(np.asarray(y0, np.float32),
+                                  np.asarray(y4, np.float32))
+    assert float(aux0) == float(aux4)
+
+
+@pytest.mark.parametrize("skew", [False, True], ids=["capped", "overflow"])
+def test_moe_capped_buffer_matches_the_full_one(skew, key, monkeypatch):
+    """A chip holding 4 of 16 experts dispatches through a buffer capped
+    at half the pairs (and a row tile per held expert) while its held
+    groups fit, and through one for every pair when a skewed router sends
+    them past the cap: either way the output and every gradient are the
+    full buffer's.  Row tiles of 8 (the CPU's grouped matmul takes any)
+    let the skewed groups outgrow the cap at this size."""
+    from repro.models import moe as moe_lib
+    from repro.models.layers import Builder
+    monkeypatch.setattr(moe_lib, "_GMM_ROWS", 8)
+    cfg = configs.get_smoke("qwen3-moe-30b-a3b").with_(
+        n_experts=16, expert_padding=0, experts_held=4)
+    p = moe_lib.moe_init(Builder("init", key, jnp.float32), cfg)
+    x = jax.random.normal(key, (2, 64, cfg.d_model), jnp.float32)
+    if skew:        # every token's top-2 lands on held experts 1 and 2
+        u = jax.random.normal(jax.random.fold_in(key, 1), (cfg.d_model,))
+        x = x + 3.0 * u
+        p["router"] = p["router"].at[:, 1].set(u).at[:, 2].set(0.9 * u)
+    pairs = x.shape[0] * x.shape[1] * cfg.top_k
+    tiles = 4 * moe_lib._GMM_ROWS
+    rows = moe_lib.buffer_rows(cfg, pairs)
+    assert rows == (pairs // 2 + tiles, pairs + tiles)
+    assert [moe_lib.pair_rows(cfg, r) for r in rows] == [pairs // 2, pairs]
+    _, _, counts, _ = moe_lib._route(p, cfg, x.reshape(-1, cfg.d_model))
+    assert (int(counts[:4].sum()) > pairs // 2) == skew
+    assert (int(jnp.sum(moe_lib._spans(counts[:4]))) > rows[0]) == skew
+    w = jnp.cos(jnp.arange(x.size, dtype=jnp.float32)).reshape(x.shape)
+
+    def loss(p, x):
+        out, aux = moe_lib.moe_apply(p, cfg, x)
+        return jnp.sum(out * w) + aux
+
+    got = jax.value_and_grad(loss, argnums=(0, 1))(p, x)
+    monkeypatch.setattr(moe_lib, "buffer_rows", lambda cfg, n: rows[-1:])
+    want = jax.value_and_grad(loss, argnums=(0, 1))(p, x)
+    for g, v in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(v), rtol=1e-6,
+                                   atol=1e-6)
+
+
+@pytest.mark.parametrize("draw", [1, 2, 3])
+def test_moe_grouped_work_does_not_follow_the_routing(draw, key, monkeypatch):
+    """On a chip holding 4 of 16 experts, each router sends its own number
+    of pairs to the held experts; the grouped matmuls run over every row of
+    the capped buffer all the same, forward and in the backward's
+    recomputation, in groups of whole row tiles, at least one each, so
+    the layer does the same work for each."""
+    from repro.models import moe as moe_lib
+    from repro.models.layers import Builder
+    cfg = configs.get_smoke("qwen3-moe-30b-a3b").with_(
+        n_experts=16, expert_padding=0, experts_held=4)
+    p = moe_lib.moe_init(Builder("init", jax.random.fold_in(key, draw),
+                                 jnp.float32), cfg)
+    x = jax.random.normal(key, (2, 64, cfg.d_model), jnp.float32)
+    pairs = x.shape[0] * x.shape[1] * cfg.top_k
+    cap, _ = moe_lib.buffer_rows(cfg, pairs)
+    _, _, counts, _ = moe_lib._route(p, cfg, x.reshape(-1, cfg.d_model))
+    held = int(counts[:4].sum())
+    assert 0 < held < cap
+    seen = []
+    real = moe_lib.grouped_matmul
+
+    def recorded(lhs, rhs, sizes):
+        jax.debug.callback(
+            lambda n, m=lhs.shape[0]: seen.append((tuple(n.tolist()), m)),
+            sizes)
+        return real(lhs, rhs, sizes)
+    monkeypatch.setattr(moe_lib, "grouped_matmul", recorded)
+    jax.grad(lambda p: jnp.sum(moe_lib.moe_apply(p, cfg, x)[0]))(p)
+    jax.effects_barrier()
+    assert len(seen) == 6          # three products, forward and recomputed
+    assert len(set(seen)) == 1
+    (sizes, m), = set(seen)
+    assert m == sum(sizes) == cap
+    tile = moe_lib._GMM_ROWS
+    assert all(n >= tile and n % tile == 0 for n in sizes)

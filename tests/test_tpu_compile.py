@@ -14,6 +14,7 @@ this file.
 """
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -76,13 +77,16 @@ def _fused_q8_args(L, m, n):
 # one-pass schedule), rows cut to four row tiles: Qwen2.5-3B's gate/up
 # (11008), down (2048) and k/v (256) stacks, Mistral-7B's gate/up (14336)
 # and down (4096).
+# Qwen3-30B-A3B's expert gate/up stacks (768 wide, four-dimensional
+# leaves merged into rows).
 CELLS = [(2, 128, 11008), (1, 256, 2048), (2, 128, 14336), (1, 128, 4096),
-         (2, 2048, 256)]
+         (2, 2048, 256), (2, 128, 768)]
 
 
 @pytest.mark.parametrize("shape", [ATTN, MLP] + CELLS,
                          ids=["attn", "mlp", "qwen-gate_up", "qwen-down",
-                              "mistral-gate_up", "mistral-down", "qwen-kv"])
+                              "mistral-gate_up", "mistral-down", "qwen-kv",
+                              "qwen3moe-gate_up"])
 def test_fused_f32_compiles(one_chip, shape):
     fn = functools.partial(kg.gwt_adam_tile_fused, level=LEVEL, gamma=1.01,
                            use_limiter=True, weight_decay=False)
@@ -129,3 +133,23 @@ def test_dwt_inv_compiles(one_chip):
     bands = [((m, n >> LEVEL), F32)] + [((m, n >> k), F32)
                                        for k in range(LEVEL, 0, -1)]
     _compile(lambda a, *d: kf.haar_dwt_inv(a, d), one_chip, *bands)
+
+
+@pytest.mark.parametrize("k,n", [(2048, 768), (768, 2048)],
+                         ids=["gate_up", "down"])
+def test_grouped_matmul_compiles(one_chip, monkeypatch, k, n):
+    """The expert layer's grouped matmuls (Megablox ``gmm``, and ``tgmm``
+    in the backward) at Qwen3-30B-A3B's widths: 32 held experts, rows cut
+    to 4096 of the cell's 65,536 (the grid's extent is read at run time)."""
+    from repro.models import moe
+    monkeypatch.setenv("REPRO_KERNEL_IMPL", "pallas")
+
+    def fwd_bwd(x, w, sizes):
+        y, vjp = jax.vjp(lambda x, w: moe.grouped_matmul(x, w, sizes), x, w)
+        return y, vjp(y)
+
+    hlo = _compile(fwd_bwd, one_chip, ((4096, k), BF16), ((32, k, n), BF16),
+                   ((33,), jnp.int32))
+    calls = re.findall(r'op_name="[^"]*jit\((t?gmm)\)[^"]*/pallas_call"',
+                       hlo)
+    assert sorted(calls) == ["gmm", "gmm", "tgmm"]
