@@ -394,9 +394,9 @@ def _fused_write_live_bytes():
               gamma=1.01, use_limiter=True, level=level)
 
     fused = jax.jit(
-        lambda g, p, st, pn: gops.fused_write_update(
-            g, p, st, jnp.int32(2), pn, impl="jnp", **kw),
-        donate_argnums=(1, 2, 3)).lower(g, p, st, pn).compile()
+        lambda gs, ps, st, pn: gops.fused_write_update(
+            gs, ps, st, jnp.int32(2), pn, impl="jnp", **kw),
+        donate_argnums=(1, 2, 3)).lower(list(g), list(p), st, pn).compile()
 
     stage_a = jax.jit(
         lambda g, st: gops.fused_update(g, st, jnp.int32(2), level=level,

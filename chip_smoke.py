@@ -10,14 +10,15 @@ One chip:
     the compressed DP wire's DWT, ``impl="pallas"`` against the jnp oracle
     at llama-1b bucket widths; and the fused kernel fed bf16 gradients
     (its one-pass schedule) bitwise against the same kernel run through
-    the f32 schedule's three-pass shuffles, at the benchmark cells'
-    bucket widths (``python chip_smoke.py --parity`` runs this phase
-    alone);
+    the f32 schedule's three-pass shuffles, and one launch per leaf (the
+    optimizer's path) bitwise against one launch over the stacked bucket,
+    at the benchmark cells' bucket widths (``python chip_smoke.py
+    --parity`` runs these two checks alone);
 (c) llama-1b GWT level-2 training through ``repro.launch.train.main``:
     f32 moments, then the int8 state codec.  Before each run the train
-    step is compiled once more on its own to check that every GWT bucket
+    step is compiled once more on its own to check that every GWT leaf
     runs the Pallas kernel (``tpu_custom_call`` in the HLO, one per
-    bucket) and to print the step's ``memory_analysis``;
+    leaf) and to print the step's ``memory_analysis``;
 (d) the last line: ``{"ok": true, "device": {...}}``.
 
 Four chips: llama-350m trained on a four-chip data-parallel mesh with the
@@ -189,11 +190,12 @@ def kernel_parity():
         tag = f"f32 {shape} limiter={use_limiter}"
         g, p, st, pn = _bucket_inputs(*shape, seed=6)
         kw = _write_kw(use_limiter)
-        out_k = gops.fused_write_update(g, p, st, jnp.int32(2), pn,
-                                        impl="pallas", **kw)
-        out_j = gops.fused_write_update(g, p, st, jnp.int32(2), pn,
-                                        impl="jnp", **kw)
+        out_k = gops.fused_write_update(list(g), list(p), st, jnp.int32(2),
+                                        pn, impl="pallas", **kw)
+        out_j = gops.fused_write_update(list(g), list(p), st, jnp.int32(2),
+                                        pn, impl="jnp", **kw)
         (pk, nk, sk), (pj, nj, sj) = jax.device_get((out_k, out_j))
+        pk, pj = np.stack(pk), np.stack(pj)
         _allclose(nk, nj, tag + " norm", NORM_TOL)
         _allclose(sk["m"], sj["m"], tag + " m", M_TOL)
         _allclose(sk["v"], sj["v"], tag + " v", V_TOL)
@@ -215,11 +217,13 @@ def kernel_parity():
         enc[name] = {"q": jnp.stack([q for q, _ in qs]),
                      "scale": jnp.stack([s for _, s in qs])}
     kw = _write_kw(True)
-    out_k = gops.fused_write_update_q8(g, p, enc, jnp.int32(1), key,
-                                       leaf_ids, pn, impl="pallas", **kw)
-    out_j = gops.fused_write_update_q8(g, p, enc, jnp.int32(1), key,
-                                       leaf_ids, pn, impl="jnp", **kw)
+    out_k = gops.fused_write_update_q8(list(g), list(p), enc, jnp.int32(1),
+                                       key, leaf_ids, pn, impl="pallas",
+                                       **kw)
+    out_j = gops.fused_write_update_q8(list(g), list(p), enc, jnp.int32(1),
+                                       key, leaf_ids, pn, impl="jnp", **kw)
     (pk, nk, sk), (pj, nj, sj) = jax.device_get((out_k, out_j))
+    pk, pj = np.stack(pk), np.stack(pj)
     _allclose(nk, nj, tag + " norm", NORM_TOL)
     for name in ("m", "v"):
         # an ulp of drift before the quantizer can flip one stochastic
@@ -267,7 +271,7 @@ def three_pass_schedule():
 
     def shuffled(x, m_st, v_st, level, b1, b2, eps, xla=False):
         out, m, v = kg._core_shuffled(x.astype(jnp.float32), m_st, v_st,
-                                      level, b1, b2, eps)
+                                      level, b1, b2, eps, xla)
         return out.astype(x.dtype), m, v
 
     kg._dht_adam_core = shuffled
@@ -317,6 +321,57 @@ def schedule_parity():
         del g, p, ms, vs, new, old
 
 
+def leaf_parity():
+    """The fused f32-moment update as the optimizer runs it, one launch
+    per leaf on the leaves' own buffers over the bucket's stacked
+    moments (``ops._leaf_by_leaf``), returns ``new_p, m, v, new_norm``
+    bitwise equal to one launch over the stacked bucket, at every
+    multi-leaf cell bucket width, fed bf16 ``g``/``p`` with the limiter
+    on.  Both take the same step size: one computed eagerly and one
+    inside a jit may differ in an ulp, which moves ``new_p``."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from repro.kernels.gwt_adam import kernel as kg
+    from repro.kernels.gwt_adam import ops as gops
+
+    kw = dict(level=LEVEL, gamma=1.01, use_limiter=True,
+              weight_decay=False)
+    scalars = (jnp.float32(0.0025), jnp.float32(0.0))
+    once = jax.jit(functools.partial(kg.gwt_adam_tile_fused, **kw))
+    by_leaf = jax.jit(lambda g, p, ms, vs, pn, *sc: gops._leaf_by_leaf(
+        functools.partial(kg.gwt_adam_tile_fused, **kw),
+        [g[j:j + 1] for j in range(g.shape[0])],
+        [p[j:j + 1] for j in range(p.shape[0])], (ms, vs), (pn,), sc))
+    for L, rows, n in CELL_BUCKETS:
+        if L < 2:
+            continue
+        m = 4 * kg.fused_row_block(rows, n, LEVEL)
+        k = jax.random.key(n + m + 1)
+        g = (jax.random.normal(k, (L, m, n)) * 1e-3).astype(jnp.bfloat16)
+        p = (jax.random.normal(jax.random.fold_in(k, 1), (L, m, n))
+             * 0.02).astype(jnp.bfloat16)
+        na = n >> LEVEL
+        ms = jax.random.normal(jax.random.fold_in(k, 2), (L, m, na)) * 1e-4
+        vs = jnp.abs(jax.random.normal(jax.random.fold_in(k, 3),
+                                       (L, m, na))) * 1e-8
+        pn = jnp.arange(L, dtype=jnp.float32) * 0.3
+        want = jax.device_get(once(g, p, ms, vs, pn, *scalars))
+        new_ps, (m2, v2), norm = jax.device_get(
+            by_leaf(g, p, ms, vs, pn, *scalars))
+        tag = f"per-leaf launches bf16 {(L, m, n)}"
+        bad = {}
+        for name, a, b in zip(("new_p", "m", "v", "new_norm"),
+                              (np.concatenate(new_ps), m2, v2, norm),
+                              want):
+            a, b = np.asarray(a), np.asarray(b)
+            bad[name] = int((a.view(f"u{a.itemsize}")
+                             != b.view(f"u{b.itemsize}")).sum())
+        say(f"parity {tag}: elements whose bits differ {bad}")
+        check(not any(bad.values()), f"parity {tag}: {bad}")
+        del g, p, ms, vs, want, new_ps, m2, v2
+
+
 # ---------------------------------------------------------------------------
 # (c) training through the launcher
 # ---------------------------------------------------------------------------
@@ -352,8 +407,10 @@ def compiled_step_check(codec: str, batch: int):
         params, ost, {"tokens": toks, "labels": toks}).compile()
     hlo = compiled.as_text()
     n_kernels = hlo.count('custom_call_target="tpu_custom_call"')
-    gwt_buckets = [b.name for b in opt.engine.plan(params).buckets
-                   if b.rule.kind.startswith("gwt")]
+    gwt = [b for b in opt.engine.plan(params).buckets
+           if b.rule.kind.startswith("gwt")]
+    gwt_buckets = [b.name for b in gwt]
+    gwt_leaves = sum(len(b.indices) for b in gwt)
     ma = compiled.memory_analysis()
     say(f"{codec}: step compiled in {time.time() - t0:.1f}s; "
         f"{len(gwt_buckets)} GWT buckets {gwt_buckets}; "
@@ -363,8 +420,8 @@ def compiled_step_check(codec: str, batch: int):
         f"{ma.output_size_in_bytes} B, alias {ma.alias_size_in_bytes} B, "
         f"temp {ma.temp_size_in_bytes} B")
     check(n_kernels >= 1, "no tpu_custom_call in the train step's HLO")
-    check(n_kernels == len(gwt_buckets),
-          f"{n_kernels} Pallas kernels for {len(gwt_buckets)} GWT buckets")
+    check(n_kernels == gwt_leaves,
+          f"{n_kernels} Pallas kernels for {gwt_leaves} GWT leaves")
 
 
 def check_losses(losses, tag):
@@ -393,6 +450,7 @@ def one_chip():
     t0 = time.time()
     kernel_parity()
     schedule_parity()
+    leaf_parity()
     say(f"kernel parity passed in {time.time() - t0:.1f}s")
     for codec, steps in (("f32", 20), ("int8", 10)):
         compiled_step_check(codec, BATCH)
@@ -456,7 +514,8 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--chips", type=int, default=1, choices=[1, 4])
     ap.add_argument("--parity", action="store_true",
-                    help="one chip: run the bf16 schedule parity alone")
+                    help="one chip: run the bf16 schedule and per-leaf "
+                    "launch parity alone")
     args = ap.parse_args(argv)
     if not (SRC / "repro").is_dir():
         print(f"chip_smoke: the repro package is not at {SRC}; run this "
@@ -472,6 +531,7 @@ def main(argv=None) -> int:
             four_chips()
         elif args.parity:
             schedule_parity()
+            leaf_parity()
         else:
             one_chip()
         say(f"all phases passed in {time.time() - t0:.1f}s")

@@ -16,9 +16,10 @@ base lr — the paper's module-wise strategy.  ``level=0`` reduces exactly to
 the host optimizer (tested).
 
 The per-leaf routing is declared as rules over the shared bucketed engine
-(``repro.optim.engine``): same-shaped eligible leaves are stacked into one
-``(L, m, n)`` bucket and — on the fused path — go through
-``kernels/gwt_adam/ops.fused_update`` in a **single** call per bucket.
+(``repro.optim.engine``): same-shaped eligible leaves share one bucket,
+whose state is stacked ``(L, …)``, and — on the fused path — go through
+``kernels/gwt_adam/ops.fused_write_update`` in one call per bucket, one
+kernel launch per leaf on the leaf's own buffers.
 
 ``impl`` selects the kernel backend: ``'pallas'`` routes eligible-leaf
 updates through the fused TPU kernel (`repro.kernels.gwt_adam`),
@@ -171,47 +172,36 @@ def gwt(lr: Schedule | float,
                     g_tilde, state["prev_norm"], gamma)
             return _apply(p, g_tilde, lr(step), lr_mult, alpha), out
 
-        def vector_update(g_stk, p_stk, state, step, leaf_ids):
-            # Fused-write megakernel: ONE launch for the whole (L, m, n)
-            # bucket performs DWT→Adam→inverse→limit→param-write — the
-            # limiter, bias-corrected step, and weight decay all run in
-            # the kernel epilogue, so g̃ never round-trips HBM.
+        def vector_update(gs, ps, state, step, leaf_ids):
+            # Fused-write megakernel: one launch per leaf of the bucket
+            # performs DWT→Adam→inverse→limit→param-write on the leaf's
+            # own g and p buffers, its slab of the stacked moments
+            # updated in place — the limiter, bias-corrected step, and
+            # weight decay all run in the kernel epilogue, so g̃ never
+            # round-trips HBM.
             from repro.kernels.gwt_adam import ops as gwt_ops  # lazy
-            with jax.named_scope("optim.pack"):
-                gt = jnp.swapaxes(g_stk, -1, -2) if swap else g_stk
-                pt = jnp.swapaxes(p_stk, -1, -2) if swap else p_stk
-            new_p, new_norm, hstate = gwt_ops.fused_write_update(
-                gt, pt, state["host"], step, state["prev_norm"],
+            new_ps, new_norm, hstate = gwt_ops.fused_write_update(
+                gs, ps, state["host"], step, state["prev_norm"],
                 lr_t=lr(step), alpha=alpha, weight_decay=weight_decay,
                 gamma=gamma, use_limiter=use_limiter, level=level,
-                impl=impl, **adam_kw)
-            if swap:
-                with jax.named_scope("optim.pack"):
-                    new_p = jnp.swapaxes(new_p, -1, -2)
-            return new_p, {"host": hstate, "prev_norm": new_norm}
+                swap=swap, impl=impl, **adam_kw)
+            return new_ps, {"host": hstate, "prev_norm": new_norm}
 
-        def vector_update_q8(g_stk, p_stk, state, step, leaf_ids,
-                             codec_key):
-            # codec-native fused-write path: the kernel dequantizes the
-            # blocked moments, updates, requantizes, AND applies
-            # limit+step+write in one launch — decoded f32 moments and g̃
+        def vector_update_q8(gs, ps, state, step, leaf_ids, codec_key):
+            # codec-native fused-write path: each leaf's launch
+            # dequantizes its blocked moments, updates, requantizes, AND
+            # applies limit+step+write — decoded f32 moments and g̃
             # never round-trip HBM.  Slot salts (m=0, v=1) match
             # codec.map_slots' sorted-key order, so this path and the
             # generic scan wrap produce the same rounding bits.
             from repro.kernels.gwt_adam import ops as gwt_ops  # lazy
-            with jax.named_scope("optim.pack"):
-                gt = jnp.swapaxes(g_stk, -1, -2) if swap else g_stk
-                pt = jnp.swapaxes(p_stk, -1, -2) if swap else p_stk
-            new_p, new_norm, hstate = gwt_ops.fused_write_update_q8(
-                gt, pt, state["host"], step, codec_key, leaf_ids,
+            new_ps, new_norm, hstate = gwt_ops.fused_write_update_q8(
+                gs, ps, state["host"], step, codec_key, leaf_ids,
                 state["prev_norm"], lr_t=lr(step), alpha=alpha,
                 weight_decay=weight_decay, gamma=gamma,
                 use_limiter=use_limiter, level=level, block=cdc.block,
-                impl=impl, **adam_kw)
-            if swap:
-                with jax.named_scope("optim.pack"):
-                    new_p = jnp.swapaxes(new_p, -1, -2)
-            return new_p, {"host": hstate, "prev_norm": new_norm}
+                swap=swap, impl=impl, **adam_kw)
+            return new_ps, {"host": hstate, "prev_norm": new_norm}
 
         def taps(g_stk, p_stk, new_p_stk, old_st, new_st, step):
             # Observability taps (DESIGN.md §12), traced only inside

@@ -40,13 +40,18 @@ by the caller (ops.py) on the staged path — the limiter needs the global
 norm, which is reduced from the per-tile partials this kernel emits.
 
 **Fused-write megakernel** (``gwt_adam_tile_fused{,_q8}``): the full
-DWT→Adam→inverse→limit→param-write chain in ONE launch per ``(L, m, n)``
-bucket.  The leaf axis is folded into the grid (no vmap); the per-leaf
+DWT→Adam→inverse→limit→param-write chain for ``(Lg, m, n)`` leaves in ONE
+launch.  The leaf axis is folded into the grid (no vmap); the per-leaf
 ``‖G̃‖`` reduction runs as a two-phase pass over the row tiles with the
 SMEM ``new_norm`` output as the accumulator, and the epilogue applies the
 norm-growth limiter, the bias-corrected step size, and weight decay before
 writing the parameter tile.  ``G̃`` never round-trips HBM and the gradient
-never lives alongside its transform.
+never lives alongside its transform.  The moments are a bucket's whole
+``(L, m, n >> level)`` stack, updated in place: the launch's leaf ``l``
+owns slab ``leaf0 + l`` (a scalar-prefetched offset that only the
+moments' index maps read), so ``ops.py`` runs one launch per leaf on the
+leaf's own gradient and parameter buffers, and all leaves of a shape
+share one compiled kernel.
 
 The q8 variant keeps the moments in the int8 codec's layout
 (``repro.optim.codec``): per-row blocks of ``block`` A-band elements, one
@@ -71,10 +76,17 @@ _SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
 _TEMPS = 16  # f32 copies of a stripe the butterfly keeps live (VMEM estimate)
 
 
-def _adam(a, m_st, v_st, b1, b2, eps):
-    """Adam's moments on the approximation band, and ``1/(√V+ε)``."""
-    m = b1 * m_st + (1.0 - b1) * a
-    v = b2 * v_st + (1.0 - b2) * a * a
+def _adam(a, m_st, v_st, b1, b2, eps, xla):
+    """Adam's moments on the approximation band, and ``1/(√V+ε)``.
+
+    Under XLA each product is fenced (:func:`lanes.fence`): XLA:CPU
+    otherwise contracts one of the two products of a moment's sum into
+    its add (an FMA), and which one depends on how the surrounding
+    program fused, so the same tile could round an ulp apart in two
+    programs (one launch per leaf or per bucket, kernel or oracle)."""
+    fence = lanes.fence if xla else (lambda t: t)
+    m = fence(b1 * m_st) + fence((1.0 - b1) * a)
+    v = fence(b2 * v_st) + fence((1.0 - b2) * a * a)
     return m, v, 1.0 / (jnp.sqrt(v) + eps)
 
 
@@ -89,10 +101,10 @@ def _dht_adam_core(x, m_st, v_st, level, b1, b2, eps, xla=False):
     if x.dtype == jnp.bfloat16:
         return _core_phases(x, m_st, v_st, level, b1, b2, eps, xla)
     return _core_shuffled(x.astype(jnp.float32), m_st, v_st, level, b1, b2,
-                          eps)
+                          eps, xla)
 
 
-def _core_shuffled(x, m_st, v_st, level, b1, b2, eps):
+def _core_shuffled(x, m_st, v_st, level, b1, b2, eps, xla=False):
     """The f32 schedule: each level's even/odd split and merge is a lane
     shuffle of an f32 band (three MXU passes each, 5,376 MXU FLOPs per
     element at level 2, the level-1 details' ``repeat_lanes`` included)."""
@@ -103,7 +115,7 @@ def _core_shuffled(x, m_st, v_st, level, b1, b2, eps):
         a = (even + odd) * INV_SQRT2
         details.append((even - odd) * INV_SQRT2)
 
-    m, v, inv_denom = _adam(a, m_st, v_st, b1, b2, eps)
+    m, v, inv_denom = _adam(a, m_st, v_st, b1, b2, eps, xla)
     x = m * inv_denom
     for k in range(level, 0, -1):
         d_t = details[k - 1] * lanes.repeat_lanes(inv_denom, 1 << (level - k))
@@ -150,7 +162,7 @@ def _core_phases(x, m_st, v_st, level, b1, b2, eps, xla):
         band = [(e + o) * INV_SQRT2 for e, o in zip(even, odd)]
         details.append([(e - o) * INV_SQRT2 for e, o in zip(even, odd)])
 
-    m, v, inv_denom = _adam(band[0], m_st, v_st, b1, b2, eps)
+    m, v, inv_denom = _adam(band[0], m_st, v_st, b1, b2, eps, xla)
     y = [m * inv_denom]
     for k in range(level, 0, -1):
         scale = inv_denom if k == level else fence(inv_denom)
@@ -228,8 +240,8 @@ def gwt_adam_tile(g: jax.Array, m_st: jax.Array, v_st: jax.Array, *,
 
 
 # ---------------------------------------------------------------------------
-# Fused-write megakernel: one launch per (L, m, n) bucket does
-# DWT -> Adam -> inverse -> norm-growth limiter -> parameter write.
+# Fused-write megakernel: one launch per leaf (or per stack of leaves)
+# does DWT -> Adam -> inverse -> norm-growth limiter -> parameter write.
 # ---------------------------------------------------------------------------
 
 def fused_row_block(m: int, n: int, level: int) -> int:
@@ -263,15 +275,16 @@ def _limiter_scale(norm, prev, gamma: float):
 
 def _body_fused(level: int, b1: float, b2: float, eps: float, xla: bool,
                 gamma: float, use_limiter: bool, wd: bool, rows: int,
-                pn_ref, sc_ref, g_ref, p_ref, m_ref, v_ref,
+                leaf0_ref, pn_ref, sc_ref, g_ref, p_ref, m_ref, v_ref,
                 p_out_ref, m_out_ref, v_out_ref, norm_ref):
-    """Grid ``(L, phases, gm)`` — leaf outermost, row tiles innermost; the
+    """Grid ``(Lg, phases, gm)`` — leaf outermost, row tiles innermost; the
     SMEM ``norm_ref[l]`` doubles as the cross-tile ssq accumulator.  Phase
     0 accumulates ``‖G̃_l‖²``; phase 1 recomputes the tile (the op is
     bandwidth-bound — recompute is cheaper than an HBM round trip of G̃)
     and applies limiter + step + weight decay + write.
     ``use_limiter=False`` runs the single write phase only.  ``sc_ref``
-    holds ``(step_size, wd_coef)``."""
+    holds ``(step_size, wd_coef)``; ``leaf0_ref`` is read by the moments'
+    index maps alone."""
     leaf = pl.program_id(0)
     phase = pl.program_id(1)
     i = pl.program_id(2)
@@ -326,25 +339,39 @@ def _body_fused(level: int, b1: float, b2: float, eps: float, xla: bool,
             norm_ref[leaf] = jnp.where(norm > 0, norm * scale, prev)
 
 
-def _fused_specs(bm: int, widths):
-    """Per-leaf row-stripe BlockSpecs over the ``(L, phases, gm)`` grid."""
-    return [pl.BlockSpec((1, bm, w), lambda l, ph, i: (l, i, 0))
-            for w in widths]
+def _stripe(bm: int, w: int, slab: bool = False) -> pl.BlockSpec:
+    """Row stripe ``(1, bm, w)`` over the ``(Lg, phases, gm)`` grid: of
+    the launch's leaf ``l``, or (``slab``) of a bucket's stacked moments
+    at the leaf's slab ``leaf0 + l``."""
+    if slab:
+        return pl.BlockSpec((1, bm, w),
+                            lambda l, ph, i, leaf0: (l + leaf0[0], i, 0))
+    return pl.BlockSpec((1, bm, w), lambda l, ph, i, leaf0: (l, i, 0))
+
+
+def _leaf0(leaf0) -> jax.Array:
+    return jnp.asarray(leaf0, jnp.int32).reshape(1)
 
 
 def gwt_adam_tile_fused(g: jax.Array, p: jax.Array, m_st: jax.Array,
                         v_st: jax.Array, prev_norm: jax.Array,
-                        step_size: jax.Array, wd_coef: jax.Array, *,
-                        level: int, gamma: float, use_limiter: bool,
-                        weight_decay: bool, b1: float = 0.9,
-                        b2: float = 0.999, eps: float = 1e-6,
-                        interpret: bool = False):
-    """Fused-write update for a whole ``(L, m, n)`` bucket in ONE launch.
+                        step_size: jax.Array, wd_coef: jax.Array,
+                        leaf0=0, *, level: int, gamma: float,
+                        use_limiter: bool, weight_decay: bool,
+                        b1: float = 0.9, b2: float = 0.999,
+                        eps: float = 1e-6, interpret: bool = False):
+    """Fused-write update of the ``(Lg, m, n)`` leaves ``g``/``p`` in ONE
+    launch.
 
-    ``prev_norm``: f32 ``(L,)`` per-leaf limiter state; ``step_size`` /
+    ``m_st``/``v_st``: a bucket's stacked moments ``(L, m, n >> level)``;
+    leaf ``l`` of the launch updates slab ``leaf0 + l`` (an i32 scalar)
+    in place, and the other slabs pass through untouched.  With
+    ``Lg == L`` and ``leaf0 = 0`` one launch updates the whole bucket.
+    ``prev_norm``: f32 ``(Lg,)`` per-leaf limiter state; ``step_size`` /
     ``wd_coef``: f32 scalars (bias-corrected lr·α and lr·weight_decay,
-    computed by ops.py).  Returns ``(new_p, new_m, new_v, new_norm)`` with
-    ``new_norm`` f32 ``(L,)``.
+    computed by ops.py).  Returns ``(new_p, new_m, new_v, new_norm)``
+    with ``new_m``/``new_v`` the whole stack and ``new_norm`` f32
+    ``(Lg,)``.
     """
     L, mm, nn = g.shape
     _check_width(nn, level)
@@ -353,26 +380,31 @@ def gwt_adam_tile_fused(g: jax.Array, p: jax.Array, m_st: jax.Array,
     phases = 2 if use_limiter else 1
     scalars = jnp.stack([jnp.asarray(step_size, jnp.float32),
                          jnp.asarray(wd_coef, jnp.float32)])
+    tiles = [_stripe(bm, nn), _stripe(bm, nn), _stripe(bm, na, slab=True),
+             _stripe(bm, na, slab=True)]
     return pl.pallas_call(
         functools.partial(_body_fused, level, b1, b2, eps, interpret, gamma,
                           use_limiter, weight_decay, mm),
-        grid=(L, phases, pl.cdiv(mm, bm)),
-        in_specs=[_SMEM, _SMEM] + _fused_specs(bm, [nn, nn, na, na]),
-        out_specs=_fused_specs(bm, [nn, na, na]) + [_SMEM],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(L, phases, pl.cdiv(mm, bm)),
+            in_specs=[_SMEM, _SMEM] + tiles,
+            out_specs=tiles[1:] + [_SMEM]),
         out_shape=[
             jax.ShapeDtypeStruct((L, mm, nn), p.dtype),
-            jax.ShapeDtypeStruct((L, mm, na), m_st.dtype),
-            jax.ShapeDtypeStruct((L, mm, na), v_st.dtype),
+            jax.ShapeDtypeStruct(m_st.shape, m_st.dtype),
+            jax.ShapeDtypeStruct(v_st.shape, v_st.dtype),
             jax.ShapeDtypeStruct((L,), jnp.float32),
         ],
         # in-place write semantics: p/m/v are updated in their own
         # buffers (each tile reads its block before writing it; phase 0
-        # writes the inputs through unchanged).  NOT prev_norm→new_norm:
+        # writes the inputs through unchanged), and the moment slabs of
+        # other leaves keep their values.  NOT prev_norm→new_norm:
         # phase 0 accumulates ssq into the norm output while phase 1 still
         # reads the history from pn_ref — aliasing them would clobber it.
-        input_output_aliases={3: 0, 4: 1, 5: 2},
+        input_output_aliases={4: 0, 5: 1, 6: 2},
         interpret=interpret,
-    )(prev_norm.astype(jnp.float32), scalars, g, p, m_st, v_st)
+    )(_leaf0(leaf0), prev_norm.astype(jnp.float32), scalars, g, p, m_st,
+      v_st)
 
 
 def _expand_scales(s: jax.Array, width: int, block: int) -> jax.Array:
@@ -414,7 +446,7 @@ def _requant(arr, salt, row0, block: int):
 
 def _body_fused_q8(level: int, b1: float, b2: float, eps: float, xla: bool,
                    gamma: float, use_limiter: bool, wd: bool, block: int,
-                   rows: int, pn_ref, sc_ref, salt_ref,
+                   rows: int, leaf0_ref, pn_ref, sc_ref, salt_ref,
                    g_ref, p_ref, qm_ref, sm_ref, qv_ref, sv_ref,
                    p_out_ref, qm_out_ref, sm_out_ref, qv_out_ref,
                    sv_out_ref, norm_ref):
@@ -491,17 +523,19 @@ def gwt_adam_tile_fused_q8(g: jax.Array, p: jax.Array, qm: jax.Array,
                            sm: jax.Array, qv: jax.Array, sv: jax.Array,
                            salt_m: jax.Array, salt_v: jax.Array,
                            prev_norm: jax.Array, step_size: jax.Array,
-                           wd_coef: jax.Array, *, level: int, block: int,
-                           gamma: float, use_limiter: bool,
+                           wd_coef: jax.Array, leaf0=0, *, level: int,
+                           block: int, gamma: float, use_limiter: bool,
                            weight_decay: bool, b1: float = 0.9,
                            b2: float = 0.999, eps: float = 1e-6,
                            interpret: bool = False):
-    """Fused-write q8 update for a whole ``(L, m, n)`` bucket in one launch.
+    """Fused-write q8 update of the ``(Lg, m, n)`` leaves in one launch.
 
-    ``qm/qv``: int8 ``(L, m, n>>level)``; ``sm/sv``: f32 ``(L, nbr, m)``
-    per-row-block scales (``codec.blocked_quant`` layout); ``salt_m/
-    salt_v``: uint32 ``(L,)`` per-leaf slot salts.  Returns ``(new_p, qm',
-    sm', qv', sv', new_norm)``.
+    ``qm/qv``: a bucket's int8 ``(L, m, n>>level)``; ``sm/sv``: f32
+    ``(L, nbr, m)`` per-row-block scales (``codec.blocked_quant`` layout),
+    each updated in place at slab ``leaf0 + l`` as in
+    :func:`gwt_adam_tile_fused`; ``salt_m/salt_v``: uint32 ``(Lg,)``
+    per-leaf slot salts.  Returns ``(new_p, qm', sm', qv', sv',
+    new_norm)``.
     """
     L, mm, nn = g.shape
     _check_width(nn, level)
@@ -515,27 +549,30 @@ def gwt_adam_tile_fused_q8(g: jax.Array, p: jax.Array, qm: jax.Array,
         jnp.concatenate([jnp.asarray(salt_m, jnp.uint32).reshape(L),
                          jnp.asarray(salt_v, jnp.uint32).reshape(L)]),
         jnp.int32)
-    tiles = _fused_specs(bm, [nn, nn, na])
-    stile = pl.BlockSpec((1, nbr, bm), lambda l, ph, i: (l, 0, i))
-    tiles = tiles + [stile, tiles[2], stile]
+    qtile = _stripe(bm, na, slab=True)
+    stile = pl.BlockSpec((1, nbr, bm),
+                         lambda l, ph, i, leaf0: (l + leaf0[0], 0, i))
+    tiles = [_stripe(bm, nn), _stripe(bm, nn), qtile, stile, qtile, stile]
     return pl.pallas_call(
         functools.partial(_body_fused_q8, level, b1, b2, eps, interpret,
                           gamma, use_limiter, weight_decay, block, mm),
-        grid=(L, phases, pl.cdiv(mm, bm)),
-        in_specs=[_SMEM, _SMEM, _SMEM] + tiles,
-        out_specs=tiles[1:] + [_SMEM],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(L, phases, pl.cdiv(mm, bm)),
+            in_specs=[_SMEM, _SMEM, _SMEM] + tiles,
+            out_specs=tiles[1:] + [_SMEM]),
         out_shape=[
             jax.ShapeDtypeStruct((L, mm, nn), p.dtype),
-            jax.ShapeDtypeStruct((L, mm, na), jnp.int8),
-            jax.ShapeDtypeStruct((L, nbr, mm), jnp.float32),
-            jax.ShapeDtypeStruct((L, mm, na), jnp.int8),
-            jax.ShapeDtypeStruct((L, nbr, mm), jnp.float32),
+            jax.ShapeDtypeStruct(qm.shape, jnp.int8),
+            jax.ShapeDtypeStruct(sm.shape, jnp.float32),
+            jax.ShapeDtypeStruct(qv.shape, jnp.int8),
+            jax.ShapeDtypeStruct(sv.shape, jnp.float32),
             jax.ShapeDtypeStruct((L,), jnp.float32),
         ],
         # in-place p and int8 payload/scale updates (reads precede writes
         # within each tile; phase 0 writes the inputs through unchanged).
         # prev_norm is deliberately NOT aliased to new_norm — see
         # gwt_adam_tile_fused.
-        input_output_aliases={4: 0, 5: 1, 6: 2, 7: 3, 8: 4},
+        input_output_aliases={5: 0, 6: 1, 7: 2, 8: 3, 9: 4},
         interpret=interpret,
-    )(prev_norm.astype(jnp.float32), scalars, salts, g, p, qm, sm, qv, sv)
+    )(_leaf0(leaf0), prev_norm.astype(jnp.float32), scalars, salts, g, p,
+      qm, sm, qv, sv)

@@ -128,12 +128,14 @@ def _limit_write(gt, p, prev, step_size, wd_coef, *, gamma, use_limiter,
     "level", "gamma", "use_limiter", "weight_decay", "bm", "b1", "b2", "eps"))
 def gwt_adam_fused(g: jax.Array, p: jax.Array, m_st: jax.Array,
                    v_st: jax.Array, prev_norm: jax.Array,
-                   step_size: jax.Array, wd_coef: jax.Array, *,
+                   step_size: jax.Array, wd_coef: jax.Array, leaf0=0, *,
                    level: int, gamma: float, use_limiter: bool,
                    weight_decay: bool, bm: int, b1: float = 0.9,
                    b2: float = 0.999, eps: float = 1e-6):
-    """Fused-write oracle over a stacked ``(L, m, n)`` bucket.  Returns
-    ``(new_p, new_m, new_v, new_norm)`` with ``new_norm`` f32 ``(L,)``.
+    """Fused-write oracle over ``(Lg, m, n)`` leaves whose moments are
+    slabs ``leaf0 …`` of a bucket's stack (``kernel.gwt_adam_tile_fused``
+    semantics).  Returns ``(new_p, new_m, new_v, new_norm)`` with
+    ``new_norm`` f32 ``(Lg,)``.
 
     ``p``/``m``/``v`` ride the ``lax.scan`` carry and are updated leaf-by-
     leaf with in-place dynamic-update-slice, so one leaf's working set is
@@ -144,17 +146,18 @@ def gwt_adam_fused(g: jax.Array, p: jax.Array, m_st: jax.Array,
     def body(carry, xs):
         p_c, m_c, v_c = carry
         gl, pnl, l = xs
+        s = l + leaf0
         gt, m, v, _ = gwt_adam_tile(
-            gl, jax.lax.dynamic_index_in_dim(m_c, l, 0, keepdims=False),
-            jax.lax.dynamic_index_in_dim(v_c, l, 0, keepdims=False),
+            gl, jax.lax.dynamic_index_in_dim(m_c, s, 0, keepdims=False),
+            jax.lax.dynamic_index_in_dim(v_c, s, 0, keepdims=False),
             level=level, b1=b1, b2=b2, eps=eps)
         new_p, new_norm = _limit_write(
             gt, jax.lax.dynamic_index_in_dim(p_c, l, 0, keepdims=False),
             pnl, step_size, wd_coef, gamma=gamma, use_limiter=use_limiter,
             weight_decay=weight_decay, bm=bm)
         p_c = jax.lax.dynamic_update_index_in_dim(p_c, new_p, l, 0)
-        m_c = jax.lax.dynamic_update_index_in_dim(m_c, m, l, 0)
-        v_c = jax.lax.dynamic_update_index_in_dim(v_c, v, l, 0)
+        m_c = jax.lax.dynamic_update_index_in_dim(m_c, m, s, 0)
+        v_c = jax.lax.dynamic_update_index_in_dim(v_c, v, s, 0)
         return (p_c, m_c, v_c), new_norm
     idx = jnp.arange(g.shape[0], dtype=jnp.int32)
     (p, m_st, v_st), norms = jax.lax.scan(
@@ -169,30 +172,33 @@ def gwt_adam_fused_q8(g: jax.Array, p: jax.Array, qm: jax.Array,
                       sm: jax.Array, qv: jax.Array, sv: jax.Array,
                       salt_m: jax.Array, salt_v: jax.Array,
                       prev_norm: jax.Array, step_size: jax.Array,
-                      wd_coef: jax.Array, *, level: int, block: int,
-                      gamma: float, use_limiter: bool, weight_decay: bool,
-                      bm: int, b1: float = 0.9, b2: float = 0.999,
-                      eps: float = 1e-6):
+                      wd_coef: jax.Array, leaf0=0, *, level: int,
+                      block: int, gamma: float, use_limiter: bool,
+                      weight_decay: bool, bm: int, b1: float = 0.9,
+                      b2: float = 0.999, eps: float = 1e-6):
     """q8 fused-write oracle (blocked-int8 moments in/out).  Returns
     ``(new_p, qm', sm', qv', sv', new_norm)``.
 
-    Same ``lax.scan`` carry structure as :func:`gwt_adam_fused` —
-    ``p``/``qm``/``sm``/``qv``/``sv`` update in-place leaf-by-leaf so
-    donated inputs alias through and one leaf bounds the live temps."""
+    Same ``lax.scan`` carry structure and ``leaf0`` slabs as
+    :func:`gwt_adam_fused` — ``p``/``qm``/``sm``/``qv``/``sv`` update
+    in-place leaf-by-leaf so donated inputs alias through and one leaf
+    bounds the live temps."""
     def body(carry, xs):
         p_c, qm_c, sm_c, qv_c, sv_c = carry
         gl, saltml, saltvl, pnl, l = xs
-        at = lambda a: jax.lax.dynamic_index_in_dim(a, l, 0, keepdims=False)
+        s = l + leaf0
+        at = lambda a, i: jax.lax.dynamic_index_in_dim(a, i, 0,
+                                                       keepdims=False)
         gt, qm2, sm2, qv2, sv2, _ = gwt_adam_tile_q8(
-            gl, at(qm_c), at(sm_c), at(qv_c), at(sv_c), saltml, saltvl,
-            level=level, block=block, b1=b1, b2=b2, eps=eps)
+            gl, at(qm_c, s), at(sm_c, s), at(qv_c, s), at(sv_c, s), saltml,
+            saltvl, level=level, block=block, b1=b1, b2=b2, eps=eps)
         new_p, new_norm = _limit_write(
-            gt, at(p_c), pnl, step_size, wd_coef, gamma=gamma,
+            gt, at(p_c, l), pnl, step_size, wd_coef, gamma=gamma,
             use_limiter=use_limiter, weight_decay=weight_decay, bm=bm)
         upd = jax.lax.dynamic_update_index_in_dim
-        return ((upd(p_c, new_p, l, 0), upd(qm_c, qm2, l, 0),
-                 upd(sm_c, sm2, l, 0), upd(qv_c, qv2, l, 0),
-                 upd(sv_c, sv2, l, 0)), new_norm)
+        return ((upd(p_c, new_p, l, 0), upd(qm_c, qm2, s, 0),
+                 upd(sm_c, sm2, s, 0), upd(qv_c, qv2, s, 0),
+                 upd(sv_c, sv2, s, 0)), new_norm)
     idx = jnp.arange(g.shape[0], dtype=jnp.int32)
     (p, qm, sm, qv, sv), norms = jax.lax.scan(
         body, (p, qm, sm, qv, sv), (g, salt_m, salt_v, prev_norm, idx))

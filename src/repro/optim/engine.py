@@ -19,9 +19,11 @@ per leaf.  This engine replaces all three loops:
 
 3. **Execution** — one ``jax.lax.scan`` over the stacked leading axis per
    bucket (the scan body is traced *once* regardless of layer count), or a
-   single vectorized call when the rule provides ``vector_update`` (the
-   fused Pallas GWT-Adam kernel consumes the whole ``(L, m, n)`` stack in
-   one launch).
+   single call when the rule provides ``vector_update``: it takes the
+   bucket's gradient and parameter *leaves* with its stacked state and
+   returns the new leaves, so no stacked copy of them is built (the fused
+   Pallas GWT-Adam rule makes one launch per leaf on the leaf's own
+   buffers, updating its slab of the stacked moments in place).
 
 State layout::
 
@@ -72,10 +74,12 @@ class LeafRule(NamedTuple):
     * ``update(g, p, state, step, leaf_id) -> (new_p, new_state)`` — one
       leaf's update.  ``leaf_id`` is the i32 flatten-order index (used e.g.
       by APOLLO's per-leaf random projector).
-    * ``vector_update`` — optional ``(g_stk, p_stk, state_stk, step,
-      leaf_ids) -> (new_p_stk, new_state_stk)`` over the whole ``(L, ...)``
-      stack in one call; used instead of the scan when present (fused
-      kernels).
+    * ``vector_update`` — optional ``(gs, ps, state_stk, step, leaf_ids)
+      -> (new_ps, new_state_stk)`` over a whole bucket in one call: the
+      bucket's gradient and parameter leaves (lists, flatten order) and
+      its stacked ``(L, ...)`` state; used instead of the scan when
+      present (fused kernels, which then read and write each leaf in its
+      own buffer).
     * ``slots`` — bool pytree mirroring the per-leaf state structure:
       True marks a *moment slot* the state codec may re-encode (int8 etc.).
       ``None`` = no slots; the codec never touches this rule's state.
@@ -371,54 +375,60 @@ def build(assign: Callable[[str, Any], LeafRule],
                                                step, lid)
                 return new_p, ns
 
+            gs = [gleaves[i] for i in b.indices]
+            ps = [pleaves[i] for i in b.indices]
             if not bucketed:
-                outs = [leaf_update(gleaves[i], pleaves[i],
-                                    _slice_state(st, j), lids[j])
-                        for j, i in enumerate(b.indices)]
-                np_stk = jnp.stack([o[0] for o in outs])
+                outs = [leaf_update(g, p, _slice_state(st, j), lids[j])
+                        for j, (g, p) in enumerate(zip(gs, ps))]
+                new_ps = [o[0] for o in outs]
                 ns = _stack_states([o[1] for o in outs])
+            elif b.rule.vector_update is not None:
+                # leaves in, leaves out: the rule reads and writes each
+                # leaf where it lives and only the state is stacked
+                if coded and b.rule.codec_native:
+                    new_ps, ns = b.rule.vector_update(gs, ps, st, step,
+                                                      lids, key)
+                elif coded:
+                    dec = _decode_stacked(cdc, b.rule.slots, st)
+                    new_ps, ns = b.rule.vector_update(gs, ps, dec, step,
+                                                      lids)
+                    ns = _encode_stacked(cdc, b.rule.slots, ns, key, step,
+                                         lids)
+                else:
+                    new_ps, ns = b.rule.vector_update(gs, ps, st, step,
+                                                      lids)
             else:
                 # ``optim.pack`` names the ops that bring a bucket's leaves
                 # into the stacked layout and back (DESIGN.md §12)
                 with jax.named_scope("optim.pack"):
-                    g_stk = jnp.stack([gleaves[i] for i in b.indices])
-                    p_stk = jnp.stack([pleaves[i] for i in b.indices])
-                if b.rule.vector_update is not None:
-                    if coded and b.rule.codec_native:
-                        np_stk, ns = b.rule.vector_update(
-                            g_stk, p_stk, st, step, lids, key)
-                    elif coded:
-                        dec = _decode_stacked(cdc, b.rule.slots, st)
-                        np_stk, ns = b.rule.vector_update(g_stk, p_stk, dec,
-                                                          step, lids)
-                        ns = _encode_stacked(cdc, b.rule.slots, ns, key,
-                                             step, lids)
-                    else:
-                        np_stk, ns = b.rule.vector_update(g_stk, p_stk, st,
-                                                          step, lids)
-                else:
-                    def body(_, xs):
-                        g, p, s, lid = xs
-                        return None, leaf_update(g, p, s, lid)
-                    _, (np_stk, ns) = jax.lax.scan(
-                        body, None, (g_stk, p_stk, st, lids))
-                if with_taps:
-                    g32 = g_stk.astype(jnp.float32)
-                    d32 = (np_stk.astype(jnp.float32)
-                           - p_stk.astype(jnp.float32))
-                    tp = {"grad_ssq": jnp.sum(g32 * g32),
-                          "update_ssq": jnp.sum(d32 * d32)}
-                    if coded:
-                        tp.update(_codec_taps(ns))
-                    if b.rule.taps is not None:
-                        tp.update(b.rule.taps(g_stk, p_stk, np_stk, st, ns,
-                                              step))
-                    for k, v in tp.items():
-                        taps[f"{b.name}/{k}"] = jnp.asarray(v, jnp.float32)
+                    g_stk, p_stk = jnp.stack(gs), jnp.stack(ps)
+
+                def body(_, xs):
+                    g, p, s, lid = xs
+                    return None, leaf_update(g, p, s, lid)
+                _, (np_stk, ns) = jax.lax.scan(
+                    body, None, (g_stk, p_stk, st, lids))
+                with jax.named_scope("optim.pack"):
+                    new_ps = [np_stk[j] for j in range(len(gs))]
+            if with_taps:
+                # the taps read the bucket stacked; only the tapped
+                # update builds these stacks
+                g_stk, p_stk, np_stk = (jnp.stack(x) for x in
+                                        (gs, ps, new_ps))
+                g32 = g_stk.astype(jnp.float32)
+                d32 = np_stk.astype(jnp.float32) - p_stk.astype(jnp.float32)
+                tp = {"grad_ssq": jnp.sum(g32 * g32),
+                      "update_ssq": jnp.sum(d32 * d32)}
+                if coded:
+                    tp.update(_codec_taps(ns))
+                if b.rule.taps is not None:
+                    tp.update(b.rule.taps(g_stk, p_stk, np_stk, st, ns,
+                                          step))
+                for k, v in tp.items():
+                    taps[f"{b.name}/{k}"] = jnp.asarray(v, jnp.float32)
             new_buckets[b.name] = _constrain_bucket(ns, hints.get(b.name))
-            with jax.named_scope("optim.pack"):
-                for j, i in enumerate(b.indices):
-                    new_leaves[i] = np_stk[j]
+            for i, new_p in zip(b.indices, new_ps):
+                new_leaves[i] = new_p
         out = {"step": step + 1, "buckets": new_buckets}
         if quant:
             out["codec_key"] = key
@@ -430,9 +440,9 @@ def build(assign: Callable[[str, Any], LeafRule],
 
     def tapped_update(grads, state, params):
         """``update`` plus per-bucket observability scalars — the on-device
-        tap channel (DESIGN.md §12).  Taps need the stacked grads/params
-        only the bucketed path materializes, so the unrolled reference
-        engine exposes no tapped channel."""
+        tap channel (DESIGN.md §12).  Taps read each bucket's grads and
+        params stacked, and build those stacks themselves; the unrolled
+        reference engine exposes no tapped channel."""
         return _run(grads, state, params, with_taps=True)
 
     return Optimizer(init, update, engine=eng,

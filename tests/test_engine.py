@@ -282,3 +282,58 @@ def test_state_sharding_hint_structure_mismatch_raises():
                       state_shardings={"gwt_last__nope": {"host": 0}})
     st = opt2.init(params)
     assert "gwt_last__mlp.w" in st["buckets"]
+
+
+def _outer_eqns(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs it calls, except the
+    bodies of Pallas kernels (which work on VMEM tiles, not on leaves)."""
+    from jax.extend import core as jcore
+    for e in jaxpr.eqns:
+        yield e
+        if e.primitive.name == "pallas_call":
+            continue
+        for v in e.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                if isinstance(sub, jcore.ClosedJaxpr):
+                    yield from _outer_eqns(sub.jaxpr)
+                elif isinstance(sub, jcore.Jaxpr):
+                    yield from _outer_eqns(sub)
+
+
+@pytest.mark.parametrize("orient", ["last", "first"])
+def test_fused_update_takes_leaves_where_they_live(orient):
+    """On the fused path the update hands the kernel each GWT leaf's own
+    gradient and parameter: ``opt.update`` concatenates no leaves (its
+    only concatenates gather scalars: a bucket's per-leaf limiter norms,
+    a launch's step size and weight decay), and
+    the trace-time counter ``gwt.kernel.in_place`` counts every GWT
+    element in place — or, for FIRST-orient leaves, whose transpose is a
+    copy, every one through a copy."""
+    from repro import obs
+    shapes = {"last": ((16, 16), (16, 32), (2, 32, 16)),
+              "first": ((16, 7), (32, 7), (2, 32, 7))}[orient]
+    k = jax.random.key(5)
+    params = {f"layer_{i}": {f"w{j}": jax.random.normal(
+        jax.random.fold_in(k, 3 * i + j), s) for j, s in enumerate(shapes)}
+        for i in range(3)}
+    opt = optim.make("gwt", lr=0.01, level=2, impl="interpret")
+    st = opt.init(params)
+    assert all(name.startswith(f"gwt_{orient}__") for name in st["buckets"])
+    jax.clear_caches()   # the kernel wrapper's counters fire when traced
+    tracer = obs.Tracer()
+    obs.configure(tracer=tracer)
+    try:
+        jaxpr = jax.make_jaxpr(opt.update)(params, st, params)
+    finally:
+        obs.shutdown()
+    cats = [e for e in _outer_eqns(jaxpr.jaxpr)
+            if e.primitive.name == "concatenate"]
+    assert cats and all(e.outvars[0].aval.ndim == 1 for e in cats), \
+        [e.outvars[0].aval for e in cats]
+    samples = [e["args"] for e in tracer.events
+               if e["name"] == "gwt.kernel.in_place"]
+    assert len(samples) == len(st["buckets"]) == 3
+    total = sum(x.size for x in jax.tree.leaves(params))
+    copied = total if orient == "first" else 0
+    assert sum(s["elements_in_place"] for s in samples) == total - copied
+    assert sum(s["elements_stacked"] for s in samples) == copied

@@ -141,10 +141,11 @@ def test_fused_write_core_bitwise_vs_staged_oracle(L, m, n, level,
                                                    use_limiter):
     g, p, st, pn = _fused_write_inputs(L, m, n, level)
     kw = _fused_write_kw(level, use_limiter=use_limiter)
-    pi, ni, si = gops.fused_write_update(g, p, st, jnp.int32(2), pn,
+    pi, ni, si = gops.fused_write_update(list(g), list(p), st,
+                                         jnp.int32(2), pn,
                                          impl="interpret", **kw)
-    pj, nj, sj = gops.fused_write_update(g, p, st, jnp.int32(2), pn,
-                                         impl="jnp", **kw)
+    pj, nj, sj = gops.fused_write_update(list(g), list(p), st,
+                                         jnp.int32(2), pn, impl="jnp", **kw)
     for tag, a, b in [("norm", ni, nj),
                       ("m", si["m"], sj["m"]), ("v", si["v"], sj["v"])]:
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
@@ -158,11 +159,12 @@ def test_fused_write_bf16_params_vs_staged_oracle():
     in practice for weight_decay == 0."""
     g, p, st, pn = _fused_write_inputs(2, 16, 256, 2, dtype=jnp.bfloat16)
     kw = _fused_write_kw(2)
-    pi, ni, si = gops.fused_write_update(g, p, st, jnp.int32(1), pn,
+    pi, ni, si = gops.fused_write_update(list(g), list(p), st,
+                                         jnp.int32(1), pn,
                                          impl="interpret", **kw)
-    pj, nj, sj = gops.fused_write_update(g, p, st, jnp.int32(1), pn,
-                                         impl="jnp", **kw)
-    assert pi.dtype == jnp.bfloat16
+    pj, nj, sj = gops.fused_write_update(list(g), list(p), st,
+                                         jnp.int32(1), pn, impl="jnp", **kw)
+    assert {x.dtype for x in pi} == {jnp.dtype(jnp.bfloat16)}
     bits_i = np.asarray(pi).view(np.uint16).astype(np.int32)
     bits_j = np.asarray(pj).view(np.uint16).astype(np.int32)
     assert np.abs(bits_i - bits_j).max() <= 1
@@ -177,10 +179,11 @@ def test_fused_write_weight_decay_within_fma_bound():
     contraction bound; everything upstream of the write stays bitwise."""
     g, p, st, pn = _fused_write_inputs(2, 32, 512, 4)
     kw = _fused_write_kw(4, weight_decay=0.01)
-    pi, ni, si = gops.fused_write_update(g, p, st, jnp.int32(2), pn,
+    pi, ni, si = gops.fused_write_update(list(g), list(p), st,
+                                         jnp.int32(2), pn,
                                          impl="interpret", **kw)
-    pj, nj, sj = gops.fused_write_update(g, p, st, jnp.int32(2), pn,
-                                         impl="jnp", **kw)
+    pj, nj, sj = gops.fused_write_update(list(g), list(p), st,
+                                         jnp.int32(2), pn, impl="jnp", **kw)
     _assert_write_parity(pi, pj, p)
     np.testing.assert_array_equal(np.asarray(ni), np.asarray(nj))
     np.testing.assert_array_equal(np.asarray(si["m"]), np.asarray(sj["m"]))
@@ -220,9 +223,11 @@ def test_fused_write_q8_bitwise_vs_staged_oracle():
     st, key, leaf_ids = _q8_encoded_state(L, m, n >> level)
     kw = _fused_write_kw(level)
     pi, ni, si = gops.fused_write_update_q8(
-        g, p, st, jnp.int32(1), key, leaf_ids, pn, impl="interpret", **kw)
+        list(g), list(p), st, jnp.int32(1), key, leaf_ids, pn,
+        impl="interpret", **kw)
     pj, nj, sj = gops.fused_write_update_q8(
-        g, p, st, jnp.int32(1), key, leaf_ids, pn, impl="jnp", **kw)
+        list(g), list(p), st, jnp.int32(1), key, leaf_ids, pn,
+        impl="jnp", **kw)
     _assert_write_parity(pi, pj, p)
     np.testing.assert_array_equal(np.asarray(ni), np.asarray(nj))
     jax.tree.map(lambda a, b: np.testing.assert_array_equal(
@@ -241,9 +246,11 @@ def test_fused_write_q8_nontileable_falls_back_to_oracle():
     st, key, leaf_ids = _q8_encoded_state(L, m, n >> level)
     kw = _fused_write_kw(level)
     pi, ni, si = gops.fused_write_update_q8(
-        g, p, st, jnp.int32(1), key, leaf_ids, pn, impl="interpret", **kw)
+        list(g), list(p), st, jnp.int32(1), key, leaf_ids, pn,
+        impl="interpret", **kw)
     pj, nj, sj = gops.fused_write_update_q8(
-        g, p, st, jnp.int32(1), key, leaf_ids, pn, impl="jnp", **kw)
+        list(g), list(p), st, jnp.int32(1), key, leaf_ids, pn,
+        impl="jnp", **kw)
     assert np.isfinite(np.asarray(pi)).all()
     _assert_write_parity(pi, pj, p)
     np.testing.assert_array_equal(np.asarray(ni), np.asarray(nj))
@@ -276,10 +283,11 @@ def test_fused_write_partial_row_tile_vs_staged_oracle(state_codec,
     if state_codec == "int8":
         st, key, leaf_ids = _q8_encoded_state(L, m, n >> level)
         run = lambda impl: gops.fused_write_update_q8(
-            g, p, st, jnp.int32(1), key, leaf_ids, pn, impl=impl, **kw)
+            list(g), list(p), st, jnp.int32(1), key, leaf_ids, pn,
+            impl=impl, **kw)
     else:
         run = lambda impl: gops.fused_write_update(
-            g, p, st, jnp.int32(2), pn, impl=impl, **kw)
+            list(g), list(p), st, jnp.int32(2), pn, impl=impl, **kw)
     pi, ni, si = run("interpret")
     pj, nj, sj = run("jnp")
     np.testing.assert_array_equal(np.asarray(ni), np.asarray(nj))
@@ -290,6 +298,97 @@ def test_fused_write_partial_row_tile_vs_staged_oracle(state_codec,
     # inverse butterfly, so g̃ moves by ulps of its operands (measured: 1404
     # of 816000 elements differ, the worst by 24 spacings of new_p).
     _assert_write_parity(pi, pj, p, butterfly=True)
+
+
+# One launch per leaf: a bucket's leaves as the engine hands them over,
+# ``(leaf shape, leaves)``: a pair of 80-row leaves whose last 64-row tile
+# is partial, a four-dimensional expert-style pair (layers, experts,
+# d_model, d_ff), and a single-leaf layer stack.
+LEAF_BUCKETS = [((80, 2048), 2), ((2, 4, 16, 768), 2), ((3, 32, 512), 1)]
+
+
+def _leaf_bucket(leaf, L, codec):
+    """bf16 gradient and parameter leaves (the cells' dtypes), the
+    bucket's stacked state in the engine's layout, and the ``(L, rows,
+    n)`` views of the same arrays that one stacked launch takes."""
+    from repro.optim import codec as codec_lib
+    k = jax.random.key(sum(leaf) + L)
+    g = jax.random.normal(k, (L,) + leaf, jnp.bfloat16)
+    p = jax.random.normal(jax.random.fold_in(k, 1), (L,) + leaf,
+                          jnp.bfloat16)
+    n = leaf[-1]
+    rows = g[0].size // n
+    a_shape = (L,) + leaf[:-1] + (n >> 2,)
+    mf = jax.random.normal(jax.random.fold_in(k, 2), a_shape) * 0.1
+    vf = jnp.abs(jax.random.normal(jax.random.fold_in(k, 3), a_shape)) * .01
+    pn = jnp.arange(L, dtype=jnp.float32) * 0.3
+    if codec == "f32":
+        st = {"m": mf, "v": vf}
+        stacked = (mf.reshape(L, rows, -1), vf.reshape(L, rows, -1))
+    else:
+        key = codec_lib.make_key(0)
+        st = {}
+        for slot, name, src in ((0, "m", mf), (1, "v", vf)):
+            qs = [codec_lib.blocked_quant(
+                src[j].reshape(rows, -1),
+                codec_lib.slot_salt(key, jnp.uint32(0), slot, jnp.uint32(j)),
+                64) for j in range(L)]
+            st[name] = {"q": jnp.stack([q for q, _ in qs]),
+                        "scale": jnp.stack([s for _, s in qs])}
+        stacked = (st["m"]["q"], st["m"]["scale"], st["v"]["q"],
+                   st["v"]["scale"])
+    return (list(g), list(p), st, pn,
+            (g.reshape(L, rows, n), p.reshape(L, rows, n)) + stacked)
+
+
+@pytest.mark.parametrize("leaf,L", LEAF_BUCKETS)
+@pytest.mark.parametrize("codec,use_limiter,wd", [
+    ("f32", True, 0.0), ("f32", False, 0.0), ("f32", True, 0.01),
+    ("int8", True, 0.01)])
+def test_leaf_launches_bitwise_vs_stacked_bucket(leaf, L, codec,
+                                                 use_limiter, wd):
+    """The fused write as the engine runs it, one interpret-mode launch
+    per leaf on the leaves' own buffers over the bucket's stacked state,
+    returns new p, m and v (or their int8 payloads and scales) and the
+    limiter norms bit for bit as the oracle ``ref.gwt_adam_fused{,_q8}``
+    on the stacked bucket, and as one launch over the stacked bucket."""
+    from repro.optim import codec as codec_lib
+    gs, ps, st, pn, stacked = _leaf_bucket(leaf, L, codec)
+    step = jnp.int32(3)
+    kw = _fused_write_kw(2, use_limiter=use_limiter, weight_decay=wd)
+    ss, wc = gops._step_scalars(step, kw["lr_t"], kw["alpha"], wd, 0.9,
+                                0.999)
+    k2 = dict(level=2, gamma=1.01, use_limiter=use_limiter,
+              weight_decay=wd != 0)
+    _, rows, n = stacked[0].shape
+    if codec == "f32":
+        new_ps, norm, new_st = gops.fused_write_update(
+            gs, ps, st, step, pn, impl="interpret", **kw)
+        got = [new_st["m"], new_st["v"]]
+        args = stacked + (pn, ss, wc)
+        want = rg.gwt_adam_fused(*args, bm=kg.fused_row_block(rows, n, 2),
+                                 **k2)
+        once = kg.gwt_adam_tile_fused(*args, interpret=True, **k2)
+    else:
+        key, lids = codec_lib.make_key(0), jnp.arange(L, dtype=jnp.uint32)
+        new_ps, norm, new_st = gops.fused_write_update_q8(
+            gs, ps, st, step, key, lids, pn, impl="interpret", **kw)
+        got = [new_st["m"]["q"], new_st["m"]["scale"], new_st["v"]["q"],
+               new_st["v"]["scale"]]
+        salts = [codec_lib.slot_salt(key, step, s, lids) for s in (0, 1)]
+        args = stacked + (*salts, pn, ss, wc)
+        want = rg.gwt_adam_fused_q8(
+            *args, bm=kg.q8_row_block(rows, n, 2, 64), block=64, **k2)
+        once = kg.gwt_adam_tile_fused_q8(*args, block=64, interpret=True,
+                                         **k2)
+    assert [x.shape for x in new_ps] == [x.shape for x in gs]
+    got = [jnp.stack(new_ps)] + got + [norm]
+    for ref_out in (want, once):
+        for i, (a, b) in enumerate(zip(got, ref_out)):
+            a, b = np.asarray(a), np.asarray(b).reshape(np.shape(a))
+            np.testing.assert_array_equal(a.view(f"u{a.itemsize}"),
+                                          b.view(f"u{b.itemsize}"),
+                                          err_msg=f"output {i}")
 
 
 def test_fused_write_on_sharded_mesh_matches_one_device():
@@ -309,7 +408,7 @@ def test_fused_write_on_sharded_mesh_matches_one_device():
         g, p, st, pn = _fused_write_inputs(2, 64, 256, 2)
         kw = _fused_write_kw(2)
         run = jax.jit(lambda g, p, st, pn: gops.fused_write_update(
-            g, p, st, jnp.int32(2), pn, impl="interpret", **kw))
+            list(g), list(p), st, jnp.int32(2), pn, impl="interpret", **kw))
         one = run(g, p, st, pn)
         mesh = compat.make_mesh((4,), ("data",))
         rows = NamedSharding(mesh, P(None, "data", None))
@@ -408,7 +507,7 @@ def _three_pass_core(x, m_st, v_st, level, b1, b2, eps, xla=False):
     tile through the f32 schedule's three-pass shuffles, G̃ rounded to the
     gradient's dtype at the end."""
     out, m, v = kg._core_shuffled(x.astype(jnp.float32), m_st, v_st, level,
-                                  b1, b2, eps)
+                                  b1, b2, eps, xla)
     return out.astype(x.dtype), m, v
 
 
@@ -456,7 +555,8 @@ def test_fused_write_counts_one_pass_schedule():
         for dtype, L in ((jnp.bfloat16, 2), (jnp.float32, 1)):
             g, p, st, pn = _fused_write_inputs(L, 8, 256, 2, dtype=dtype)
             jax.eval_shape(lambda *a: gops.fused_write_update(
-                *a, impl="interpret", **kw), g, p, st, jnp.int32(0), pn)
+                *a, impl="interpret", **kw), list(g), list(p), st,
+                jnp.int32(0), pn)
     finally:
         obs.shutdown()
     got = [e for e in tracer.events if e["name"] == "gwt.kernel.one_pass"]

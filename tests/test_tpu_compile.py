@@ -93,6 +93,61 @@ def test_fused_f32_compiles(one_chip, shape):
     _compile(fn, one_chip, *_fused_args(*shape))
 
 
+def _big_ops(comp: str, limit: int = 4096):
+    """The instructions of one HLO computation's text, other than
+    parameters, bitcasts and tuple plumbing, whose array result has more
+    than ``limit`` elements (the kernel launches return tuples)."""
+    out = []
+    for line in comp.splitlines()[1:]:
+        m = re.match(r"\s*(?:ROOT )?%?\S+ = (\w+)\[([\d,]*)\]\S* ([\w\-]+)\(",
+                     line)
+        if not m:
+            continue
+        dtype, dims, op = m.groups()
+        size = 1
+        for d in filter(None, dims.split(",")):
+            size *= int(d)
+        if size > limit and op not in ("parameter", "bitcast",
+                                       "get-tuple-element"):
+            out.append(f"{op} {dtype}[{dims}]")
+    return out
+
+
+@pytest.mark.parametrize("leaf", [(9, 2048, 11008), (6, 32, 2048, 768)],
+                         ids=["qwen-gate_up", "qwen3moe-gate_up"])
+def test_fused_update_takes_leaves_in_place(one_chip, leaf):
+    """The optimizer's fused update of a two-leaf bucket at a cell's size
+    (Qwen2.5-3B's gate/up layer stacks, Qwen3-30B-A3B's expert stacks),
+    run in a loop that carries the parameters and moments as the train
+    superstep does, compiles to one launch per leaf on the carried and
+    the gradients' own buffers: nothing in the loop stacks, slices,
+    copies or transposes a leaf or the moments."""
+    from repro.kernels.gwt_adam import ops as gops
+    L = 2
+    a_shape = (L,) + leaf[:-1] + (leaf[-1] >> LEVEL,)
+    sds = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+    args = ([sds(leaf, BF16)] * L, [sds(leaf, BF16)] * L,
+            {"m": sds(a_shape, F32), "v": sds(a_shape, F32)},
+            sds((L,), F32))
+
+    def steps(gs, ps, st, pn):
+        def step(i, carry):
+            ps, st, pn = carry
+            ps, pn, st = gops.fused_write_update(
+                gs, ps, st, i, pn, lr_t=jnp.float32(1e-3), alpha=0.25,
+                weight_decay=0.0, gamma=1.01, use_limiter=True,
+                level=LEVEL, impl="pallas")
+            return ps, st, pn
+        return jax.lax.fori_loop(0, 3, step, (ps, st, pn))
+    hlo = jax.jit(steps, donate_argnums=(1, 2)).lower(*args).compile() \
+        .as_text()
+    (body,) = re.findall(r" while\(.*?body=%?([\w.\-]+)", hlo)
+    comp = hlo[hlo.index(f"\n%{body} ") + 1:]
+    comp = comp[:comp.index("\n}")]
+    assert comp.count('custom_call_target="tpu_custom_call"') == L
+    assert _big_ops(comp) == []
+
+
 @pytest.mark.parametrize("shape,use_limiter", [
     (ATTN, True), (MLP, True), (MLP_DOWN, False), (ATTN, False)],
     ids=["attn-limiter", "mlp-limiter", "mlp_down-nolimiter",
