@@ -30,7 +30,6 @@ butterfly, and ``'auto'`` (default) resolves per platform via
 
 from __future__ import annotations
 
-import functools
 from typing import Callable, Dict, Optional
 
 import jax
@@ -73,7 +72,6 @@ def gwt(lr: Schedule | float,
         state_dtype=jnp.float32,
         wavelet: str = "haar",
         impl: str = "auto",
-        fused_write: bool = True,
         bucketed: bool = True,
         state_shardings=None,
         state_codec="f32") -> Optimizer:
@@ -86,10 +84,7 @@ def gwt(lr: Schedule | float,
     (``repro.optim.codec``): int8 composes multiplicatively with the
     wavelet subspace — host moments live on the ``A_l`` band AND are
     stored blocked-quantized.  On the fused kernel path the requantize
-    epilogue runs inside the kernel (``ops.fused_write_update_q8``).
-    ``fused_write=False`` keeps the DWT+Adam core kernel but stages the
-    limiter/step/param-write outside it (the pre-megakernel dataflow,
-    materializing g̃) — a benchmarking baseline, not a production knob."""
+    epilogue runs inside the kernel (``ops.fused_write_update_q8``)."""
     from repro.optim import codec as codec_lib
     if wavelet not in ("haar", "db2"):
         raise ValueError(f"unknown wavelet {wavelet!r}")
@@ -152,20 +147,19 @@ def gwt(lr: Schedule | float,
             return {"host": h.init(jax.ShapeDtypeStruct(a_shape, state_dtype)),
                     "prev_norm": jnp.zeros((), jnp.float32)}
 
-        def core(g, hstate, step):
-            gt = jnp.swapaxes(g, -1, -2) if swap else g
+        def update(g, p, state, step, leaf_id):
             if use_fused:
-                from repro.kernels.gwt_adam import ops as gwt_ops  # lazy
-                g_tilde, lr_mult, hstate = gwt_ops.fused_update(
-                    gt, hstate, step, level=level, impl=impl, **adam_kw)
-            else:
-                g_tilde, lr_mult, hstate = _gwt_core(gt, hstate, step)
+                # the fused launch on a one-leaf bucket; a coded state
+                # arrives here decoded, so the f32 launch serves either
+                # codec
+                new_ps, out = vector_update(
+                    [g], [p], jax.tree.map(lambda a: a[None], state), step,
+                    jnp.reshape(leaf_id, (1,)))
+                return new_ps[0], jax.tree.map(lambda a: a[0], out)
+            gt = jnp.swapaxes(g, -1, -2) if swap else g
+            g_tilde, lr_mult, hstate = _gwt_core(gt, state["host"], step)
             if swap:
                 g_tilde = jnp.swapaxes(g_tilde, -1, -2)
-            return g_tilde, lr_mult, hstate
-
-        def update(g, p, state, step, leaf_id):
-            g_tilde, lr_mult, hstate = core(g, state["host"], step)
             out = {"host": hstate, "prev_norm": state["prev_norm"]}
             if use_limiter:
                 g_tilde, out["prev_norm"] = limiter.limit(
@@ -231,7 +225,7 @@ def gwt(lr: Schedule | float,
             return out
 
         vu, native = None, False
-        if use_fused and fused_write:
+        if use_fused:
             vu, native = (vector_update_q8, True) if quant \
                 else (vector_update, False)
         return engine.LeafRule(

@@ -5,8 +5,7 @@ exact ``(index, batch)`` queue contract.
 Why processes: the thread Prefetcher decouples *latency* but not *CPU* —
 a tokenization-heavy source (pure-python BPE encode) holds the GIL, so
 the producer thread and the training host serialize.  Worker processes
-each own an interpreter; throughput scales with workers
-(``benchmarks/run.py data`` gates process ≥ thread on the heavy source).
+each own an interpreter, so throughput can grow with workers.
 
 Transport: one ``SharedMemory`` segment carved into ``depth`` slots.  A
 worker computes a batch, claims a free slot, writes each array into the

@@ -8,18 +8,18 @@ Per ``(bm, n)`` row stripe of the gradient, in a single VMEM residency:
     Ã = M/(√V+ε) ;  D̃_k = D_k · repeat(1/(√V+ε))
     inverse butterfly → G̃ tile
     partial ‖G̃‖² per tile                          [for the norm-growth limiter]
+    P ← P − step · limit(G̃) [− wd · P]
 
-HBM traffic of one pass: read G + read/write M,V (at ``1/2^l`` of the
-width) + write G̃ — about ``2 + 4/2^l`` elements per gradient element,
-against ``≥ 6`` for the op-by-op schedule.  The detail bands are *never*
-materialized in HBM — the paper's "temporary information generated during
-the wavelet transform" observation (§V), taken to its architectural
-conclusion.  With the norm-growth limiter on (the default) the fused-write
-kernel below makes two passes (phase 0 sums ‖G̃‖² and writes P, M, V
-through unchanged, phase 1 recomputes and writes): with bf16 G and P and
-f32 moments at level 2, 20 B per gradient element against the 10 B of
-the least one-pass traffic.  With three-pass shuffles it ran at 19% of
-that 10 B roofline on a TPU v5e, bound by the MXU's shuffles.
+The detail bands and G̃ are *never* materialized in HBM — the paper's
+"temporary information generated during the wavelet transform"
+observation (§V), taken to its architectural conclusion.  One pass reads
+G and P, reads and writes M, V (at ``1/2^l`` of the width) and writes P:
+with bf16 G and P and f32 moments at level 2, 10 B per gradient element.
+With the norm-growth limiter on (the default) the kernel makes two
+passes (phase 0 sums ‖G̃‖² and writes P, M, V through unchanged, phase 1
+recomputes and writes), 20 B per gradient element.  With three-pass
+shuffles it ran at 19% of that 10 B roofline on a TPU v5e, bound by the
+MXU's shuffles.
 
 The butterfly's even/odd lane pairing is a product with a 0/1 selection
 matrix on the MXU (``repro.kernels.lanes``), exact, so every tile is
@@ -35,18 +35,13 @@ partial last tile masks its out-of-range rows out of every norm.
 Scalars (per-leaf limiter state, step size, weight decay, rounding salts)
 and the per-tile norm partials live in SMEM.
 
-Bias correction (``lr_mult``) and the norm-growth limiter ratio are applied
-by the caller (ops.py) on the staged path — the limiter needs the global
-norm, which is reduced from the per-tile partials this kernel emits.
-
 **Fused-write megakernel** (``gwt_adam_tile_fused{,_q8}``): the full
 DWT→Adam→inverse→limit→param-write chain for ``(Lg, m, n)`` leaves in ONE
 launch.  The leaf axis is folded into the grid (no vmap); the per-leaf
 ``‖G̃‖`` reduction runs as a two-phase pass over the row tiles with the
 SMEM ``new_norm`` output as the accumulator, and the epilogue applies the
 norm-growth limiter, the bias-corrected step size, and weight decay before
-writing the parameter tile.  ``G̃`` never round-trips HBM and the gradient
-never lives alongside its transform.  The moments are a bucket's whole
+writing the parameter tile.  The moments are a bucket's whole
 ``(L, m, n >> level)`` stack, updated in place: the launch's leaf ``l``
 owns slab ``leaf0 + l`` (a scalar-prefetched offset that only the
 moments' index maps read), so ``ops.py`` runs one launch per leaf on the
@@ -61,7 +56,6 @@ f32 scale each, so a row tile always holds whole blocks.
 from __future__ import annotations
 
 import functools
-from typing import Tuple
 
 import jax
 import jax.numpy as jnp
@@ -190,55 +184,6 @@ def _check_width(n: int, level: int) -> None:
         raise ValueError(f"n={n} not divisible by 2^{level}")
 
 
-def _body(level: int, b1: float, b2: float, eps: float, xla: bool,
-          rows: int, g_ref, m_ref, v_ref, gt_ref, m_out_ref, v_out_ref,
-          ssq_ref):
-    i = pl.program_id(0)
-    x = g_ref[...]
-    out, m, v = _dht_adam_core(x, m_ref[...].astype(jnp.float32),
-                               v_ref[...].astype(jnp.float32),
-                               level, b1, b2, eps, xla)
-    out = out.astype(gt_ref.dtype)
-    gt_ref[...] = out
-    m_out_ref[...] = m.astype(m_out_ref.dtype)
-    v_out_ref[...] = v.astype(v_out_ref.dtype)
-    # limiter norm partials over the ROUNDED output tile (matches ref.py):
-    # the limiter should see the norm of the g̃ actually written to HBM
-    ssq_ref[i] = lanes.masked_ssq(out.astype(jnp.float32), i * x.shape[0],
-                                  rows)
-
-
-def gwt_adam_tile(g: jax.Array, m_st: jax.Array, v_st: jax.Array, *,
-                  level: int, b1: float = 0.9, b2: float = 0.999,
-                  eps: float = 1e-6, interpret: bool = False
-                  ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
-    """Fused update for one 2-D leaf.
-
-    Returns ``(g_tilde, new_m, new_v, sumsq_partials)`` where
-    ``sumsq_partials`` has one entry per row tile (caller sums → ‖G̃‖²).
-    """
-    mm, nn = g.shape
-    _check_width(nn, level)
-    na = nn >> level
-    stream = 2 * g.dtype.itemsize + 4 * m_st.dtype.itemsize / (1 << level)
-    bm = lanes.row_block(mm, _row_bytes(nn, level, stream))
-    gm = pl.cdiv(mm, bm)
-    tile = lambda w: pl.BlockSpec((bm, w), lambda i: (i, 0))
-    return pl.pallas_call(
-        functools.partial(_body, level, b1, b2, eps, interpret, mm),
-        grid=(gm,),
-        in_specs=[tile(nn), tile(na), tile(na)],
-        out_specs=[tile(nn), tile(na), tile(na), _SMEM],
-        out_shape=[
-            jax.ShapeDtypeStruct((mm, nn), g.dtype),
-            jax.ShapeDtypeStruct((mm, na), m_st.dtype),
-            jax.ShapeDtypeStruct((mm, na), v_st.dtype),
-            jax.ShapeDtypeStruct((gm,), jnp.float32),
-        ],
-        interpret=interpret,
-    )(g, m_st, v_st)
-
-
 # ---------------------------------------------------------------------------
 # Fused-write megakernel: one launch per leaf (or per stack of leaves)
 # does DWT -> Adam -> inverse -> norm-growth limiter -> parameter write.
@@ -266,7 +211,7 @@ def q8_row_block(m: int, n: int, level: int, block: int) -> int:
 
 def _limiter_scale(norm, prev, gamma: float):
     """The norm-growth limiter ratio — term-for-term ``core.limiter.limit``
-    (bitwise parity with the staged path is a test invariant)."""
+    (bitwise parity with the oracle is a test invariant)."""
     safe_prev = jnp.where(prev > 0, prev, norm)
     return jnp.where(norm > gamma * safe_prev,
                      gamma * safe_prev / jnp.maximum(norm, 1e-30),

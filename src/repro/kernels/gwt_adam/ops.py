@@ -7,17 +7,13 @@ state, and they make one fused-write launch per leaf on the leaf's own
 buffers (``(1, rows, n)`` bitcast views), updating the leaf's slab of
 the stacked moments in place.  No stacked copy of the gradients or
 parameters is built, and no new parameter is sliced back out of one.
-
-``fused_update`` is the staged core (``gwt(fused_write=False)``): any
-leading dims of ``g`` are flattened and vmapped.  Semantics match
-``repro.core.gwt._gwt_core`` exactly (tested leaf-by-leaf); the
-norm-growth limiter stays in the caller (vmapped per leaf).
+The limiter, the bias-corrected step, weight decay and the parameter
+write all run inside the launch, so g̃ never round-trips HBM.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Tuple
 
 import jax
 import jax.numpy as jnp
@@ -27,64 +23,10 @@ from repro import compat, obs
 from repro.kernels.gwt_adam import kernel, ref
 
 
-def _tile_fn(impl: str, level: int, b1: float, b2: float, eps: float):
-    impl = compat.resolve_kernel_impl(impl)
-    if impl == "pallas":
-        return functools.partial(kernel.gwt_adam_tile, level=level, b1=b1,
-                                 b2=b2, eps=eps)
-    if impl == "interpret":
-        return functools.partial(kernel.gwt_adam_tile, level=level, b1=b1,
-                                 b2=b2, eps=eps, interpret=True)
-    return functools.partial(ref.gwt_adam_tile, level=level, b1=b1, b2=b2,
-                             eps=eps)
-
-
-def fused_update(g: jax.Array, state: dict, step: jax.Array, *,
-                 level: int, b1: float = 0.9, b2: float = 0.999,
-                 eps: float = 1e-6, impl: str = "auto"
-                 ) -> Tuple[jax.Array, jax.Array, dict]:
-    """Returns ``(g_tilde, lr_mult, new_state)`` — drop-in for the jnp core.
-
-    ``impl``: auto|pallas|interpret|jnp — 'auto' resolves per platform via
-    repro.compat (launchers pass MeshContext.kernel_impl explicitly).
-    Resolution happens OUTSIDE the jitted body: 'auto' as a static jit arg
-    would freeze the REPRO_KERNEL_IMPL env read into the trace cache."""
-    impl = compat.resolve_kernel_impl(impl)
-    return _fused_update(g, state, step, level=level, b1=b1, b2=b2, eps=eps,
-                         impl=impl)
-
-
-@functools.partial(jax.jit, static_argnames=("level", "b1", "b2", "eps", "impl"))
-def _fused_update(g, state, step, *, level, b1, b2, eps, impl):
-    _count_schedule(g)
-    fn = _tile_fn(impl, level, b1, b2, eps)
-    if g.ndim > 2:  # stacked scan leaves (L, m, n)
-        lead = g.shape[:-2]
-        g2 = g.reshape((-1,) + g.shape[-2:])
-        m2 = state["m"].reshape((-1,) + state["m"].shape[-2:])
-        v2 = state["v"].reshape((-1,) + state["v"].shape[-2:])
-        gt, m, v, _ = jax.vmap(fn)(g2, m2, v2)
-        gt = gt.reshape(lead + gt.shape[-2:])
-        m = m.reshape(lead + m.shape[-2:])
-        v = v.reshape(lead + v.shape[-2:])
-    else:
-        gt, m, v, _ = fn(g, state["m"], state["v"])
-    t = step.astype(jnp.float32) + 1.0
-    lr_mult = jnp.sqrt(1 - b2 ** t) / (1 - b1 ** t)
-    return gt, lr_mult, {"m": m, "v": v}
-
-
-# ---------------------------------------------------------------------------
-# Fused-write (megakernel) path: limiter + bias-corrected apply + weight
-# decay + parameter write move INTO the launch — one kernel call per leaf
-# of a bucket consumes (g, p, its slab of the bucket's m, v, prev_norm)
-# and emits (new_p, new_m, new_v, new_norm); g̃ never round-trips HBM.
-# ---------------------------------------------------------------------------
-
 def _step_scalars(step, lr_t, alpha, weight_decay, b1, b2):
     """Bias-corrected step size and weight-decay coefficient, computed
     outside the kernel exactly as ``core.gwt._apply`` does (term order
-    matters for bitwise parity with the staged path)."""
+    matters for bitwise parity with the jnp reference)."""
     t = step.astype(jnp.float32) + 1.0
     lr_mult = jnp.sqrt(1 - b2 ** t) / (1 - b1 ** t)
     step_size = (lr_t * lr_mult * alpha).astype(jnp.float32)
@@ -233,7 +175,6 @@ def _fused_write_update(g, p, m, v, prev_norm, step_size, wd_coef, leaf0,
                         *, level, gamma, use_limiter, weight_decay, b1, b2,
                         eps, impl):
     """One leaf's launch over its slab ``leaf0`` of the stacked moments."""
-    from repro.kernels.gwt_adam import kernel, ref  # noqa: F811 — local
     kw = dict(level=level, gamma=gamma, use_limiter=use_limiter,
               weight_decay=weight_decay, b1=b1, b2=b2, eps=eps)
     if impl in ("pallas", "interpret"):
@@ -299,7 +240,6 @@ def _fused_write_update_q8(g, p, qm, sm, qv, sv, salt_m, salt_v, prev_norm,
                            impl):
     """One leaf's q8 launch over its slab ``leaf0`` of the stacked
     payloads and scales."""
-    from repro.kernels.gwt_adam import kernel, ref  # noqa: F811 — local
     kw = dict(level=level, block=block, gamma=gamma,
               use_limiter=use_limiter, weight_decay=weight_decay, b1=b1,
               b2=b2, eps=eps)

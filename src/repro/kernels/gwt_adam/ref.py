@@ -32,36 +32,32 @@ from repro.kernels.gwt_adam import kernel
 
 
 @functools.partial(jax.jit, static_argnames=("level", "b1", "b2", "eps"))
-def gwt_adam_tile(g: jax.Array, m_st: jax.Array, v_st: jax.Array, *,
-                  level: int, b1: float = 0.9, b2: float = 0.999,
-                  eps: float = 1e-6) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+def _tile(g: jax.Array, m_st: jax.Array, v_st: jax.Array, *,
+          level: int, b1: float = 0.9, b2: float = 0.999,
+          eps: float = 1e-6) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """One leaf's G̃ (in ``g``'s dtype) and new moments."""
     out, m, v = kernel._dht_adam_core(g, m_st.astype(jnp.float32),
                                       v_st.astype(jnp.float32), level, b1,
                                       b2, eps, xla=True)
-    gt = out.astype(g.dtype)
-    # limiter norm partials over the ROUNDED output — the norm of the g̃
-    # actually emitted, matching the kernel's ssq_ref
-    gr = gt.astype(jnp.float32)
-    ssq = jnp.sum(gr * gr)[None, None]
-    return (gt, m.astype(m_st.dtype), v.astype(v_st.dtype), ssq)
+    return out.astype(g.dtype), m.astype(m_st.dtype), v.astype(v_st.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("level", "block", "b1", "b2",
                                              "eps"))
-def gwt_adam_tile_q8(g: jax.Array, qm: jax.Array, sm: jax.Array,
-                     qv: jax.Array, sv: jax.Array,
-                     salt_m: jax.Array, salt_v: jax.Array, *,
-                     level: int, block: int, b1: float = 0.9,
-                     b2: float = 0.999, eps: float = 1e-6):
+def _tile_q8(g: jax.Array, qm: jax.Array, sm: jax.Array,
+             qv: jax.Array, sv: jax.Array,
+             salt_m: jax.Array, salt_v: jax.Array, *,
+             level: int, block: int, b1: float = 0.9,
+             b2: float = 0.999, eps: float = 1e-6):
     """q8 oracle: blocked-int8 moments in, blocked-int8 moments out.
 
-    Dequantize → ``gwt_adam_tile`` math → stochastic requantize with the
+    Dequantize → :func:`_tile` math → stochastic requantize with the
     caller-supplied per-slot salts (``repro.optim.codec`` hash — the same
     bits the Pallas epilogue and the engine's generic scan wrap produce).
     The dequantize multiplies by the kernel's per-element scale expansion
     (values equal to ``codec.blocked_dequant``'s broadcast, but XLA may
     fold ``β·(q·s)`` differently around a broadcast and round an ulp off).
-    Returns ``(gt, qm', sm', qv', sv', ssq)``.
+    Returns ``(gt, qm', sm', qv', sv')``.
     """
     from repro.optim import codec as codec_lib
     rows, na = qm.shape
@@ -73,12 +69,9 @@ def gwt_adam_tile_q8(g: jax.Array, qm: jax.Array, sm: jax.Array,
     m_st, v_st = dequant(qm, sm), dequant(qv, sv)
     out, m, v = kernel._dht_adam_core(g, m_st, v_st, level, b1, b2, eps,
                                       xla=True)
-    gt = out.astype(g.dtype)
-    gr = gt.astype(jnp.float32)
-    ssq = jnp.sum(gr * gr)[None, None]
     qm2, sm2 = codec_lib.blocked_quant(m, salt_m, block)
     qv2, sv2 = codec_lib.blocked_quant(v, salt_v, block)
-    return (gt, qm2, sm2, qv2, sv2, ssq)
+    return (out.astype(g.dtype), qm2, sm2, qv2, sv2)
 
 
 # ---------------------------------------------------------------------------
@@ -141,13 +134,12 @@ def gwt_adam_fused(g: jax.Array, p: jax.Array, m_st: jax.Array,
     leaf with in-place dynamic-update-slice, so one leaf's working set is
     the only live temp and donated inputs alias straight through to the
     outputs — the one-launch dataflow the kernel has, visible to XLA
-    buffer assignment (the step benchmark's fused-vs-staged peak-live
-    gate rides on this)."""
+    buffer assignment."""
     def body(carry, xs):
         p_c, m_c, v_c = carry
         gl, pnl, l = xs
         s = l + leaf0
-        gt, m, v, _ = gwt_adam_tile(
+        gt, m, v = _tile(
             gl, jax.lax.dynamic_index_in_dim(m_c, s, 0, keepdims=False),
             jax.lax.dynamic_index_in_dim(v_c, s, 0, keepdims=False),
             level=level, b1=b1, b2=b2, eps=eps)
@@ -189,7 +181,7 @@ def gwt_adam_fused_q8(g: jax.Array, p: jax.Array, qm: jax.Array,
         s = l + leaf0
         at = lambda a, i: jax.lax.dynamic_index_in_dim(a, i, 0,
                                                        keepdims=False)
-        gt, qm2, sm2, qv2, sv2, _ = gwt_adam_tile_q8(
+        gt, qm2, sm2, qv2, sv2 = _tile_q8(
             gl, at(qm_c, s), at(sm_c, s), at(qv_c, s), at(sv_c, s), saltml,
             saltvl, level=level, block=block, b1=b1, b2=b2, eps=eps)
         new_p, new_norm = _limit_write(

@@ -152,7 +152,7 @@ def main(argv=None):
                          "without recorded fine-tune metadata")
     ap.add_argument("--lora-alpha", type=float, default=16.0)
     ap.add_argument("--static", action="store_true",
-                    help="static-wave admission (the benchmark baseline)")
+                    help="static-wave admission: a wave drains before the next")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--kernel-impl", default="auto",
                     choices=["auto", "pallas", "interpret", "jnp"])

@@ -157,8 +157,7 @@ class StepWatchdog:
 class TrainLoop:
     """Checkpointed, preemption-safe, straggler-monitored loop around a
     train_step ``(params, opt_state, batch) -> (params, opt_state,
-    metrics)``.  Used by launch/train.py, the examples, and the step
-    benchmark.
+    metrics)``.  Used by launch/train.py, the examples, and ``bench/``.
 
     ``pipelined=True`` (default) wraps the step in a jitted
     scan-over-chunk *superstep* with ``donate_argnums=(params,
@@ -170,8 +169,8 @@ class TrainLoop:
 
     ``pipelined=False`` reproduces the pre-pipeline loop — one dispatch
     and one blocking ``float(loss)`` per step, synchronous batch fetch, no
-    donation — and is what ``benchmarks/run.py step`` measures the
-    pipelined loop against.
+    donation — and is the reference the pipelined loop is tested against
+    (``tests/test_runtime_pipeline.py``).
     """
 
     def __init__(self, train_step, ckpt, data_source, *,

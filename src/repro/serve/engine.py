@@ -20,8 +20,7 @@ slot is mid-prompt, then one decode step if any slot is generating.
 Requests therefore join and leave the running batch *between decode
 steps* — the continuous-batching property — instead of the static-wave
 discipline (``static=True``: admit only when all slots are free, decode
-only once every admitted prompt is fully paged in) that the serve
-benchmark uses as its baseline.
+only once every admitted prompt is fully paged in).
 
 Both compiled steps are jitted with ``donate_argnums=(1,)``: the page
 pools are the only mutated state and XLA aliases them in place, so the

@@ -157,22 +157,6 @@ def test_optimizers_converge_on_quadratic(name, kw):
     assert float(loss_fn(ps)) < 0.9 * l0
 
 
-def test_gwt_equals_fused_kernel_path():
-    """jnp core path == fused-kernel (interpret) path, leaf by leaf."""
-    from repro.kernels.gwt_adam import ops as gops
-    g = jax.random.normal(jax.random.key(3), (64, 256))
-    st = {"m": jnp.zeros((64, 64)), "v": jnp.zeros((64, 64))}
-    for step in range(3):
-        gt_i, lm_i, st_i = gops.fused_update(g, st, jnp.int32(step),
-                                             level=2, impl="interpret")
-        gt_j, lm_j, st_j = gops.fused_update(g, st, jnp.int32(step),
-                                             level=2, impl="jnp")
-        np.testing.assert_allclose(gt_i, gt_j, rtol=1e-4, atol=1e-4)
-        np.testing.assert_allclose(st_i["v"], st_j["v"], rtol=1e-5, atol=1e-6)
-        st = st_i
-        g = g * 0.9
-
-
 def test_galore_projector_refresh():
     """Projection refreshes every update_gap steps (SVD under lax.cond)."""
     o = optim.make("galore", lr=0.01, rank=2, update_gap=3)

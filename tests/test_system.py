@@ -12,7 +12,7 @@ import pytest
 
 from repro import configs, optim
 from repro.data.pipeline import SyntheticLM
-from repro.models import lm
+from repro.models import lm, lora
 from repro.optim.schedules import warmup_cosine
 
 gwt_mod = importlib.import_module("repro.core.gwt")
@@ -22,18 +22,30 @@ TINY = configs.LLAMA["llama-60m"].with_(
     d_ff=256, vocab=512, name="tiny")
 
 
-def _train(optimizer, steps=40, seed=0, seq=64, batch=8, cfg=TINY):
-    key = jax.random.key(seed)
-    params = lm.init(cfg, key)
-    st = optimizer.init(params)
+def _fit(step_fn, params, st, steps=40, seed=0, seq=64, batch=8, cfg=TINY):
+    """``steps`` of ``step_fn`` on the Markov task of ``seed``; returns the
+    trained params and the losses."""
     data = SyntheticLM(cfg.vocab, seq, batch, seed=seed)
-    step_fn = jax.jit(lm.make_train_step(cfg, optimizer))
+    step_fn = jax.jit(step_fn)
     losses = []
     for i in range(steps):
         b = {k: jnp.asarray(v) for k, v in data.batch(i).items()}
         params, st, m = step_fn(params, st, b)
         losses.append(float(m["loss"]))
-    return losses
+    return params, losses
+
+
+def _train(optimizer, steps=40, seed=0, seq=64, batch=8, cfg=TINY):
+    params = lm.init(cfg, jax.random.key(seed))
+    return _fit(lm.make_train_step(cfg, optimizer), params,
+                optimizer.init(params), steps, seed, seq, batch, cfg)[1]
+
+
+def _final(losses):
+    """Final loss as the tracking bounds read it: the mean of the last
+    tenth of the steps."""
+    k = max(len(losses) // 10, 1)
+    return sum(losses[-k:]) / k
 
 
 def test_training_reduces_loss_gwt():
@@ -48,6 +60,36 @@ def test_gwt_tracks_adam_quality():
     adam_l = _train(optim.make("adam", lr=warmup_cosine(0.0025, 40)))
     gwt_l = _train(optim.make("gwt", lr=warmup_cosine(0.01, 40), level=2))
     assert gwt_l[-1] < adam_l[-1] * 1.35, (adam_l[-1], gwt_l[-1])
+
+
+def test_gwt_int8_tracks_gwt_f32():
+    """int8-coded moments follow the f32 GWT-2 curve within 1.25x of its
+    final loss.  At 40 steps this catches a codec that diverges or stalls,
+    not a small rounding bias: the limiter-bounded update learns even from
+    moments rebuilt every step."""
+    f32 = _train(optim.make("gwt", lr=warmup_cosine(0.01, 40), level=2))
+    q8 = _train(optim.make("gwt", lr=warmup_cosine(0.01, 40), level=2,
+                           state_codec="int8"))
+    assert _final(q8) <= 1.25 * _final(f32), (_final(f32), _final(q8))
+
+
+def test_gwt_lora_tracks_adam_lora():
+    """LoRA fine-tuning of an Adam-trained base on another Markov task:
+    GWT-2 on the adapters' moments ends within 1.25x of Adam on them (the
+    frozen base keeps no state, so only the adapters' moments differ)."""
+    adam = optim.make("adam", lr=warmup_cosine(0.01, 40))
+    params = lm.init(TINY, jax.random.key(0))
+    base, _ = _fit(lm.make_train_step(TINY, adam), params, adam.init(params))
+    finals = {}
+    for name, kw in (("gwt", {"level": 2}), ("adam", {})):
+        opt = lora.wrap_optimizer(
+            optim.make(name, lr=warmup_cosine(0.01, 40), **kw))
+        tree = lora.inject(base, 8, jax.random.key(777))
+        step = lora.make_train_step(lm, TINY, opt, rank=8, alpha=16.0)
+        losses = _fit(step, tree, opt.init(tree), seed=1)[1]
+        finals[name] = _final(losses)
+        assert finals[name] < losses[0], (name, losses[0], finals[name])
+    assert finals["gwt"] <= 1.25 * finals["adam"], finals
 
 
 def test_gwt_beats_galore_at_matched_memory():

@@ -81,12 +81,15 @@ def _fused_q8_args(L, m, n):
 # leaves merged into rows).
 CELLS = [(2, 128, 11008), (1, 256, 2048), (2, 128, 14336), (1, 128, 4096),
          (2, 2048, 256), (2, 128, 768)]
+# One llama-1b MLP stripe as a leaf of its own: 5461 rows, a partial last
+# row tile.
+CORE = (1, 5461, 2048)
 
 
-@pytest.mark.parametrize("shape", [ATTN, MLP] + CELLS,
+@pytest.mark.parametrize("shape", [ATTN, MLP] + CELLS + [CORE],
                          ids=["attn", "mlp", "qwen-gate_up", "qwen-down",
                               "mistral-gate_up", "mistral-down", "qwen-kv",
-                              "qwen3moe-gate_up"])
+                              "qwen3moe-gate_up", "core-5461"])
 def test_fused_f32_compiles(one_chip, shape):
     fn = functools.partial(kg.gwt_adam_tile_fused, level=LEVEL, gamma=1.01,
                            use_limiter=True, weight_decay=False)
@@ -157,13 +160,6 @@ def test_fused_q8_compiles(one_chip, shape, use_limiter):
                            block=BLOCK, gamma=1.01, use_limiter=use_limiter,
                            weight_decay=True)
     _compile(fn, one_chip, *_fused_q8_args(*shape))
-
-
-def test_core_tile_compiles(one_chip):
-    m, n = 5461, 2048
-    fn = functools.partial(kg.gwt_adam_tile, level=LEVEL)
-    _compile(fn, one_chip, ((m, n), BF16), ((m, n >> LEVEL), F32),
-             ((m, n >> LEVEL), F32))
 
 
 @pytest.mark.parametrize("m,n", [(24 * 5461, 2048), (2048, 32000)],
