@@ -13,7 +13,7 @@ One chip:
     the f32 schedule's three-pass shuffles, and one launch per leaf (the
     optimizer's path) bitwise against one launch over the stacked bucket,
     at the benchmark cells' bucket widths (``python chip_smoke.py
-    --parity`` runs these two checks alone);
+    --parity`` runs these checks alone);
 (c) llama-1b GWT level-2 training through ``repro.launch.train.main``:
     f32 moments, then the int8 state codec.  Before each run the train
     step is compiled once more on its own to check that every GWT leaf
@@ -263,16 +263,18 @@ CELL_BUCKETS = [(2, 18432, 11008), (1, 99072, 2048), (2, 18432, 2048),
 def three_pass_schedule():
     """Inside, the fused kernel runs every gradient tile through the f32
     schedule (three-pass shuffles, rounded to the gradient's dtype at the
-    end): the kernel as it was before the bf16 schedule.  Takes effect
-    for kernels traced inside."""
+    end): the kernel as it was before the bf16 schedule.  Unmerged, that
+    G̃ is split into the stride phases the bf16 schedule hands the norm
+    pass.  Takes effect for kernels traced inside."""
     import jax.numpy as jnp
     from repro.kernels.gwt_adam import kernel as kg
     core = kg._dht_adam_core
 
-    def shuffled(x, m_st, v_st, level, b1, b2, eps, xla=False):
+    def shuffled(x, m_st, v_st, level, b1, b2, eps, xla=False, merge=True):
         out, m, v = kg._core_shuffled(x.astype(jnp.float32), m_st, v_st,
                                       level, b1, b2, eps, xla)
-        return out.astype(x.dtype), m, v
+        out = out.astype(x.dtype)
+        return (out if merge else kg.split_phases(out, level)), m, v
 
     kg._dht_adam_core = shuffled
     try:
@@ -514,8 +516,9 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--chips", type=int, default=1, choices=[1, 4])
     ap.add_argument("--parity", action="store_true",
-                    help="one chip: run the bf16 schedule and per-leaf "
-                    "launch parity alone")
+                    help="one chip: run the kernel parity against the "
+                    "oracle, the bf16 schedule and per-leaf launch parity "
+                    "alone")
     args = ap.parse_args(argv)
     if not (SRC / "repro").is_dir():
         print(f"chip_smoke: the repro package is not at {SRC}; run this "
@@ -530,6 +533,7 @@ def main(argv=None) -> int:
         if args.chips == 4:
             four_chips()
         elif args.parity:
+            kernel_parity()
             schedule_parity()
             leaf_parity()
         else:

@@ -16,10 +16,11 @@ observation (§V), taken to its architectural conclusion.  One pass reads
 G and P, reads and writes M, V (at ``1/2^l`` of the width) and writes P:
 with bf16 G and P and f32 moments at level 2, 10 B per gradient element.
 With the norm-growth limiter on (the default) the kernel makes two
-passes (phase 0 sums ‖G̃‖² and writes P, M, V through unchanged, phase 1
-recomputes and writes), 20 B per gradient element.  With three-pass
-shuffles it ran at 19% of that 10 B roofline on a TPU v5e, bound by the
-MXU's shuffles.
+passes.  Phase 0 sums ‖G̃‖² and reads only G, M and V: the P input and
+the P, M, V output windows stay pinned on the leaf's first row block, so
+it fetches no P tile and writes nothing back (4 B per element).  Phase 1
+recomputes the tile and writes (10 B), so 14 B per gradient element in
+all.
 
 The butterfly's even/odd lane pairing is a product with a 0/1 selection
 matrix on the MXU (``repro.kernels.lanes``), exact, so every tile is
@@ -28,10 +29,12 @@ one three.  So a bf16 gradient tile runs the butterfly's arithmetic on
 its stride-``2^l`` phases, split off and merged back in bf16
 (``_core_phases``: 2,048 MXU FLOPs per element at level 2); any other
 dtype runs it on f32 bands (``_core_shuffled``: 5,376).  Both give the
-same bits.  Stripes span the full row (a block whose last dimension is
-the array's is always tile-legal, whatever the width); the row tile is
-bounded by the VMEM budget and the grid is ``pl.cdiv(m, bm)``, so a
-partial last tile masks its out-of-range rows out of every norm.
+same bits.  The norm pass of a bf16 tile sums the squares of G̃'s
+phases before they are merged, so it skips the merge (1,024).  Stripes
+span the full row (a block whose last dimension is the array's is
+always tile-legal, whatever the width); the row tile is bounded by the
+VMEM budget and the grid is ``pl.cdiv(m, bm)``, so a partial last tile
+masks its out-of-range rows out of every norm.
 Scalars (per-leaf limiter state, step size, weight decay, rounding salts)
 and the per-tile norm partials live in SMEM.
 
@@ -84,18 +87,43 @@ def _adam(a, m_st, v_st, b1, b2, eps, xla):
     return m, v, 1.0 / (jnp.sqrt(v) + eps)
 
 
-def _dht_adam_core(x, m_st, v_st, level, b1, b2, eps, xla=False):
+def _dht_adam_core(x, m_st, v_st, level, b1, b2, eps, xla=False,
+                   merge=True):
     """Forward butterfly → Adam on A → scaled-detail inverse butterfly of
     a raw gradient tile ``x``.  Shared by the f32 and the q8 (blocked-int8
     moments) bodies and by ``ref.py``.  Returns ``(out, m, v)``: ``out``
     is G̃ in ``x``'s dtype for a bf16 ``x`` (:func:`_core_phases`), in f32
     otherwise (:func:`_core_shuffled`); rounding it to ``x``'s dtype gives
-    the same bits either way.  ``xla``: the body is compiled by XLA
+    the same bits either way.  ``merge=False`` returns ``out`` as a list
+    of tiles that :func:`merge_phases` merges into G̃: a bf16 ``x``'s
+    ``2^l`` stride phases ``G̃[:, j::2^l]``, not yet interleaved, or
+    ``[G̃]`` for any other dtype.  ``xla``: the body is compiled by XLA
     (interpret mode, the oracles), not by Mosaic."""
     if x.dtype == jnp.bfloat16:
-        return _core_phases(x, m_st, v_st, level, b1, b2, eps, xla)
-    return _core_shuffled(x.astype(jnp.float32), m_st, v_st, level, b1, b2,
-                          eps, xla)
+        out, m, v = _core_phases(x, m_st, v_st, level, b1, b2, eps, xla)
+        return (merge_phases(out) if merge else out), m, v
+    out, m, v = _core_shuffled(x.astype(jnp.float32), m_st, v_st, level, b1,
+                               b2, eps, xla)
+    return (out if merge else [out]), m, v
+
+
+def split_phases(x, level: int) -> list:
+    """The ``2^level`` stride phases ``[x[:, j::2^level]]`` of a tile, by
+    ``level`` rounds of :func:`lanes.deinterleave` (exact)."""
+    ph = [x]                     # ph[j] == x[:, j::len(ph)]
+    for _ in range(level):
+        halves = [lanes.deinterleave(p) for p in ph]
+        ph = [e for e, _ in halves] + [o for _, o in halves]
+    return ph
+
+
+def merge_phases(ph: list):
+    """Inverse of :func:`split_phases`: the tile whose stride phases are
+    ``ph``, by ``log2(len(ph))`` rounds of :func:`lanes.interleave`."""
+    while len(ph) > 1:           # ph[j] == tile[:, j::len(ph)]
+        half = len(ph) // 2
+        ph = [lanes.interleave(ph[j], ph[j + half]) for j in range(half)]
+    return ph[0]
 
 
 def _core_shuffled(x, m_st, v_st, level, b1, b2, eps, xla=False):
@@ -128,9 +156,11 @@ def _core_phases(x, m_st, v_st, level, b1, b2, eps, xla):
     :func:`_core_shuffled` on the same elements in the same order, so the
     bands, the moments and every output element are bitwise its own.  The
     details of every level are scaled by ``1/(√V+ε)`` phase by phase, with
-    no ``repeat_lanes``.  The output phases are rounded to bf16 and
-    interleaved in ``l`` one-pass rounds: a permutation commutes with the
-    rounding.  At level 2 that is 2,048 MXU FLOPs per element.
+    no ``repeat_lanes``.  Returns the output phases rounded to bf16
+    (``out[j] == G̃[:, j::2^l]``); :func:`merge_phases` interleaves them in
+    ``l`` one-pass rounds, and a permutation commutes with the rounding.
+    At level 2 the split takes 1,024 MXU FLOPs per element and the merge
+    as many.
 
     Under XLA a :func:`lanes.fence` stands wherever
     :func:`_core_shuffled` puts a shuffle between two f32 ops: between
@@ -142,12 +172,7 @@ def _core_phases(x, m_st, v_st, level, b1, b2, eps, xla):
     Mosaic runs no fence; the chip parity phase of ``chip_smoke.py`` pins
     the compiled kernel to the f32 schedule's bits."""
     fence = lanes.fence if xla else (lambda t: t)
-    ph = [x]                     # ph[j] == x[:, j::len(ph)]
-    for _ in range(level):
-        halves = [lanes.deinterleave(p) for p in ph]
-        ph = [e for e, _ in halves] + [o for _, o in halves]
-
-    band = [p.astype(jnp.float32) for p in ph]
+    band = [p.astype(jnp.float32) for p in split_phases(x, level)]
     details = []
     for k in range(level):
         if k:
@@ -166,11 +191,7 @@ def _core_phases(x, m_st, v_st, level, b1, b2, eps, xla):
             nxt += [(yj + d_t) * INV_SQRT2, (yj - d_t) * INV_SQRT2]
         y = [fence(u) for u in nxt]
 
-    out = [yj.astype(x.dtype) for yj in y]
-    while len(out) > 1:          # out[j] == G̃[:, j::len(out)]
-        half = len(out) // 2
-        out = [lanes.interleave(out[j], out[j + half]) for j in range(half)]
-    return out[0], m, v
+    return [yj.astype(x.dtype) for yj in y], m, v
 
 
 def _row_bytes(n: int, level: int, stream_cols: float) -> int:
@@ -224,9 +245,9 @@ def _body_fused(level: int, b1: float, b2: float, eps: float, xla: bool,
                 p_out_ref, m_out_ref, v_out_ref, norm_ref):
     """Grid ``(Lg, phases, gm)`` — leaf outermost, row tiles innermost; the
     SMEM ``norm_ref[l]`` doubles as the cross-tile ssq accumulator.  Phase
-    0 accumulates ``‖G̃_l‖²``; phase 1 recomputes the tile (the op is
-    bandwidth-bound — recompute is cheaper than an HBM round trip of G̃)
-    and applies limiter + step + weight decay + write.
+    0 accumulates ``‖G̃_l‖²`` and writes nothing; phase 1 recomputes the
+    tile (the op is bandwidth-bound — recompute is cheaper than an HBM
+    round trip of G̃) and applies limiter + step + weight decay + write.
     ``use_limiter=False`` runs the single write phase only.  ``sc_ref``
     holds ``(step_size, wd_coef)``; ``leaf0_ref`` is read by the moments'
     index maps alone."""
@@ -235,13 +256,13 @@ def _body_fused(level: int, b1: float, b2: float, eps: float, xla: bool,
     i = pl.program_id(2)
     gm = pl.num_programs(2)
     x = g_ref[0]
-    out, m, v = _dht_adam_core(x, m_ref[0].astype(jnp.float32),
-                               v_ref[0].astype(jnp.float32),
-                               level, b1, b2, eps, xla)
-    gt = out.astype(g_ref.dtype)
+    outs, m, v = _dht_adam_core(x, m_ref[0].astype(jnp.float32),
+                                v_ref[0].astype(jnp.float32),
+                                level, b1, b2, eps, xla, merge=False)
     prev = pn_ref[leaf]
 
     def write(scale):
+        gt = merge_phases(outs).astype(g_ref.dtype)
         limited = gt * scale.astype(gt.dtype)
         p32 = p_ref[0].astype(jnp.float32)
         new_p = p32 - sc_ref[0] * limited.astype(jnp.float32)
@@ -256,21 +277,22 @@ def _body_fused(level: int, b1: float, b2: float, eps: float, xla: bool,
         norm_ref[leaf] = prev  # limiter off: prev_norm passes through
         return
 
-    part = lanes.masked_ssq(gt.astype(jnp.float32), i * x.shape[0], rows)
-
     @pl.when(phase == 0)
     def _():
+        # The norm pass: G̃'s squares phase by phase, unmerged, in the
+        # order ref._tiled_norm states.  The p input and the p/m/v output
+        # windows are pinned on the leaf's first row block through this
+        # phase (_fused_specs), and on the TPU a window is copied back to
+        # HBM only when the next grid step maps another block: the
+        # unwritten windows leave VMEM after phase 1's tile 0 has written
+        # them, and p is fetched once, for that tile.
+        part = None
+        for o in outs:
+            s = lanes.masked_ssq(o.astype(g_ref.dtype).astype(jnp.float32),
+                                 i * x.shape[0], rows)
+            part = s if part is None else part + s
         acc = jnp.where(i == 0, jnp.float32(0.0), norm_ref[leaf])
         norm_ref[leaf] = acc + part
-        # On hardware, every output window a grid step maps is copied back
-        # to HBM when the step ends, written or not — and p/m/v alias
-        # their inputs, so leaving them unwritten here would clobber the
-        # state phase 1 re-reads with undefined VMEM.  Pass the inputs
-        # through unmodified (interpret mode masks this; the chip parity
-        # phase of chip_smoke.py pins it).
-        p_out_ref[0] = p_ref[0]
-        m_out_ref[0] = m_ref[0]
-        v_out_ref[0] = v_ref[0]
 
     @pl.when(phase == 1)
     def _():
@@ -284,14 +306,32 @@ def _body_fused(level: int, b1: float, b2: float, eps: float, xla: bool,
             norm_ref[leaf] = jnp.where(norm > 0, norm * scale, prev)
 
 
-def _stripe(bm: int, w: int, slab: bool = False) -> pl.BlockSpec:
+def _stripe(bm: int, w: int, slab: bool = False,
+            pinned: bool = False) -> pl.BlockSpec:
     """Row stripe ``(1, bm, w)`` over the ``(Lg, phases, gm)`` grid: of
     the launch's leaf ``l``, or (``slab``) of a bucket's stacked moments
-    at the leaf's slab ``leaf0 + l``."""
+    at the leaf's slab ``leaf0 + l``.  ``pinned``: phase 0 maps every row
+    tile to the first block (row index ``i * ph``), phase 1 tile ``i``."""
+    row = (lambda ph, i: i * ph) if pinned else (lambda ph, i: i)
     if slab:
-        return pl.BlockSpec((1, bm, w),
-                            lambda l, ph, i, leaf0: (l + leaf0[0], i, 0))
-    return pl.BlockSpec((1, bm, w), lambda l, ph, i, leaf0: (l, i, 0))
+        return pl.BlockSpec((1, bm, w), lambda l, ph, i, leaf0: (
+            l + leaf0[0], row(ph, i), 0))
+    return pl.BlockSpec((1, bm, w), lambda l, ph, i, leaf0: (
+        l, row(ph, i), 0))
+
+
+def _fused_specs(bm: int, n: int, level: int, use_limiter: bool):
+    """``(in_specs, out_specs)`` of :func:`gwt_adam_tile_fused`'s g, p, m,
+    v inputs and p, m, v outputs.  With the limiter on, the p input and
+    every output are pinned through phase 0 (:func:`_stripe`); g, m and v
+    stream in both phases."""
+    na = n >> level
+    ins = [_stripe(bm, n), _stripe(bm, n, pinned=use_limiter),
+           _stripe(bm, na, slab=True), _stripe(bm, na, slab=True)]
+    outs = [_stripe(bm, n, pinned=use_limiter),
+            _stripe(bm, na, slab=True, pinned=use_limiter),
+            _stripe(bm, na, slab=True, pinned=use_limiter)]
+    return ins, outs
 
 
 def _leaf0(leaf0) -> jax.Array:
@@ -321,19 +361,17 @@ def gwt_adam_tile_fused(g: jax.Array, p: jax.Array, m_st: jax.Array,
     L, mm, nn = g.shape
     _check_width(nn, level)
     bm = fused_row_block(mm, nn, level)
-    na = nn >> level
     phases = 2 if use_limiter else 1
     scalars = jnp.stack([jnp.asarray(step_size, jnp.float32),
                          jnp.asarray(wd_coef, jnp.float32)])
-    tiles = [_stripe(bm, nn), _stripe(bm, nn), _stripe(bm, na, slab=True),
-             _stripe(bm, na, slab=True)]
+    ins, outs = _fused_specs(bm, nn, level, use_limiter)
     return pl.pallas_call(
         functools.partial(_body_fused, level, b1, b2, eps, interpret, gamma,
                           use_limiter, weight_decay, mm),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(L, phases, pl.cdiv(mm, bm)),
-            in_specs=[_SMEM, _SMEM] + tiles,
-            out_specs=tiles[1:] + [_SMEM]),
+            in_specs=[_SMEM, _SMEM] + ins,
+            out_specs=outs + [_SMEM]),
         out_shape=[
             jax.ShapeDtypeStruct((L, mm, nn), p.dtype),
             jax.ShapeDtypeStruct(m_st.shape, m_st.dtype),
@@ -341,11 +379,12 @@ def gwt_adam_tile_fused(g: jax.Array, p: jax.Array, m_st: jax.Array,
             jax.ShapeDtypeStruct((L,), jnp.float32),
         ],
         # in-place write semantics: p/m/v are updated in their own
-        # buffers (each tile reads its block before writing it; phase 0
-        # writes the inputs through unchanged), and the moment slabs of
-        # other leaves keep their values.  NOT prev_norm→new_norm:
-        # phase 0 accumulates ssq into the norm output while phase 1 still
-        # reads the history from pn_ref — aliasing them would clobber it.
+        # buffers (each tile reads its block before writing it; phase 0's
+        # pinned windows are written back only after phase 1 wrote them),
+        # and the moment slabs of other leaves keep their values.  NOT
+        # prev_norm→new_norm: phase 0 accumulates ssq into the norm output
+        # while phase 1 still reads the history from pn_ref — aliasing
+        # them would clobber it.
         input_output_aliases={4: 0, 5: 1, 6: 2},
         interpret=interpret,
     )(_leaf0(leaf0), prev_norm.astype(jnp.float32), scalars, g, p, m_st,
@@ -444,9 +483,13 @@ def _body_fused_q8(level: int, b1: float, b2: float, eps: float, xla: bool,
     def _():
         acc = jnp.where(i == 0, jnp.float32(0.0), norm_ref[leaf])
         norm_ref[leaf] = acc + part
-        # hardware copy-out of unwritten aliased windows would clobber
-        # the state phase 1 re-reads — pass inputs through unmodified
-        # (see _body_fused)
+        # On the TPU an output window is copied back to HBM whenever the
+        # next grid step maps another block, written or not.  These
+        # windows stream with the row tiles and alias their inputs, so
+        # left unwritten they would clobber, with undefined VMEM, the
+        # state phase 1 re-reads: pass the inputs through unmodified
+        # (interpret mode masks this; the chip parity phase of
+        # chip_smoke.py pins it).
         p_out_ref[0] = p_ref[0]
         qm_out_ref[0] = qm_ref[0]
         sm_out_ref[0] = sm_ref[0]

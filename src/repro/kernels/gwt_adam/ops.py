@@ -82,6 +82,20 @@ def _count_in_place(gs, swap: bool) -> None:
                       elements_stacked=n if swap else 0)
 
 
+def _count_norm_pass(gs, use_limiter: bool, norm_only: bool) -> None:
+    """At trace time, one sample of the counter ``gwt.kernel.norm_pass``
+    (category ``gwt``) for this bucket, in gradient elements launched with
+    the limiter on: those whose norm pass (phase 0) reads only ``g``,
+    ``m`` and ``v`` and writes nothing (``elements_norm_only``: the
+    f32-moment kernel, ``kernel._fused_specs``), and those whose norm
+    pass also reads ``p`` and writes ``p`` and the state through
+    (``elements_full``: the q8 kernel)."""
+    n = sum(g.size for g in gs) if use_limiter else 0
+    obs.get().counter("gwt.kernel.norm_pass", cat="gwt",
+                      elements_norm_only=n if norm_only else 0,
+                      elements_full=0 if norm_only else n)
+
+
 def _rows(x: jax.Array, swap: bool) -> jax.Array:
     """A leaf as the kernel's ``(1, rows, n)``: the transform runs along
     the last axis (the second to last for a FIRST-orient leaf, which is
@@ -148,6 +162,7 @@ def fused_write_update(gs, ps, state: dict, step: jax.Array,
     impl = compat.resolve_kernel_impl(impl)
     _count_schedule(gs)
     _count_in_place(gs, swap)
+    _count_norm_pass(gs, use_limiter, norm_only=True)
     with jax.named_scope("gwt.kernel"):
         step_size, wd_coef = _step_scalars(step, lr_t, alpha, weight_decay,
                                            b1, b2)
@@ -205,6 +220,7 @@ def fused_write_update_q8(gs, ps, state: dict, step: jax.Array,
     impl = compat.resolve_kernel_impl(impl)
     _count_schedule(gs)
     _count_in_place(gs, swap)
+    _count_norm_pass(gs, use_limiter, norm_only=False)
     L = len(gs)
     enc = (state["m"]["q"], state["m"]["scale"], state["v"]["q"],
            state["v"]["scale"])

@@ -84,10 +84,15 @@ def _tile_q8(g: jax.Array, qm: jax.Array, sm: jax.Array,
 # (ops.py passes ``kernel.fused_row_block`` / ``kernel.q8_row_block``).
 # ---------------------------------------------------------------------------
 
-def _tiled_norm(gt: jax.Array, bm: int) -> jax.Array:
-    """‖gt‖ via the kernel's reduction order: one ``jnp.sum`` per (bm, n)
-    row stripe, partials added sequentially; a partial last stripe is
-    zero-padded to ``bm`` rows, as the kernel masks its missing rows."""
+def _tiled_norm(gt: jax.Array, bm: int, phases: int = 1) -> jax.Array:
+    """‖gt‖ via the kernel's reduction order: per (bm, n) row stripe, one
+    ``jnp.sum`` of the squares of each stride phase ``t[:, j::phases]`` in
+    turn, added left to right into the stripe's partial, and the partials
+    added sequentially.  ``phases`` is ``2^level`` for a bf16 G̃ in the
+    f32-moment kernel, whose norm pass sums its unmerged phases
+    (``kernel._body_fused``), and 1 (the whole stripe) otherwise.  A
+    partial last stripe is zero-padded to ``bm`` rows, as the kernel masks
+    its missing rows."""
     xr = gt.astype(jnp.float32)
     pad = -xr.shape[0] % bm
     if pad:
@@ -95,15 +100,19 @@ def _tiled_norm(gt: jax.Array, bm: int) -> jax.Array:
     acc = None
     for k in range(xr.shape[0] // bm):
         t = xr[k * bm:(k + 1) * bm]
-        part = jnp.sum(t * t)
+        part = None
+        for j in range(phases):
+            tj = t[:, j::phases]
+            s = jnp.sum(tj * tj)
+            part = s if part is None else part + s
         acc = part if acc is None else acc + part
     return jnp.sqrt(acc)
 
 
 def _limit_write(gt, p, prev, step_size, wd_coef, *, gamma, use_limiter,
-                 weight_decay, bm):
+                 weight_decay, bm, phases=1):
     if use_limiter:
-        norm = _tiled_norm(gt, bm)
+        norm = _tiled_norm(gt, bm, phases)
         scale = kernel._limiter_scale(norm, prev, gamma)
         new_norm = jnp.where(norm > 0, norm * scale, prev)
     else:
@@ -135,6 +144,8 @@ def gwt_adam_fused(g: jax.Array, p: jax.Array, m_st: jax.Array,
     the only live temp and donated inputs alias straight through to the
     outputs — the one-launch dataflow the kernel has, visible to XLA
     buffer assignment."""
+    phases = 1 << level if g.dtype == jnp.bfloat16 else 1
+
     def body(carry, xs):
         p_c, m_c, v_c = carry
         gl, pnl, l = xs
@@ -146,7 +157,7 @@ def gwt_adam_fused(g: jax.Array, p: jax.Array, m_st: jax.Array,
         new_p, new_norm = _limit_write(
             gt, jax.lax.dynamic_index_in_dim(p_c, l, 0, keepdims=False),
             pnl, step_size, wd_coef, gamma=gamma, use_limiter=use_limiter,
-            weight_decay=weight_decay, bm=bm)
+            weight_decay=weight_decay, bm=bm, phases=phases)
         p_c = jax.lax.dynamic_update_index_in_dim(p_c, new_p, l, 0)
         m_c = jax.lax.dynamic_update_index_in_dim(m_c, m, s, 0)
         v_c = jax.lax.dynamic_update_index_in_dim(v_c, v, s, 0)

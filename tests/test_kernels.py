@@ -463,13 +463,16 @@ def test_lane_shuffles_one_pass_bf16_exact(width):
     assert dots(lanes.deinterleave, x.astype(jnp.float32)) == 6 * pieces
 
 
-def _three_pass_core(x, m_st, v_st, level, b1, b2, eps, xla=False):
+def _three_pass_core(x, m_st, v_st, level, b1, b2, eps, xla=False,
+                     merge=True):
     """The fused kernel's core as it was before the bf16 schedule: the
     tile through the f32 schedule's three-pass shuffles, G̃ rounded to the
-    gradient's dtype at the end."""
+    gradient's dtype at the end.  Unmerged, that G̃ is split into the
+    stride phases the bf16 schedule hands the norm pass."""
     out, m, v = kg._core_shuffled(x.astype(jnp.float32), m_st, v_st, level,
                                   b1, b2, eps, xla)
-    return out.astype(x.dtype), m, v
+    out = out.astype(x.dtype)
+    return (out if merge else kg.split_phases(out, level)), m, v
 
 
 @pytest.mark.parametrize("n", [256, 2048, 11008])
@@ -527,3 +530,121 @@ def test_fused_write_counts_one_pass_schedule():
          "buckets_three_pass": 0.0, "elements_three_pass": 0.0},
         {"buckets_one_pass": 0.0, "elements_one_pass": 0.0,
          "buckets_three_pass": 1.0, "elements_three_pass": 8 * 256.0}]
+
+
+def _launch_specs(monkeypatch, fn, *args):
+    """``(grid, in_specs, out_specs)`` of the ``pallas_call`` that ``fn``
+    makes, traced (not run) with ``args``."""
+    seen = []
+    real = kg.pl.pallas_call
+
+    def spy(*a, grid_spec, **kw):
+        seen.append(grid_spec)
+        return real(*a, grid_spec=grid_spec, **kw)
+    monkeypatch.setattr(kg.pl, "pallas_call", spy)
+    jax.eval_shape(fn, *args)
+    (gs,) = seen
+    return gs.grid, gs.in_specs, gs.out_specs
+
+
+def _block(spec, l, ph, i, leaf0):
+    return tuple(int(b) for b in spec.index_map(
+        l, ph, i, jnp.asarray([leaf0], jnp.int32)))
+
+
+@pytest.mark.parametrize("leaf0", [0, 3])
+@pytest.mark.parametrize("use_limiter", [True, False])
+def test_fused_launch_pins_norm_pass_windows(monkeypatch, leaf0,
+                                             use_limiter):
+    """The f32-moment launch's index maps: with the limiter on, phase 0
+    maps every row tile of the p input and of the p, m and v outputs to
+    the first block of the leaf (p) or of its slab ``leaf0 + l`` (m, v),
+    and phase 1 maps tile i; g, m and v inputs stream tile i in both
+    phases.  With the limiter off the one write phase streams them all."""
+    L, m, n, level = 2, 200, 4080, 2
+    bm = kg.fused_row_block(m, n, level)
+    gm = -(-m // bm)
+    assert gm > 2
+    g, p, st, pn = _fused_write_inputs(L, m, n, level, dtype=jnp.bfloat16)
+    ms = jnp.zeros((leaf0 + L, m, n >> level))
+    grid, ins, outs = _launch_specs(
+        monkeypatch, lambda *a: kg.gwt_adam_tile_fused(
+            *a, leaf0, level=level, gamma=1.01, use_limiter=use_limiter,
+            weight_decay=False, interpret=True),
+        g, p, ms, ms, pn, jnp.float32(0.01), jnp.float32(0.0))
+    phases = 2 if use_limiter else 1
+    assert grid == (L, phases, gm)
+    g_in, p_in, m_in, v_in = ins[2:]
+    p_out, m_out, v_out = outs[:3]
+    for l in range(L):
+        for ph in range(phases):
+            pinned = use_limiter and ph == 0
+            for i in range(gm):
+                row = 0 if pinned else i
+                assert _block(g_in, l, ph, i, leaf0) == (l, i, 0)
+                for spec in (m_in, v_in):
+                    assert _block(spec, l, ph, i, leaf0) == (l + leaf0, i, 0)
+                for spec in (p_in, p_out):
+                    assert _block(spec, l, ph, i, leaf0) == (l, row, 0)
+                for spec in (m_out, v_out):
+                    assert _block(spec, l, ph, i, leaf0) == (
+                        l + leaf0, row, 0)
+
+
+@pytest.mark.parametrize("L,m,n", [(1, 200, 4080), (2, 16, 256)],
+                         ids=["partial_last_tile", "one_tile"])
+@pytest.mark.parametrize("level", [1, 2])
+def test_fused_write_bf16_norm_pass_bitwise_vs_oracle(L, m, n, level):
+    """Fed bf16 g/p with the limiter on, the interpret-mode kernel's norm
+    pass sums G̃'s unmerged stride phases tile by tile, and the oracle
+    (``ref._tiled_norm``) states that order: the norms and moments are
+    bit for bit the oracle's, at a partial last row tile and at a single
+    one, and new_p within one bf16 ulp (the write chain's contraction)."""
+    bm = kg.fused_row_block(m, n, level)
+    assert (m > bm and m % bm) if m > 16 else m <= bm
+    g, p, st, pn = _fused_write_inputs(L, m, n, level, dtype=jnp.bfloat16)
+    kw = _fused_write_kw(level)
+    pi, ni, si = gops.fused_write_update(list(g), list(p), st, jnp.int32(1),
+                                         pn, impl="interpret", **kw)
+    pj, nj, sj = gops.fused_write_update(list(g), list(p), st, jnp.int32(1),
+                                         pn, impl="jnp", **kw)
+    for tag, a, b in [("norm", ni, nj),
+                      ("m", si["m"], sj["m"]), ("v", si["v"], sj["v"])]:
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                      err_msg=tag)
+    bits_i = np.asarray(pi).view(np.uint16).astype(np.int32)
+    bits_j = np.asarray(pj).view(np.uint16).astype(np.int32)
+    assert np.abs(bits_i - bits_j).max() <= 1
+
+
+def test_fused_write_counts_norm_pass():
+    """At trace time each fused wrapper records, as the counter
+    ``gwt.kernel.norm_pass``, the gradient elements it launches with the
+    limiter on: norm-only for the f32-moment kernel, whose phase 0 reads
+    g, m and v and writes nothing, full for the q8 kernel; nothing with
+    the limiter off."""
+    from repro import obs
+    L, m, n, level = 2, 8, 256, 2
+    g, p, st, pn = _fused_write_inputs(L, m, n, level, dtype=jnp.bfloat16)
+    q8, key, lids = _q8_encoded_state(L, m, n >> level)
+    tracer = obs.Tracer()
+    obs.configure(tracer=tracer)
+    try:
+        for use_limiter in (True, False):
+            kw = _fused_write_kw(level, use_limiter=use_limiter)
+            jax.eval_shape(lambda *a: gops.fused_write_update(
+                *a, impl="interpret", **kw), list(g), list(p), st,
+                jnp.int32(0), pn)
+            jax.eval_shape(lambda *a: gops.fused_write_update_q8(
+                *a, impl="interpret", **kw), list(g), list(p), q8,
+                jnp.int32(0), key, lids, pn)
+    finally:
+        obs.shutdown()
+    got = [e for e in tracer.events if e["name"] == "gwt.kernel.norm_pass"]
+    assert {e["cat"] for e in got} == {"gwt"}
+    n_el = float(L * m * n)
+    assert [e["args"] for e in got] == [
+        {"elements_norm_only": n_el, "elements_full": 0.0},
+        {"elements_norm_only": 0.0, "elements_full": n_el},
+        {"elements_norm_only": 0.0, "elements_full": 0.0},
+        {"elements_norm_only": 0.0, "elements_full": 0.0}]

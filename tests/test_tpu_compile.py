@@ -86,14 +86,43 @@ CELLS = [(2, 128, 11008), (1, 256, 2048), (2, 128, 14336), (1, 128, 4096),
 CORE = (1, 5461, 2048)
 
 
+# The fused launch's in-place outputs: p, m, v alias the g, p, m, v
+# operands after the scalar prefetch, the norms and the scalars.
+FUSED_ALIASES = {(0, 4), (1, 5), (2, 6)}
+
+
+def _aliases(hlo: str):
+    """The ``(output, operand)`` aliases of each Mosaic call in ``hlo``."""
+    out = []
+    for line in hlo.splitlines():
+        if 'custom_call_target="tpu_custom_call"' in line:
+            seg = line.partition("output_to_operand_aliasing=")[2]
+            out.append({(int(o), int(a)) for o, a in re.findall(
+                r"\{(\d+)\}: \((\d+), \{\}\)", seg)})
+    return out
+
+
 @pytest.mark.parametrize("shape", [ATTN, MLP] + CELLS + [CORE],
                          ids=["attn", "mlp", "qwen-gate_up", "qwen-down",
                               "mistral-gate_up", "mistral-down", "qwen-kv",
                               "qwen3moe-gate_up", "core-5461"])
-def test_fused_f32_compiles(one_chip, shape):
+def test_fused_f32_compiles(one_chip, monkeypatch, shape):
+    """With the limiter's norm pass on its pinned windows (the p input and
+    the p, m, v outputs on block 0 through phase 0, tile i in phase 1),
+    and p, m, v updated in place (outputs 0-2 alias operands 4-6)."""
+    specs = []
+    real = kg._fused_specs
+    monkeypatch.setattr(kg, "_fused_specs",
+                        lambda *a: specs.append(real(*a)) or specs[-1])
     fn = functools.partial(kg.gwt_adam_tile_fused, level=LEVEL, gamma=1.01,
                            use_limiter=True, weight_decay=False)
-    _compile(fn, one_chip, *_fused_args(*shape))
+    hlo = _compile(fn, one_chip, *_fused_args(*shape))
+    ((ins, outs),) = specs
+    leaf0 = jnp.zeros((1,), jnp.int32)
+    for spec in [ins[1]] + outs:
+        assert int(spec.index_map(0, 0, 3, leaf0)[1]) == 0
+        assert int(spec.index_map(0, 1, 3, leaf0)[1]) == 3
+    assert _aliases(hlo) == [FUSED_ALIASES]
 
 
 def _big_ops(comp: str, limit: int = 4096):
@@ -123,8 +152,9 @@ def test_fused_update_takes_leaves_in_place(one_chip, leaf):
     (Qwen2.5-3B's gate/up layer stacks, Qwen3-30B-A3B's expert stacks),
     run in a loop that carries the parameters and moments as the train
     superstep does, compiles to one launch per leaf on the carried and
-    the gradients' own buffers: nothing in the loop stacks, slices,
-    copies or transposes a leaf or the moments."""
+    the gradients' own buffers, each updating p, m and v in place:
+    nothing in the loop stacks, slices, copies or transposes a leaf or
+    the moments."""
     from repro.kernels.gwt_adam import ops as gops
     L = 2
     a_shape = (L,) + leaf[:-1] + (leaf[-1] >> LEVEL,)
@@ -148,6 +178,7 @@ def test_fused_update_takes_leaves_in_place(one_chip, leaf):
     comp = hlo[hlo.index(f"\n%{body} ") + 1:]
     comp = comp[:comp.index("\n}")]
     assert comp.count('custom_call_target="tpu_custom_call"') == L
+    assert _aliases(comp) == [FUSED_ALIASES] * L
     assert _big_ops(comp) == []
 
 
